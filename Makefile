@@ -117,7 +117,7 @@
 #                     every acked request completes with zero client
 #                     resubmission, the dead member is adopted AND
 #                     respawned on a fresh dir before the drain (rc 114)
-#   bench-trajectory= aggregate the BENCH_r01..r16 headline numbers into
+#   bench-trajectory= aggregate the BENCH_r07..r16 headline numbers into
 #                     one table (stdout + rewritten into docs/PERFORMANCE.md
 #                     "Performance trajectory"), so the perf history is
 #                     readable without opening ten JSON files

@@ -1,39 +1,16 @@
-"""jax version-compatibility shims.
+"""One import site for jax API spellings the package shares.
 
-One import site for API surface that moved between jax releases, so the
-rest of the package (and the tests) can write against the modern spelling
-without mutating the global ``jax`` namespace.
+``typeof``: the aval of a value (carrying ``vma`` under shard_map).
+``shard_map``: ``jax.shard_map`` with its ``check_vma=`` keyword.
 
-``typeof``: modern jax's ``jax.typeof`` (the aval of a value, carrying
-``vma`` under shard_map); older jax spells it ``jax.core.get_aval`` (no
-``vma`` attribute — callers already treat it as optional).
-
-``shard_map``: modern jax exposes it as ``jax.shard_map`` with a
-``check_vma=`` keyword; older jax only has
-``jax.experimental.shard_map.shard_map`` with ``check_rep=``.  On old jax
-the replication checker also has no rule for ``lax.while_loop`` (every
-kernel here carries one) — its check is advisory, so it defaults off
-there rather than rejecting programs the modern checker accepts.
+Written against the installed jax (0.9.0); the rest of the package (and
+the tests) import these names from here, so the next move of either API
+is a one-line change.
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "typeof"):
-    typeof = jax.typeof
-else:  # pragma: no cover - exercised only on old jax
-    from jax.core import get_aval as typeof
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on old jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        kwargs.setdefault("check_rep", False)
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
+typeof = jax.typeof
+shard_map = jax.shard_map

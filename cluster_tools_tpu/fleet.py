@@ -277,7 +277,14 @@ class FleetSupervisor:
                       record: str = MEMBER_SPAWN, **fields) -> Any:
         """Start one member server subprocess; journals the decision
         (``record``) before returning.  Used at boot, for respawns, and
-        for scale-up."""
+        for scale-up.
+
+        The member is ``serve.main``: it places the compile cache
+        (``parallel.mesh.configure_compile_cache`` — the same fixed path for
+        every member) and, with ``--tpu``, opens the accelerator before it
+        binds.  This supervisor never initialises a JAX backend, so it holds
+        no chip; nothing here gives a member a chip of its own, so a second
+        ``--tpu`` member on one chip exits 1 at start (ROADMAP R6)."""
         os.makedirs(mdir, exist_ok=True)
         cmd = [
             sys.executable, "-m", "cluster_tools_tpu.serve",
@@ -1071,8 +1078,10 @@ def main(argv=None) -> int:
                    help="fleet config json: members/gateway/server/"
                         "supervisor keys")
     p.add_argument("--tpu", action="store_true",
-                   help="skip the cpu platform pin on members (requests "
-                        "may target the accelerator)")
+                   help="start every member with --tpu (each opens the "
+                        "accelerator before it binds; a chip belongs to "
+                        "one process, so on one chip only one member can "
+                        "start — the rest exit 1 with the reason)")
     p.add_argument("--status", metavar="BASE_DIR", default=None,
                    help="print a running gateway's /status and exit with "
                         "its rc")

@@ -73,61 +73,6 @@ def _out_struct(shape, dtype, *like) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _ccl_kernel_doubling(tile_shape, mask_ref, out_ref):
-    """In-tile CCL via guarded run-doubling propagation.
-
-    Per iteration, every axis propagates the min label along *entire
-    foreground runs* with log2(extent) doubling levels: a label may jump
-    2^k along an axis iff the whole segment between is foreground
-    (``conn_k[i] = conn_{k-1}[i] & conn_{k-1}[i - 2^{k-1}]``).  Convergence
-    is O(#direction changes of the component) instead of O(diameter) —
-    fewer, fatter iterations than the unit-step kernel; which wins is
-    hardware-measured (scripts/tpu_measure.py), selected via
-    ``tile_ccl_pallas(..., doubling=True)``.
-    """
-    tz, ty, tx = tile_shape
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    k = pl.program_id(2)
-    ny = pl.num_programs(1) * ty
-    nx = pl.num_programs(2) * tx
-    mask = mask_ref[:] > 0
-    gz = lax.broadcasted_iota(jnp.int32, tile_shape, 0) + i * tz
-    gy = lax.broadcasted_iota(jnp.int32, tile_shape, 1) + j * ty
-    gx = lax.broadcasted_iota(jnp.int32, tile_shape, 2) + k * tx
-    gidx = (gz * ny + gy) * nx + gx
-    lab = jnp.where(mask, gidx, jnp.int32(BIG))
-
-    def axis_sweep(l, ax):
-        n = l.shape[ax]
-        for direction in (1, -1):
-            conn = mask & _shift(mask, direction, ax, False)
-            m = l
-            step = 1
-            while step < n:
-                cand = _shift(m, direction * step, ax, jnp.int32(BIG))
-                m = jnp.where(conn, jnp.minimum(m, cand), m)
-                nxt = step * 2
-                if nxt < n:
-                    conn = conn & _shift(conn, direction * step, ax, False)
-                step = nxt
-            l = jnp.minimum(l, jnp.where(mask, m, jnp.int32(BIG)))
-        return l
-
-    def cond(s):
-        return s[1]
-
-    def body(s):
-        l, _ = s
-        l2 = l
-        for ax in range(3):
-            l2 = axis_sweep(l2, ax)
-        return l2, jnp.any(l2 != l)
-
-    lab, _ = lax.while_loop(cond, body, (lab, True))
-    out_ref[:] = lab
-
-
 def _ccl_kernel(tile_shape, mask_ref, out_ref):
     tz, ty, tx = tile_shape
     i = pl.program_id(0)
@@ -164,26 +109,23 @@ def _ccl_kernel(tile_shape, mask_ref, out_ref):
     out_ref[:] = lab
 
 
-@partial(jax.jit, static_argnames=("tile", "interpret", "doubling"))
+@partial(jax.jit, static_argnames=("tile", "interpret"))
 def tile_ccl_pallas(
     mask: jnp.ndarray,
     tile: Tuple[int, int, int] = (16, 16, 128),
     interpret: bool = False,
-    doubling: bool = False,
 ) -> jnp.ndarray:
     """Exact per-tile CCL of a 3-D bool mask; labels are global flat indices.
 
     Shape must be divisible by ``tile`` (callers pad).  Foreground voxels get
     the minimum global flat index of their *within-tile* component;
     background gets ``BIG``.  Cross-tile merging is ``tile_ccl.py``'s job.
-    ``doubling`` selects the run-doubling propagation variant.
     """
     z, y, x = mask.shape
     tz, ty, tx = tile
     assert z % tz == 0 and y % ty == 0 and x % tx == 0, (mask.shape, tile)
-    kernel = _ccl_kernel_doubling if doubling else _ccl_kernel
     return pl.pallas_call(
-        partial(kernel, tile),
+        partial(_ccl_kernel, tile),
         out_shape=_out_struct((z, y, x), jnp.int32, mask),
         grid=(z // tz, y // ty, x // tx),
         in_specs=[

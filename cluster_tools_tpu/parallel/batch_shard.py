@@ -9,10 +9,10 @@ the standard TPU-native shape (the fluid-flow TPU framework of
 arXiv:2108.11076 runs its whole grid as one sharded program per step):
 
 - :func:`batched_shard_map` — a whole Morton batch of blocks becomes ONE
-  compiled program over the named device mesh: ``shard_map`` (through the
-  version compat shim) splits the stacked batch axis across devices and
-  ``vmap`` runs the per-block kernel over each device's sub-batch.  The
-  dispatch lock is held once per batch instead of once per block.
+  compiled program over the named device mesh: ``shard_map`` splits the
+  stacked batch axis across devices and ``vmap`` runs the per-block kernel
+  over each device's sub-batch.  The dispatch lock is held once per batch
+  instead of once per block.
 - :func:`exchange_batch_halo` — device-side halo exchange along the batch
   axis for batches whose blocks form a contiguous run along one spatial
   axis (slab sweeps): each block's halo is reconstructed from its batch
@@ -125,8 +125,7 @@ def batched_shard_map(
 
     ``check_vma=False`` for the same reason as ``parallel/pipeline.py``:
     kernels carrying ``while_loop``/pallas bodies trip the static
-    replication checker on the jax versions the compat shim supports; only
-    the advisory check is off, the collectives (none here unless the kernel
+    replication checker; only the advisory check is off, the collectives (none here unless the kernel
     adds them) are unaffected.
     """
     n = mesh_n_devices(mesh)

@@ -18,11 +18,55 @@ use.
 
 from __future__ import annotations
 
+import logging
+import os
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
+
+logger = logging.getLogger(__name__)
+
+#: default home of JAX's persistent compile cache: a fixed path inside the
+#: checkout (the path is part of the cache key, so it must never move)
+_DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compile cache; returns the directory in use.
+
+    Called by the entry points (``cli``, ``serve`` — and so by every fleet
+    member — and ``bench.py``) before their first compile, never at import.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this
+    sets nothing; otherwise the cache lives at ``<checkout>/.jax_cache``.
+    The tiled watershed takes minutes to compile for the chip; without
+    this every ``cli run`` and every server restart pays that again.
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", _DEFAULT_COMPILE_CACHE)
+    return _DEFAULT_COMPILE_CACHE
+
+
+def use_cpu_backend(why: str) -> None:
+    """The entry points' explicit choice of the CPU backend for a process
+    whose work does not target the accelerator (``target != "tpu"``, a
+    server started without ``--tpu``).  Must run before the first backend
+    initialisation.  Said out loud when it overrides what ``JAX_PLATFORMS``
+    / JAX's default would have picked — kernel selection keys on
+    ``jax.default_backend()``, so a process that computes on CPU devices
+    must also have the CPU as its default backend."""
+    if jax.config.jax_platforms != "cpu":
+        logger.warning(
+            "%s: computing on the CPU backend (jax_platforms=cpu); use "
+            "target 'tpu' / --tpu to compute on the accelerator", why,
+        )
+        jax.config.update("jax_platforms", "cpu")
 
 
 def _pick_grid(n: int, n_axes: int) -> Tuple[int, ...]:
@@ -46,16 +90,18 @@ def _pick_grid(n: int, n_axes: int) -> Tuple[int, ...]:
 
 def backend_devices(target: str = "local", n_devices: Optional[int] = None):
     """Devices for a mesh: ``local`` = CPU (the fake-cluster test backend,
-    honoring ``xla_force_host_platform_device_count``), ``tpu`` = TPU chips."""
+    honoring ``xla_force_host_platform_device_count``), ``tpu`` = TPU chips.
+    Neither falls back to the other's devices: a target whose backend is
+    not there raises."""
     if target == "tpu":
         devs = [d for d in jax.devices() if d.platform == "tpu"]
         if not devs:
-            raise RuntimeError("target='tpu' but no TPU devices are visible")
+            raise RuntimeError(
+                "target='tpu' but no TPU devices are visible (jax found "
+                f"{sorted({d.platform for d in jax.devices()})})"
+            )
     elif target == "local":
-        try:
-            devs = jax.devices("cpu")
-        except RuntimeError:
-            devs = jax.devices()
+        devs = jax.devices("cpu")
     else:
         raise ValueError(f"unknown target {target!r}")
     if n_devices is not None:
@@ -65,6 +111,25 @@ def backend_devices(target: str = "local", n_devices: Optional[int] = None):
             )
         devs = devs[:n_devices]
     return devs
+
+
+def describe_devices(devices) -> list:
+    """``["tpu:0", ...]`` — how the tasks' logs name the devices a sweep or
+    a mesh ran on (``executor.devices=`` / ``mesh.devices=``)."""
+    return [f"{d.platform}:{d.id}" for d in np.asarray(devices).flat]
+
+
+def device_peak_bytes(devices) -> Dict[str, int]:
+    """Peak device memory per device so far in this process
+    (``memory_stats()["peak_bytes_in_use"]``; empty where the backend does
+    not report it, as on the CPU) — logged by the tasks after a sweep or a
+    mesh step, so that "everything landed on device 0" is visible."""
+    peaks = {}
+    for name, d in zip(describe_devices(devices), np.asarray(devices).flat):
+        stats = d.memory_stats()
+        if stats and "peak_bytes_in_use" in stats:
+            peaks[name] = int(stats["peak_bytes_in_use"])
+    return peaks
 
 
 def make_mesh(

@@ -95,6 +95,12 @@ class ShardedSolveError(RuntimeError):
     malformed shard state).  Callers degrade to the single-host solver."""
 
 
+#: the degrade ladders below absorb resource, deadline and worker faults;
+#: these are defects in the program (a moved API, a bad call) and must
+#: surface — a ladder that swallows them keeps a dead plane "passing"
+_PROGRAMMING_ERRORS = (ImportError, AttributeError, TypeError)
+
+
 def _host_impl(impl: Optional[str] = None) -> str:
     """Concrete host-side contraction impl (``native``/``numpy``), never
     ``auto``: ``auto``'s accelerator probe initializes the XLA client,
@@ -618,7 +624,7 @@ class CollectiveReducePlane:
     hop deadline; its failures are the ladder's second rung.
 
     Everything numeric runs under the thread-local
-    ``jax.experimental.enable_x64`` context — staging included: without
+    ``jax.enable_x64`` context — staging included: without
     it ``device_put``/``jnp.zeros`` silently downcast f64→f32 and the
     bit-identity contract breaks.
     """
@@ -681,9 +687,9 @@ class CollectiveReducePlane:
         import jax
         import jax.numpy as jnp
         from jax import lax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
+        from ..compat import shard_map
         from ..ops.contraction import lane_frontier_rounds
         from .mesh import SIBLING_AXIS
 
@@ -708,10 +714,10 @@ class CollectiveReducePlane:
             return labels, rounds
 
         prog = jax.jit(shard_map(
-            per_device, self.mesh,
+            per_device, mesh=self.mesh,
             in_specs=(P(),) * 6 + (P(SIBLING_AXIS), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         ))
         self._programs[(Wn,)] = prog
         return prog
@@ -840,7 +846,6 @@ class CollectiveReducePlane:
     ) -> np.ndarray:
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         from ..runtime import faults as faults_mod
         from .device_pool import get_device_pool
@@ -853,7 +858,7 @@ class CollectiveReducePlane:
             try:
                 # thread-local x64: staging AND the call must both see it,
                 # and this worker thread is where both happen
-                with enable_x64():
+                with jax.enable_x64():
                     # hop chaos: a hang here is a wedged interconnect the
                     # deadline must notice; an error a failed collective
                     injector.maybe_hang("hop", block_id=level)
@@ -892,10 +897,10 @@ class CollectiveReducePlane:
             )
         if "error" in box:
             err = box["error"]
-            if isinstance(err, BaseException) and not isinstance(
-                err, Exception
+            if not isinstance(err, Exception) or isinstance(
+                err, _PROGRAMMING_ERRORS
             ):
-                raise err  # DrainInterrupt etc. pass through
+                raise err  # DrainInterrupt etc. and program defects pass through
             raise ShardedSolveError(
                 f"collective level {level} failed: "
                 f"{type(err).__name__}: {err}"
@@ -1011,6 +1016,8 @@ def sharded_solve(
                     mode, threshold, payload.shape[1],
                     hop_deadline_s=hop_deadline,
                 )
+            except _PROGRAMMING_ERRORS:
+                raise
             except Exception as e:
                 # init-failure rung: attributed when the plane was
                 # demanded, counter-only when auto was probing
@@ -1057,6 +1064,8 @@ def sharded_solve(
                     state, groups, level=li, deadline_s=hop_deadline
                 )
                 level_plane = "collective"
+            except _PROGRAMMING_ERRORS:
+                raise
             except Exception as e:
                 # runtime rung of the degrade ladder (hop deadline, a
                 # failed collective, pool exhaustion): this and every
@@ -1688,6 +1697,8 @@ def solve_with_reduce_tree(
             hop_deadline_s=hop_deadline_s, failures_path=failures_path,
             task_name=task_name,
         )
+    except _PROGRAMMING_ERRORS:
+        raise
     except Exception as e:
         if no_partition:
             return unsharded(), {"sharded": False, "shards": 1}

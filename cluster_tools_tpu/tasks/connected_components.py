@@ -34,6 +34,7 @@ import numpy as np
 
 from ..ops.ccl import label_components, label_components_keyed
 from ..ops.unionfind import union_find, union_find_host
+from ..parallel.mesh import describe_devices
 from ..runtime import handoff
 from ..runtime.executor import (
     BlockwiseExecutor,
@@ -169,6 +170,9 @@ class BlockComponentsBase(BaseTask):
             io_threads=int(cfg.get("io_threads") or max(1, self.max_jobs)),
             max_retries=int(cfg.get("io_retries", 2)),
             backoff_base=float(cfg.get("io_backoff_s", 0.05)),
+        )
+        self.logger.info(
+            f"executor.devices={describe_devices(executor.devices)}"
         )
         executor.map_blocks(
             kernel,

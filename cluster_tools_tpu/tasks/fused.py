@@ -68,7 +68,13 @@ class FusedSegmentationBase(BaseTask):
     def run_impl(self):
         import jax
 
-        from ..parallel.mesh import make_mesh
+        from ..ops.tile_ws import resolved_modes
+        from ..parallel.mesh import (
+            backend_devices,
+            describe_devices,
+            device_peak_bytes,
+            make_mesh,
+        )
         from ..parallel.pipeline import make_ws_ccl_step
         from ..parallel.split_pipeline import make_ws_ccl_split
 
@@ -86,8 +92,11 @@ class FusedSegmentationBase(BaseTask):
         if len(roi_shape) != 3:
             raise ValueError(f"fused segmentation is 3-D only, got {roi_shape}")
 
-        # one ROI = batch of 1: every device goes to the spatial axes
-        n_dev = len(jax.devices())
+        # one ROI = batch of 1: every device of the task's target goes to
+        # the spatial axes (the same rule as the blockwise executor, so
+        # target="tpu" with no TPU raises here too)
+        devices = backend_devices(self.target)
+        n_dev = len(devices)
         decomposition = str(cfg.get("decomposition", "slab"))
         if decomposition == "grid" and n_dev > 1:
             # factor devices over z and y, z getting the larger share
@@ -96,13 +105,16 @@ class FusedSegmentationBase(BaseTask):
             )
             sz = n_dev // sy
             mesh = make_mesh(
-                axis_names=("dp", "spz", "spy"), grid=(1, sz, sy)
+                axis_names=("dp", "spz", "spy"), grid=(1, sz, sy),
+                devices=devices,
             )
             sp_axis = ("spz", "spy")
             divides = (roi_shape[0] % sz == 0) and (roi_shape[1] % sy == 0)
             sp_desc = f"spz={sz} spy={sy}"
         elif decomposition in ("slab", "grid"):
-            mesh = make_mesh(axis_names=("dp", "sp"), grid=(1, n_dev))
+            mesh = make_mesh(
+                axis_names=("dp", "sp"), grid=(1, n_dev), devices=devices
+            )
             sp_axis = "sp"
             divides = roi_shape[0] % n_dev == 0
             sp_desc = f"sp={n_dev}"
@@ -128,6 +140,7 @@ class FusedSegmentationBase(BaseTask):
             raise ValueError(
                 f"execution must be 'fused' or 'split', got {execution!r}"
             )
+        impl = str(cfg.get("impl", "auto"))
         build_step = make_ws_ccl_step if execution == "fused" else make_ws_ccl_split
         step = build_step(
             mesh,
@@ -137,15 +150,18 @@ class FusedSegmentationBase(BaseTask):
             dt_max_distance=dt_max,
             min_seed_distance=float(cfg.get("min_seed_distance") or 0.0),
             max_labels_per_shard=cfg.get("max_labels_per_shard"),
-            impl=str(cfg.get("impl", "auto")),
+            impl=impl,
             exact_edt=bool(cfg.get("exact_edt", False)),
             stitch_ws_threshold=cfg.get("stitch_ws_threshold"),
         )
         self.logger.info(
-            f"{execution} step on mesh {sp_desc}, roi {roi_shape}, halo={halo}"
+            f"{execution} step on mesh {sp_desc}, roi {roi_shape}, "
+            f"halo={halo}; mesh.devices={describe_devices(mesh.devices)}; "
+            f"kernels={resolved_modes(impl)}"
         )
         vol = np.asarray(inp[roi]).astype(np.float32)
         ws, cc, n_fg, overflow = jax.block_until_ready(step(vol[None]))
+        self.logger.info(f"device.peak_bytes={device_peak_bytes(mesh.devices)}")
         if bool(np.asarray(overflow)):
             raise RuntimeError(
                 "fused step overflowed a label capacity; raise "

@@ -198,6 +198,24 @@ class _WsTaskBase(BaseTask):
         table[iso] = (np.arange(len(iso)) + k + 1).astype(lab.dtype)
         return table[lab]
 
+    def _log_execution(self, executor, impl, use_tiled):
+        """Which devices and which kernels this sweep runs — ``auto``
+        resolves per backend, and a run's log must say what it resolved to."""
+        from ..ops.tile_ws import resolved_modes
+        from ..parallel.mesh import describe_devices
+
+        self.logger.info(
+            f"executor.devices={describe_devices(executor.devices)}; "
+            f"kernels={resolved_modes(impl if use_tiled else 'legacy')}"
+        )
+
+    def _log_device_peak(self, executor):
+        from ..parallel.mesh import device_peak_bytes
+
+        self.logger.info(
+            f"device.peak_bytes={device_peak_bytes(executor.devices)}"
+        )
+
     def _store_labels(self, out, block, raw, n_outer, size_dtype=np.uint64):
         """Crop inner region from the padded-outer labels and globalize."""
         inner = raw[block.inner_in_outer_bb]
@@ -376,6 +394,7 @@ class WatershedBase(_WsTaskBase):
                 max_retries=int(cfg.get("io_retries", 2)),
                 backoff_base=float(cfg.get("io_backoff_s", 0.05)),
             )
+            self._log_execution(executor, impl, use_tiled)
             executor.map_blocks(
                 kernel,
                 blocks_all,
@@ -411,6 +430,7 @@ class WatershedBase(_WsTaskBase):
                 degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
                 inflight_byte_budget=cfg.get("inflight_byte_budget"),
             )
+            self._log_device_peak(executor)
         return {
             "n_blocks": len(block_ids),
             "n_outer": n_outer,
@@ -586,6 +606,7 @@ class TwoPassWatershedBase(_WsTaskBase):
             max_retries=int(cfg.get("io_retries", 2)),
             backoff_base=float(cfg.get("io_backoff_s", 0.05)),
         )
+        self._log_execution(executor, impl, use_tiled)
         executor.map_blocks(
             kernel,
             blocks_all,
@@ -610,6 +631,7 @@ class TwoPassWatershedBase(_WsTaskBase):
             degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
             inflight_byte_budget=cfg.get("inflight_byte_budget"),
         )
+        self._log_device_peak(executor)
         return {
             "n_blocks": len(block_ids),
             "n_outer": n_outer,

@@ -1,4 +1,4 @@
-"""Aggregate the scattered BENCH_r01..r16 headline numbers into one table.
+"""Aggregate the scattered BENCH_r07..r16 headline numbers into one table.
 
 Usage::
 
@@ -8,12 +8,13 @@ Usage::
         BENCH-TRAJECTORY markers)
     make bench-trajectory
 
-Each bench round left a differently-shaped JSON next to the repo root
-(r01-r06: the driver wrapper with a ``parsed`` headline line; r07+: the
-raw records the focused benches write).  This script knows each shape,
-extracts one headline row per round, and renders a markdown table — the
-perf trajectory readable without opening nine files.  Unreadable or
-headline-less rounds degrade to an explanatory row, never a crash.
+Each bench round left a differently-shaped JSON next to the repo root (the
+raw records the focused benches write; r01-r06, the driver-wrapper records
+of the earlier accelerator set-up, were deleted in PR 24).  This script
+knows each shape, extracts one headline row per round, and renders a
+markdown table — the perf trajectory readable without opening ten files.
+Unreadable or unknown records degrade to an explanatory row, never a crash.
+All of them are CPU-backend records: counts, not speeds.
 """
 
 from __future__ import annotations
@@ -34,28 +35,12 @@ def _fmt(value: float) -> str:
     return f"{value:,.0f}" if abs(value) >= 100 else f"{value:g}"
 
 
-def _wrapper_row(doc: dict) -> dict:
-    """r01-r06: the orchestrated driver wrapper ({n, cmd, rc, tail,
-    parsed}); the headline is the parsed final JSON line."""
-    parsed = doc.get("parsed") or {}
-    if not parsed:
-        note = "timed out" if doc.get("rc") == 124 else f"rc={doc.get('rc')}"
-        return {
-            "metric": "fused watershed+CCL",
-            "headline": f"no headline ({note})",
-            "backend": "?",
-            "detail": "",
-        }
-    vs = parsed.get("vs_baseline")
+def _unknown_row(doc: dict) -> dict:
     return {
-        "metric": parsed.get("metric", "?"),
-        "headline": f"{_fmt(parsed.get('value') or 0)} {parsed.get('unit', '')}".strip(),
-        "backend": parsed.get("backend", "?"),
-        "detail": (
-            f"{vs}x baseline" if vs is not None else ""
-        ) + (
-            f"; {parsed['headline_path']}" if parsed.get("headline_path") else ""
-        ),
+        "metric": str(doc.get("metric", "?")),
+        "headline": "no extractor for this record shape",
+        "backend": doc.get("backend", "?"),
+        "detail": "",
     }
 
 
@@ -314,7 +299,7 @@ def _reduce_row(doc: dict) -> dict:
     }
 
 
-#: per-metric extractors for the raw (non-wrapper) records
+#: per-metric extractors
 _RAW_ROWS = {
     "sharded_sweep_dispatch": _sweep_row,
     "task_graph_fusion_workflow": _fuse_row,
@@ -339,10 +324,7 @@ def extract_row(path: str) -> dict:
             "round": round_no, "metric": "?", "backend": "?",
             "headline": f"unreadable ({e.__class__.__name__})", "detail": "",
         }
-    if "parsed" in doc or "cmd" in doc:
-        row = _wrapper_row(doc)
-    else:
-        row = _RAW_ROWS.get(str(doc.get("metric")), _wrapper_row)(doc)
+    row = _RAW_ROWS.get(str(doc.get("metric")), _unknown_row)(doc)
     row["round"] = round_no
     return row
 

@@ -6,14 +6,17 @@ on XLA:CPU, and FAILS on any overflow flag.  This is the run that
 caught two headline-scale cap bugs in round 5 (fill_rounds' 2^16 bound
 vs 80,902 measured basins; adj_cap n/128 vs the measured n/85 unique
 adjacency load — docs/PERFORMANCE.md "512³ host-substrate rehearsal"),
-either of which would otherwise have burned the first real chip window
-with an overflow-flagged headline.
+either of which would otherwise have cost a chip run an overflow-flagged
+result.
 
 Needs ~40 GB RAM and ~15-25 min on a 2-core box (the synth volume
 dominates).  Run before any chip campaign and after any capacity /
 round-bound / fill change:
 
     python scripts/rehearse_512.py [extent]
+
+A rehearsal tool: it pins the CPU backend on purpose and never opens the
+chip (``chip_smoke.py`` is what runs there).
 """
 
 import os

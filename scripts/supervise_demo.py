@@ -9,7 +9,8 @@ resubmission log and the ``failures.json`` attribution so an operator can
 see the whole detection -> resubmit -> recover loop in one screenful.
 
 Self-contained: writes synthetic data, stubs, and all scratch under a
-temporary directory.
+temporary directory.  A demo of the supervision plane, not of the compute:
+it pins the CPU backend on purpose and never opens the chip.
 """
 
 import json

@@ -6,6 +6,9 @@ Usage: python chaos_driver.py <spec.json>
 Exit codes: 0 workflow ok, 1 workflow failed, KILL_EXIT_CODE (113) injected
 kill, REQUEUE_EXIT_CODE (114) graceful drain after SIGTERM/preempt — rerun
 with the same spec to resume.
+
+A test tool: it pins the CPU backend on purpose (the chaos suite exercises
+fault handling, not the chip).
 """
 
 import json

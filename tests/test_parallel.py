@@ -599,3 +599,52 @@ def test_replication_fence_detects_varying_escape():
     )
     with pytest.raises(AssertionError):
         _assert_shards_identical(leaked, "leaked rank")
+
+
+# -- entry-point device / compile-cache policy (parallel/mesh.py) -------------
+
+
+def test_backend_devices_never_hands_back_another_backends_devices():
+    """``target="tpu"`` with no TPU raises (naming what jax did find);
+    ``"local"`` is the CPU backend's devices and nothing else."""
+    with pytest.raises(RuntimeError, match="no TPU devices are visible"):
+        backend_devices("tpu")
+    assert {d.platform for d in backend_devices("local")} == {"cpu"}
+    with pytest.raises(ValueError):
+        backend_devices("gpu")
+
+
+@pytest.fixture
+def restore_cache_dir():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch, restore_cache_dir):
+    """Where JAX_COMPILATION_CACHE_DIR is set the package sets nothing (jax
+    reads it); where it is not, the cache is <checkout>/.jax_cache — a fixed
+    path, because the path is part of the cache key."""
+    import os
+
+    from cluster_tools_tpu.parallel import mesh as mesh_mod
+
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert mesh_mod.configure_compile_cache() == "/somewhere/else"
+    assert jax.config.jax_compilation_cache_dir is None  # untouched
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert mesh_mod.configure_compile_cache() == os.path.join(repo, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == os.path.join(repo, ".jax_cache")
+
+
+def test_use_cpu_backend_says_so_only_when_it_overrides(caplog):
+    from cluster_tools_tpu.parallel import mesh as mesh_mod
+
+    # tests run with jax_platforms=cpu: nothing to override, nothing said
+    with caplog.at_level("WARNING", logger=mesh_mod.__name__):
+        mesh_mod.use_cpu_backend("target='local'")
+    assert not caplog.records
+    assert jax.config.jax_platforms == "cpu"

@@ -236,6 +236,30 @@ def test_auto_plane_floor_and_override(tmp_path, monkeypatch):
     assert np.array_equal(labels_h, labels_c)
 
 
+@pytest.mark.parametrize("exc", [ImportError, AttributeError, TypeError])
+def test_auto_plane_does_not_absorb_program_defects(tmp_path, monkeypatch, exc):
+    """A moved API or a bad call inside the plane is a defect, not a fault:
+    `auto` must raise it (through the unsharded-solve ladder too) instead
+    of landing on the host rung with every test still green."""
+    n, edges, costs, node_shard = _grid_problem(g=6, shards=4)
+    monkeypatch.setenv("CT_REDUCE_PLANE_MIN_EDGES", "1")
+
+    def broken(self, probs, level, deadline):
+        raise exc("defect in the level program")
+
+    monkeypatch.setattr(rt.CollectiveReducePlane, "_dispatch", broken)
+    with pytest.raises(exc):
+        _solve("auto", n, edges, costs, node_shard, tmp_path=tmp_path)
+    with pytest.raises(exc):
+        rt.solve_with_reduce_tree(
+            n, edges, costs, node_shard=node_shard, solver_shards=4,
+            fanout=2, failures_path=str(tmp_path / "failures.json"),
+            task_name="plane_solve",
+            unsharded=lambda: np.zeros(n, np.int64),
+        )
+    assert not (tmp_path / "failures.json").exists()
+
+
 def test_env_plane_override_wins(monkeypatch):
     """CT_REDUCE_PLANE is the operator kill-switch: it overrides the
     call-site knob in both directions."""

@@ -6,6 +6,8 @@ and the operator progress view.  CPU-only, tier-1 fast."""
 import importlib.util
 import json
 import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -749,3 +751,23 @@ def test_serve_cli_status_requires_endpoint(tmp_path):
 
     with pytest.raises(FileNotFoundError):
         serve_cli.cmd_status(str(tmp_path))
+
+
+def test_serve_tpu_without_a_tpu_exits_at_start_with_the_reason(tmp_path):
+    """``serve --tpu`` opens the accelerator BEFORE it binds: a member that
+    cannot have the chip (none here; on a chip machine, a sibling holding
+    it) exits non-zero at start and says why — it never binds, so nothing
+    can be routed to it and fail at the first request instead."""
+    base = tmp_path / "srv"
+    proc = subprocess.run(
+        [sys.executable, "-m", "cluster_tools_tpu.serve",
+         "--base-dir", str(base), "--tpu"],
+        capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": os.pathsep.join(
+            [os.path.dirname(os.path.dirname(__file__)),
+             os.environ.get("PYTHONPATH", "")])},
+    )
+    assert proc.returncode == 1, proc.stderr[-2000:]
+    assert "cannot open the accelerator" in proc.stderr
+    assert "no TPU devices are visible" in proc.stderr
+    assert not (base / "server.json").exists()  # never bound

@@ -53,6 +53,22 @@ def test_generator_is_deterministic_and_exact():
     assert b1[interfaces].mean() > b1[inner].mean() + 0.15
 
 
+def test_generator_output_is_pinned():
+    """The volumes the quality bounds (and chip_smoke.py's data) rest on:
+    digest of the ground truth and mask as the generator made them before
+    its coordinate grid went from a materialised (z, y, x, 3) array to
+    broadcast axes (PR 24) — a faster generator must stay the same
+    generator, bit for bit.  (The boundary map derives from the ground
+    truth through scipy and ``exp``, untouched by that change and not
+    pinned: a libm may round its last bit differently.)"""
+    import hashlib
+
+    _, g, m = synthetic_em_volume(shape=(16, 64, 64), n_objects=6, seed=3)
+    assert hashlib.sha256(g.tobytes() + m.tobytes()).hexdigest() == (
+        "c5b7da6e1afc1857c2caaf4e9bc9d4a8a3b98f744a67cee7370fcd69d170d57e"
+    )
+
+
 def _run_e2e(workspace, two_d: bool):
     tmp_folder, config_dir, root = workspace
     shape = (24, 96, 96)

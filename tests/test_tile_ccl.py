@@ -139,17 +139,3 @@ def test_tiled_full_pallas_interpret(rng):
     assert_labels_equivalent(
         np.asarray(finalize_labels(jnp.asarray(np.asarray(lab)))), ref
     )
-
-
-def test_pallas_doubling_kernel_matches_unit_step(rng):
-    """The run-doubling propagation variant is exact: identical within-tile
-    labels to the unit-step kernel on adversarial masks."""
-    from cluster_tools_tpu.ops.pallas_kernels import tile_ccl_pallas
-
-    for p, seed in ((0.5, 0), (0.75, 1), (0.2, 2)):
-        mask = jnp.asarray(np.random.default_rng(seed).random((16, 32, 256)) < p)
-        a = np.asarray(tile_ccl_pallas(mask, tile=(16, 16, 128), interpret=True))
-        b = np.asarray(
-            tile_ccl_pallas(mask, tile=(16, 16, 128), interpret=True, doubling=True)
-        )
-        np.testing.assert_array_equal(a, b)
