@@ -278,7 +278,6 @@ def absorbed_failures(tmp_folder: str) -> list:
 
 _DEV_RE = re.compile(r"(executor|mesh)\.devices=\[([^\]]*)\]")
 _KERNELS_RE = re.compile(r"kernels=(\{[^}]*\})")
-_PEAK_RE = re.compile(r"device\.peak_bytes=(\{[^}]*\})")
 
 
 def task_logs(tmp_folder: str) -> str:
@@ -317,7 +316,13 @@ def check_phase_records(phase: str, tmp_folder: str, platform: str,
         )
     logs = task_logs(tmp_folder)
     kernels = sorted(set(_KERNELS_RE.findall(logs)))
-    peaks = [json.loads(p.replace("'", '"')) for p in _PEAK_RE.findall(logs)]
+    # the tasks' success manifests carry memory_stats() peaks per device
+    peaks = []
+    for mf in sorted(glob.glob(os.path.join(tmp_folder, "*.success.json"))):
+        with open(mf) as f:
+            memory = json.load(f).get("device_memory")
+        if memory:
+            peaks.append(memory)
     return dict(devices=sorted(set(devs)), kernels=kernels, peaks=peaks)
 
 
@@ -834,7 +839,8 @@ class Smoke:
         say(f"  {name}: per-device memory_stats() peak bytes: {peaks}")
         if self.rehearse:
             return  # the CPU backend reports no memory_stats
-        if len(peaks) != n or not all(v > 0 for v in peaks.values()):
+        if len(peaks) != n or not all(
+                v.get("peak_bytes_in_use", 0) > 0 for v in peaks.values()):
             raise PhaseFailed(
                 f"{name}: expected a non-zero peak on all {n} devices, got {peaks}"
             )

@@ -79,6 +79,19 @@ def _faults():
     return _faults_mod
 
 
+_trace_mod = None
+
+
+def _trace():
+    # lazily bound once, as _faults is: runtime/ imports this package
+    global _trace_mod
+    if _trace_mod is None:
+        from ..runtime import trace as _tm
+
+        _trace_mod = _tm
+    return _trace_mod
+
+
 def _inject(site: str, voxels: Optional[int] = None) -> Optional[int]:
     """Fault-injection hook for the container IO layer (sites ``io_read`` /
     ``io_write``; see runtime/faults.py).  A no-op unless an injector is
@@ -680,32 +693,43 @@ class Dataset(_ChecksumOps):
         cache.invalidate([key for key, _box in cover])
 
     def __getitem__(self, bb) -> np.ndarray:
-        bid = _inject("io_read")
-        _hang("io_read", bid)
-        self._apply_read_rot(bb, bid)
-        plan = self._begin_cached_read(bb)
-        if plan is None:
-            arr = np.asarray(self._store[bb].read().result())
-            _chunk_cache.get_chunk_cache().record_direct(arr.nbytes)
-            return self._postread(bb, arr)
-        arr = self._finish_cached_read(plan)
-        # a failed digest verify must not leave the bad chunks resident:
-        # the verifying reader evicts before attempting lineage repair
-        return self._postread(bb, arr, evict=lambda: self._evict_plan(plan))
+        # the doorway's own span (io.read / io.write): every caller's reads
+        # and writes, timed where they happen (docs/OBSERVABILITY.md)
+        with _trace().span("io.read", key=self._label) as sp:
+            bid = _inject("io_read")
+            _hang("io_read", bid)
+            self._apply_read_rot(bb, bid)
+            plan = self._begin_cached_read(bb)
+            if plan is None:
+                arr = np.asarray(self._store[bb].read().result())
+                _chunk_cache.get_chunk_cache().record_direct(arr.nbytes)
+                arr = self._postread(bb, arr)
+            else:
+                arr = self._finish_cached_read(plan)
+                # a failed digest verify must not leave the bad chunks
+                # resident: the verifying reader evicts before attempting
+                # lineage repair
+                arr = self._postread(
+                    bb, arr, evict=lambda: self._evict_plan(plan)
+                )
+            sp.note(nbytes=int(arr.nbytes))
+            return arr
 
     def __setitem__(self, bb, value) -> None:
-        bid = _inject("io_write", voxels=getattr(value, "size", None))
-        _hang("io_write", bid)
-        value = np.asarray(value, dtype=self.dtype)
-        try:
-            self._store[bb].write(value).result()
-            self._after_write(bb, value, bid)
-        finally:
-            # in a finally: a write that RAISES may still have landed some
-            # chunks (partial multi-chunk store, ENOSPC mid-region, sidecar
-            # failure after the data landed) — stale pre-write entries must
-            # not outlive any of those either
-            self._invalidate_cached_region(bb)
+        with _trace().span("io.write", key=self._label) as sp:
+            bid = _inject("io_write", voxels=getattr(value, "size", None))
+            _hang("io_write", bid)
+            value = np.asarray(value, dtype=self.dtype)
+            sp.note(nbytes=int(value.nbytes))
+            try:
+                self._store[bb].write(value).result()
+                self._after_write(bb, value, bid)
+            finally:
+                # in a finally: a write that RAISES may still have landed
+                # some chunks (partial multi-chunk store, ENOSPC mid-region,
+                # sidecar failure after the data landed) — stale pre-write
+                # entries must not outlive any of those either
+                self._invalidate_cached_region(bb)
 
     def read_async(self, bb):
         """Start an async read; returns a future with ``.result()`` -> numpy.

@@ -81,6 +81,7 @@ def _edt_1d_axis(f: jnp.ndarray, axis: int, w: float, radius: int) -> jnp.ndarra
 
 
 @partial(jax.jit, static_argnames=("sampling", "radii", "impl", "interpret"))
+@jax.named_scope("edt")
 def _dt_squared_impl(
     mask: jnp.ndarray,
     sampling: Tuple[float, ...],
@@ -123,6 +124,7 @@ def _pallas_axis_cascade(
     return f[:z, :y, :x]
 
 
+@jax.named_scope("edt")
 def edt_axis_pass(
     f: jnp.ndarray, axis: int, w: float, radius: int, impl: str = "auto"
 ) -> jnp.ndarray:

@@ -135,6 +135,7 @@ def tile_ccl_pallas(
             tile, lambda i, j, k: (i, j, k), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="tile_ccl",
     )(mask.astype(jnp.int32))
 
 
@@ -228,6 +229,7 @@ def tile_ws_propagate_pallas(
             tile, lambda i, j, k: (i, j, k), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="tile_ws_propagate",
     )(dirs.astype(jnp.int32), seeds_or_invalid.astype(jnp.int32))
 
 
@@ -282,6 +284,7 @@ def edt_cascade_pallas(
             tile, lambda i, j, k: (i, j, k), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="edt_cascade",
     )(f.astype(jnp.float32))
 
 
@@ -336,4 +339,5 @@ def apply_remap_pallas(
             tile, lambda i, j, k: (i, j, k), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="apply_remap",
     )(old3, new3, labels)

@@ -597,57 +597,61 @@ def _label_components_tiled_jit(
         pair_cap = _auto_cap(zp * yp * xp, DEFAULT_PAIR_CAP, 32)
     if edge_cap is None:
         edge_cap = _auto_cap(zp * yp * xp, DEFAULT_EDGE_CAP, 128)
-    m = mask.astype(bool)
-    if padded:
-        m = jnp.pad(m, ((0, zp - z), (0, yp - y), (0, xp - x)))
-
     if impl == "pallas":
         from .pallas_kernels import apply_remap_pallas, tile_ccl_pallas
 
-        labels = tile_ccl_pallas(m, tile=tile, interpret=interpret)
-    else:
-        labels = tile_local_labels_xla(m, tile)
+    # first level: every tile labelled on its own
+    with jax.named_scope("ccl.tile"):
+        m = mask.astype(bool)
+        if padded:
+            m = jnp.pad(m, ((0, zp - z), (0, yp - y), (0, xp - x)))
+        if impl == "pallas":
+            labels = tile_ccl_pallas(m, tile=tile, interpret=interpret)
+        else:
+            labels = tile_local_labels_xla(m, tile)
 
-    ea, eb, root_a, root_b, n_edges, overflow = merge_face_pairs(
-        labels, tile, pair_cap=pair_cap, edge_cap=edge_cap
-    )
-
-    if impl == "pallas":
-        n_tiles = (zp // tz) * (yp // ty) * (xp // tx)
-        v = jnp.concatenate([ea, eb])
-        r = jnp.concatenate([root_a, root_b])
-        changed = (v < BIG) & (r != v)
-        tids = jnp.where(
-            changed, _tile_id_of(v, (zp, yp, xp), tile), jnp.int32(BIG)
-        )
-        old_tbl, new_tbl, tbl_overflow = build_remap_tables(
-            tids, v, r, n_tiles, table_cap=table_cap
+    # second level: equivalences across tile faces, solved and applied
+    with jax.named_scope("ccl.merge"):
+        ea, eb, root_a, root_b, n_edges, overflow = merge_face_pairs(
+            labels, tile, pair_cap=pair_cap, edge_cap=edge_cap
         )
 
-        def fast(args):
-            labels, old_tbl, new_tbl = args
-            return apply_remap_pallas(
-                labels, old_tbl, new_tbl, tile=tile, cap=table_cap,
-                interpret=interpret,
+        if impl == "pallas":
+            n_tiles = (zp // tz) * (yp // ty) * (xp // tx)
+            v = jnp.concatenate([ea, eb])
+            r = jnp.concatenate([root_a, root_b])
+            changed = (v < BIG) & (r != v)
+            tids = jnp.where(
+                changed, _tile_id_of(v, (zp, yp, xp), tile), jnp.int32(BIG)
+            )
+            old_tbl, new_tbl, tbl_overflow = build_remap_tables(
+                tids, v, r, n_tiles, table_cap=table_cap
             )
 
-        def slow(args):
-            labels, _, _ = args
-            return resolve_labels_gather(labels, ea, eb, root_a, root_b)
+            def fast(args):
+                labels, old_tbl, new_tbl = args
+                return apply_remap_pallas(
+                    labels, old_tbl, new_tbl, tile=tile, cap=table_cap,
+                    interpret=interpret,
+                )
 
-        resolved = lax.cond(tbl_overflow, slow, fast, (labels, old_tbl, new_tbl))
-    else:
-        resolved = resolve_labels_gather(labels, ea, eb, root_a, root_b)
+            def slow(args):
+                labels, _, _ = args
+                return resolve_labels_gather(labels, ea, eb, root_a, root_b)
 
-    n_orig = z * y * x
-    if padded:
-        resolved = resolved[:z, :y, :x]
-        # padded-flat representative -> original-flat representative
-        vz = resolved // (yp * xp)
-        vy = (resolved // xp) % yp
-        vx = resolved % xp
-        orig = ((vz * y + vy) * x + vx).astype(jnp.int32)
-        out = jnp.where(resolved >= BIG, jnp.int32(n_orig), orig)
-    else:
-        out = jnp.where(resolved >= BIG, jnp.int32(n_orig), resolved)
+            resolved = lax.cond(tbl_overflow, slow, fast, (labels, old_tbl, new_tbl))
+        else:
+            resolved = resolve_labels_gather(labels, ea, eb, root_a, root_b)
+
+        n_orig = z * y * x
+        if padded:
+            resolved = resolved[:z, :y, :x]
+            # padded-flat representative -> original-flat representative
+            vz = resolved // (yp * xp)
+            vy = (resolved // xp) % yp
+            vx = resolved % xp
+            orig = ((vz * y + vy) * x + vx).astype(jnp.int32)
+            out = jnp.where(resolved >= BIG, jnp.int32(n_orig), orig)
+        else:
+            out = jnp.where(resolved >= BIG, jnp.int32(n_orig), resolved)
     return out, overflow

@@ -1071,6 +1071,7 @@ def _ws_static_plan(shape, tile, exit_cap, fill_cap):
     return tile, (zp, yp, xp), exit_cap, fill_cap
 
 
+@jax.named_scope("ws.flow")
 def _ws_flow_core(
     height: jnp.ndarray,
     seeds: jnp.ndarray,
@@ -1104,23 +1105,45 @@ def _ws_flow_core(
         s = jnp.pad(s, pads)
         valid = jnp.pad(valid, pads)
 
-    dirs = descent_directions(h, s > 0, valid)
-    sv = jnp.where(valid, s, -1)
+    with jax.named_scope("ws.flow.descent"):
+        dirs = descent_directions(h, s > 0, valid)
+        sv = jnp.where(valid, s, -1)
 
-    if impl == "pallas":
-        from .pallas_kernels import apply_remap_pallas, tile_ws_propagate_pallas
+    with jax.named_scope("ws.flow.propagate"):
+        if impl == "pallas":
+            from .pallas_kernels import tile_ws_propagate_pallas
 
-        values = tile_ws_propagate_pallas(dirs, sv, tile=tile, interpret=interpret)
-    else:
-        values = tile_ws_propagate_xla(dirs, sv, tile)
+            values = tile_ws_propagate_pallas(
+                dirs, sv, tile=tile, interpret=interpret
+            )
+        else:
+            values = tile_ws_propagate_xla(dirs, sv, tile)
 
     # cross-tile exits: collect, chase, remap
-    codes, code_tiles, overflow = collect_negative_values(values, tile, exit_cap)
-    finals, chase_unconverged = chase_exits(values, codes)
+    with jax.named_scope("ws.flow.exits"):
+        codes, code_tiles, overflow = collect_negative_values(
+            values, tile, exit_cap
+        )
+    with jax.named_scope("ws.flow.chase"):
+        finals, chase_unconverged = chase_exits(values, codes)
     overflow = overflow | chase_unconverged
+    values = _remap_exits(
+        values, codes, code_tiles, finals, impl, tile, table_cap, interpret
+    )
+    return values, h, overflow
+
+
+@jax.named_scope("ws.flow.exits")
+def _remap_exits(values, codes, code_tiles, finals, impl, tile, table_cap,
+                 interpret):
+    """Write every chased exit code's final value back into ``values``."""
+    zp, yp, xp = values.shape
+    tz, ty, tx = tile
     n_tiles = (zp // tz) * (yp // ty) * (xp // tx)
 
     if impl == "pallas":
+        from .pallas_kernels import apply_remap_pallas
+
         changed = (codes <= -2) & (finals != codes)
         tids = jnp.where(changed, code_tiles, jnp.int32(BIG))
         old_tbl, new_tbl, tbl_overflow = build_remap_tables(
@@ -1137,12 +1160,11 @@ def _ws_flow_core(
             v, _, _ = args
             return _resolve_codes_gather(v, codes, finals)
 
-        values = lax.cond(tbl_overflow, slow, fast, (values, old_tbl, new_tbl))
-    else:
-        values = _resolve_codes_gather(values, codes, finals)
-    return values, h, overflow
+        return lax.cond(tbl_overflow, slow, fast, (values, old_tbl, new_tbl))
+    return _resolve_codes_gather(values, codes, finals)
 
 
+@jax.named_scope("ws.fill")
 def _ws_fill_core(
     values: jnp.ndarray,
     h: jnp.ndarray,
@@ -1176,17 +1198,20 @@ def _ws_fill_core(
     if fill_rounds is None:
         fill_rounds = _auto_fill_rounds(zp * yp * xp)
     if fill_mode == "dense":
-        values, fill_unconv = fill_unseeded_basins_dense(
-            values, h, max_rounds=fill_rounds
-        )
+        with jax.named_scope("ws.fill.dense"):
+            values, fill_unconv = fill_unseeded_basins_dense(
+                values, h, max_rounds=fill_rounds
+            )
         overflow = fill_unconv > 0
         out = jnp.where(values > 0, values, 0).astype(jnp.int32)
         if padded:
             out = out[:z, :y, :x]
         return out, overflow
-    fill_vals, fill_finals, overflow = fill_unseeded_basins(
-        values, h, fill_cap=fill_cap, max_rounds=fill_rounds, adj_cap=adj_cap
-    )
+    with jax.named_scope("ws.fill.capacity"):
+        fill_vals, fill_finals, overflow = fill_unseeded_basins(
+            values, h, fill_cap=fill_cap, max_rounds=fill_rounds,
+            adj_cap=adj_cap,
+        )
     n_tiles = (zp // tz) * (yp // ty) * (xp // tx)
 
     if impl == "pallas":
@@ -1232,6 +1257,7 @@ def _ws_fill_core(
     return out, overflow
 
 
+@jax.named_scope("ws.seeds")
 def _dt_seeds_core(
     boundaries: jnp.ndarray,
     mask: Optional[jnp.ndarray],

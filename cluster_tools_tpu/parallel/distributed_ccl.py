@@ -209,13 +209,9 @@ def sharded_label_components(
     if not 1 <= connectivity <= mask.ndim:
         raise ValueError(f"connectivity must be in [1, {mask.ndim}]")
     axes = _norm_shard_axes(axis_name, axis_size, shard_axis, shard_axes)
-    shape = mask.shape
-    n_slab = int(np.prod(shape))
-    n_shards = int(np.prod([s for _, _, s in axes]))
 
-    rank = linearized_shard_rank(axes)
-
-    # 1. per-shard CCL; globalize so labels are unique across shards
+    # 1. per-shard CCL (the tiled machinery carries its own ccl.tile /
+    # ccl.merge scopes)
     use_tiled = impl != "legacy" and mask.ndim == 3 and connectivity == 1
     if use_tiled:
         from ..ops.tile_ccl import label_components_tiled
@@ -225,8 +221,26 @@ def sharded_label_components(
             mask, connectivity=connectivity, impl=tiled_impl
         )
     else:
-        raw = label_components(mask, connectivity=connectivity)
+        with jax.named_scope("ccl.tile"):
+            raw = label_components(mask, connectivity=connectivity)
         tiled_overflow = None
+    return _globalize_and_merge(
+        raw, tiled_overflow, axes, connectivity, max_labels_per_shard,
+        return_overflow,
+    )
+
+
+@jax.named_scope("ccl.merge")
+def _globalize_and_merge(
+    raw, tiled_overflow, axes, connectivity, max_labels_per_shard,
+    return_overflow,
+):
+    """Steps 2-4 of :func:`sharded_label_components`: make the per-shard
+    labels unique over the mesh, exchange the cross-shard equivalences,
+    solve them replicated and relabel the local shard."""
+    n_slab = int(np.prod(raw.shape))
+    n_shards = int(np.prod([s for _, _, s in axes]))
+    rank = linearized_shard_rank(axes)
     # constant-False flag carrying the shard data's vma type, so the pmax
     # reduction below is legal with or without compaction
     overflow = raw.ravel()[0] * 0 > 0

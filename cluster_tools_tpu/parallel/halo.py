@@ -16,10 +16,12 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 
+@jax.named_scope("step.halo")
 def exchange_halo(
     x: jnp.ndarray,
     halo: int,

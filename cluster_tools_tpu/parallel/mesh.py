@@ -119,16 +119,23 @@ def describe_devices(devices) -> list:
     return [f"{d.platform}:{d.id}" for d in np.asarray(devices).flat]
 
 
-def device_peak_bytes(devices) -> Dict[str, int]:
-    """Peak device memory per device so far in this process
-    (``memory_stats()["peak_bytes_in_use"]``; empty where the backend does
-    not report it, as on the CPU) — logged by the tasks after a sweep or a
-    mesh step, so that "everything landed on device 0" is visible."""
+def device_peak_bytes(devices) -> Dict[str, Dict[str, int]]:
+    """Peak device memory per device so far in this process, from
+    ``memory_stats()``: ``peak_bytes_in_use`` (the allocator's) and
+    ``peak_bytes_reserved`` (on the TPU runtime a loaded program's
+    temporaries are reserved beside the allocator, not allocated from it,
+    so the first alone leaves out most of what a step holds).  Empty where
+    the backend reports neither, as on the CPU.  The tasks put it into
+    their success manifest as ``device_memory`` after a sweep or a mesh
+    step, so that "everything landed on device 0" is visible."""
     peaks = {}
     for name, d in zip(describe_devices(devices), np.asarray(devices).flat):
-        stats = d.memory_stats()
-        if stats and "peak_bytes_in_use" in stats:
-            peaks[name] = int(stats["peak_bytes_in_use"])
+        stats = d.memory_stats() or {}
+        row = {k: int(stats[k])
+               for k in ("peak_bytes_in_use", "peak_bytes_reserved")
+               if k in stats}
+        if row:
+            peaks[name] = row
     return peaks
 
 

@@ -46,6 +46,7 @@ from .halo import crop_halo, exchange_halo, neighbor_face
 from .mesh import mesh_axis_sizes
 
 
+@jax.named_scope("step.stitch")
 def _stitch_ws_fragments(
     ws: jnp.ndarray,
     vol: jnp.ndarray,
@@ -87,6 +88,70 @@ def _stitch_ws_fragments(
     )
 
 
+def exchange_all(x, halo: int, sp_axes: Sequence[ShardAxis], fill):
+    """One ``ppermute`` per sharded axis; later exchanges forward the halos
+    received by earlier ones, so diagonal (corner) regions arrive with the
+    correct neighbor-of-neighbor data."""
+    for a, name, size in sp_axes:
+        x = exchange_halo(x, halo, a, name, size, fill=fill)
+    return x
+
+
+@jax.named_scope("step.globalize")
+def globalize_fragments(
+    ws: jnp.ndarray,
+    halo: int,
+    sp_axes: Sequence[ShardAxis],
+    rank: jnp.ndarray,
+    n_pad: int,
+    max_labels_per_shard: Optional[int],
+) -> Tuple[jnp.ndarray, int, Optional[jnp.ndarray]]:
+    """Crop the halo and make watershed fragment ids unique over the mesh
+    by shard rank: ``(ws, span, overflow)``, shared by the fused step and
+    the split chain's fill stage.  With a compaction cap, fragment ids are
+    densified first so the label space is ``n_shards * cap`` instead of
+    ``n_shards * padded_voxels`` (the int32 ceiling that blocked teravoxel
+    volumes); ``overflow`` is then the int32 flag of a shard with more
+    fragments than the cap, else None."""
+    n_shards = int(np.prod([s for _, _, s in sp_axes]))
+    for a, _, _ in sp_axes:
+        ws = crop_halo(ws, halo, a)
+    if max_labels_per_shard is None:
+        if n_shards * n_pad >= 2**31:
+            raise ValueError(
+                f"{n_shards} shards of {n_pad} padded voxels overflow "
+                "int32 labels; pass max_labels_per_shard"
+            )
+        return jnp.where(ws > 0, ws + rank * jnp.int32(n_pad), 0), n_pad, None
+    cap = int(max_labels_per_shard)
+    if n_shards * (cap + 1) >= 2**31:
+        raise ValueError(
+            f"{n_shards} shards x {cap} ws fragments overflow int32"
+        )
+    # ws fragment ids are PADDED-volume flat indices (+1), which exceed
+    # the halo-cropped labels.size — pass the padded span or the bitmap
+    # fast path silently never engages here
+    ws, n_frag = relabel_consecutive(
+        ws, max_labels=cap, value_bound=n_pad + 1
+    )
+    ws = jnp.where(ws > 0, ws + rank * jnp.int32(cap + 1), 0)
+    return ws, cap + 1, (n_frag > cap).astype(jnp.int32)
+
+
+@jax.named_scope("step.count")
+def count_foreground(
+    cc_lab: jnp.ndarray, sp_axes: Sequence[ShardAxis], dp_axis: str
+) -> jnp.ndarray:
+    """Global foreground voxel count over the full mesh (dp and all sp
+    axes).  Summed in float32: an int32 psum would wrap past 2**31 global
+    foreground voxels (the teravoxel layouts this step supports); f32 is
+    exact below 2**24 per shard and ~1e-7 relative beyond."""
+    n_fg = jnp.sum(cc_lab > 0).astype(jnp.float32)
+    for _, name, _ in sp_axes:
+        n_fg = lax.psum(n_fg, name)
+    return lax.psum(n_fg, dp_axis)
+
+
 def _ws_ccl_shard(
     boundaries: jnp.ndarray,
     *,
@@ -124,14 +189,6 @@ def _ws_ccl_shard(
             f"(got a {boundaries.ndim - 1}-D volume)"
         )
 
-    def exchange_all(x, fill):
-        # one ppermute per sharded axis; later exchanges forward the halos
-        # received by earlier ones, so diagonal (corner) regions arrive with
-        # the correct neighbor-of-neighbor data
-        for a, name, size in sp_axes:
-            x = exchange_halo(x, halo, a, name, size, fill=fill)
-        return x
-
     ws_out = []
     cc_out = []
     # per-shard ws-compaction overflow (varies over the mesh); cc overflow
@@ -144,7 +201,7 @@ def _ws_ccl_shard(
         vol = boundaries[b]
         # border fill = 1.0 (pure boundary) so basins never leak out of the
         # volume
-        padded = exchange_all(vol, fill=1.0)
+        padded = exchange_all(vol, halo, sp_axes, fill=1.0)
         if tiled_ok:
             from ..ops.tile_ws import dt_watershed_tiled
 
@@ -168,7 +225,7 @@ def _ws_ccl_shard(
                     max_distance=dt_max_distance,
                     impl="xla" if impl in ("xla", "tiled") else "auto",
                 )
-                dist_pad = exchange_all(dist_sq, fill=0.0)
+                dist_pad = exchange_all(dist_sq, halo, sp_axes, fill=0.0)
             ws, ws_over = dt_watershed_tiled(
                 padded,
                 threshold=threshold,
@@ -186,38 +243,12 @@ def _ws_ccl_shard(
                 connectivity=connectivity,
                 dt_max_distance=dt_max_distance,
             )
-        for a, _, _ in sp_axes:
-            ws = crop_halo(ws, halo, a)
-        # globalize watershed fragment ids by shard rank; with a compaction
-        # cap, fragment ids are densified first so the label space is
-        # n_shards * cap instead of n_shards * padded_voxels (the int32
-        # ceiling that blocked teravoxel volumes)
-        n_pad = int(np.prod(padded.shape))
-        if max_labels_per_shard is not None:
-            cap = int(max_labels_per_shard)
-            if n_shards * (cap + 1) >= 2**31:
-                raise ValueError(
-                    f"{n_shards} shards x {cap} ws fragments overflow int32"
-                )
-            # ws fragment ids are PADDED-volume flat indices (+1), which
-            # exceed the halo-cropped labels.size — pass the padded span
-            # or the bitmap fast path silently never engages here
-            ws, n_frag = relabel_consecutive(
-                ws, max_labels=cap, value_bound=n_pad + 1
-            )
-            ws_overflow = jnp.maximum(
-                ws_overflow, (n_frag > cap).astype(jnp.int32)
-            )
-            ws = jnp.where(ws > 0, ws + rank * jnp.int32(cap + 1), 0)
-            ws_span = cap + 1
-        else:
-            if n_shards * n_pad >= 2**31:
-                raise ValueError(
-                    f"{n_shards} shards of {n_pad} padded voxels overflow "
-                    "int32 labels; pass max_labels_per_shard"
-                )
-            ws = jnp.where(ws > 0, ws + rank * jnp.int32(n_pad), 0)
-            ws_span = n_pad
+        ws, ws_span, frag_over = globalize_fragments(
+            ws, halo, sp_axes, rank, int(np.prod(padded.shape)),
+            max_labels_per_shard,
+        )
+        if frag_over is not None:
+            ws_overflow = jnp.maximum(ws_overflow, frag_over)
         if stitch_ws_threshold is not None and n_shards > 1:
             # cross-shard fragment merge: the "stitch" of BASELINE config 3,
             # device-resident (skipped at 1 shard — no cuts exist, and the
@@ -245,14 +276,7 @@ def _ws_ccl_shard(
 
     ws_lab = jnp.stack(ws_out)
     cc_lab = jnp.stack(cc_out)
-    # global foreground voxel count over the full mesh (dp and all sp axes).
-    # Summed in float32: an int32 psum would wrap past 2**31 global
-    # foreground voxels (the teravoxel layouts this step supports); f32 is
-    # exact below 2**24 per shard and ~1e-7 relative beyond
-    n_fg = jnp.sum(cc_lab > 0).astype(jnp.float32)
-    for _, name, _ in sp_axes:
-        n_fg = lax.psum(n_fg, name)
-    n_fg = lax.psum(n_fg, dp_axis)
+    n_fg = count_foreground(cc_lab, sp_axes, dp_axis)
     # mesh-wide label-compaction overflow flag (always False w/o compaction)
     for _, name, _ in sp_axes:
         ws_overflow = lax.pmax(ws_overflow, name)
@@ -347,4 +371,8 @@ def make_ws_ccl_step(
         out_specs=(spec, spec, P(), P()),
         check_vma=False,
     )
+    # the name the compiled module, its cache entry and every trace carry:
+    # jit_ws_ccl_step (named on the function itself: a wrapper of its own
+    # costs the trace half a second a job, PERF.md PR 28)
+    sharded.__name__ = sharded.__qualname__ = "ws_ccl_step"
     return jax.jit(sharded)

@@ -49,7 +49,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compat import shard_map
-from ..ops.ccl import _match_vma, relabel_consecutive
+from ..ops.ccl import _match_vma
 from ..ops.tile_ccl import DEFAULT_TABLE_CAP
 from ..ops.tile_ws import (
     _dt_seeds_core,
@@ -63,8 +63,12 @@ from .distributed_ccl import (
     sharded_label_components,
     sp_axes_for_mesh,
 )
-from .halo import crop_halo, exchange_halo
-from .pipeline import _stitch_ws_fragments
+from .pipeline import (
+    _stitch_ws_fragments,
+    count_foreground,
+    exchange_all,
+    globalize_fragments,
+)
 
 
 class SplitWsCclStep:
@@ -135,22 +139,14 @@ def make_ws_ccl_split(
     spec = P(dp_axis, *names)
     rep = P()
 
-    def _smap(body, in_specs, out_specs, donate=()):
-        fn = jax.jit(
-            shard_map(
-                body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            ),
-            donate_argnums=donate,
+    def _smap(name, body, in_specs, out_specs, donate=()):
+        sharded = shard_map(
+            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )
-        return fn
-
-    def exchange_all(x, fill):
-        # one ppermute per sharded axis; later exchanges forward the halos
-        # received by earlier ones, so corner regions arrive correctly
-        for a, name, size in sp_axes:
-            x = exchange_halo(x, halo, a, name, size, fill=fill)
-        return x
+        # the name the compiled module and every trace carry: jit_ws_ccl_<stage>
+        sharded.__name__ = sharded.__qualname__ = f"ws_ccl_{name}"
+        return jax.jit(sharded, donate_argnums=donate)
 
     def _reduce_all(v):
         for _, name, _ in sp_axes:
@@ -166,7 +162,7 @@ def make_ws_ccl_split(
         ovf = _match_vma(jnp.zeros((), jnp.int32), boundaries)
         for b in range(local_b):
             vol = boundaries[b]
-            padded = exchange_all(vol, fill=1.0)
+            padded = exchange_all(vol, halo, sp_axes, fill=1.0)
             dist_pad = None
             if exact_edt:
                 from .distributed_edt import (
@@ -179,7 +175,7 @@ def make_ws_ccl_split(
                     max_distance=dt_max_distance,
                     impl="xla" if impl in ("xla", "tiled") else "auto",
                 )
-                dist_pad = exchange_all(dist_sq, fill=0.0)
+                dist_pad = exchange_all(dist_sq, halo, sp_axes, fill=0.0)
             seeds, _, s_ovf = _dt_seeds_core(
                 padded, None, dist_pad, threshold=threshold,
                 sigma_seeds=0.0, min_seed_distance=min_seed_distance,
@@ -230,30 +226,11 @@ def make_ws_ccl_split(
                 fill_mode=fill_mode,
             )
             ovf = jnp.maximum(ovf, o.astype(jnp.int32))
-            for a, _, _ in sp_axes:
-                ws = crop_halo(ws, halo, a)
-            # globalize fragment ids by shard rank (identical arithmetic to
-            # the fused body — parallel/pipeline.py _ws_ccl_shard)
-            if max_labels_per_shard is not None:
-                cap = int(max_labels_per_shard)
-                if n_shards * (cap + 1) >= 2**31:
-                    raise ValueError(
-                        f"{n_shards} shards x {cap} ws fragments overflow int32"
-                    )
-                ws, n_frag = relabel_consecutive(
-                    ws, max_labels=cap, value_bound=n_pad + 1
-                )
-                ovf = jnp.maximum(ovf, (n_frag > cap).astype(jnp.int32))
-                ws = jnp.where(ws > 0, ws + rank * jnp.int32(cap + 1), 0)
-                ws_span = cap + 1
-            else:
-                if n_shards * n_pad >= 2**31:
-                    raise ValueError(
-                        f"{n_shards} shards of {n_pad} padded voxels overflow "
-                        "int32 labels; pass max_labels_per_shard"
-                    )
-                ws = jnp.where(ws > 0, ws + rank * jnp.int32(n_pad), 0)
-                ws_span = n_pad
+            ws, ws_span, frag_over = globalize_fragments(
+                ws, halo, sp_axes, rank, n_pad, max_labels_per_shard
+            )
+            if frag_over is not None:
+                ovf = jnp.maximum(ovf, frag_over)
             if stitch_ws_threshold is not None and n_shards > 1:
                 ws = _stitch_ws_fragments(
                     ws, boundaries[b], sp_axes, rank, ws_span,
@@ -280,26 +257,21 @@ def make_ws_ccl_split(
             ovf = jnp.maximum(ovf, cc_over.astype(jnp.int32))
             cc_out.append(cc)
         cc_lab = jnp.stack(cc_out)
-        # float32 psum: an int32 count would wrap past 2**31 global
-        # foreground voxels (same rationale as the fused step)
-        n_fg = jnp.sum(cc_lab > 0).astype(jnp.float32)
-        for _, name, _ in sp_axes:
-            n_fg = lax.psum(n_fg, name)
-        n_fg = lax.psum(n_fg, dp_axis)
+        n_fg = count_foreground(cc_lab, sp_axes, dp_axis)
         overflow = _reduce_all(ovf) > 0
         return cc_lab, n_fg, overflow
 
     stages = {
-        "seeds": _smap(seeds_body, (spec,), (spec, spec, rep)),
+        "seeds": _smap("seeds", seeds_body, (spec,), (spec, spec, rep)),
         # donate the padded volume (consumed by flow) and values/h
         # (consumed by fill) so peak HBM stays in the fused step's class
         "flow": _smap(
-            flow_body, (spec, spec, rep), (spec, spec, rep), donate=(0, 1)
+            "flow", flow_body, (spec, spec, rep), (spec, spec, rep), donate=(0, 1)
         ),
         "fill": _smap(
-            fill_body, (spec, spec, spec, rep), (spec, rep), donate=(0, 1)
+            "fill", fill_body, (spec, spec, spec, rep), (spec, rep), donate=(0, 1)
         ),
-        "cc": _smap(cc_body, (spec, rep), (spec, rep, rep)),
+        "cc": _smap("cc", cc_body, (spec, rep), (spec, rep, rep)),
     }
 
     def runner(boundaries, sync=None):

@@ -276,87 +276,101 @@ class BaseTask:
             )
         # the task.run span doubles as the runtime_s clock (CT008: trace
         # spans are the one timing source in runtime/) and carries the
-        # dependency uids the trace aggregator's critical path walks
+        # dependency uids the trace aggregator's critical path walks.
+        # Entered as a context, it also opens the profiler annotation that
+        # ties the ring's monotonic clock to a device trace's
         run_span = trace_mod.begin(
             "task.run", task=self.uid, task_name=self.task_name,
             deps=[d.uid for d in self.dependencies],
         )
-        # fault specs with a "tasks" filter target the running task's uid
-        faults_mod.set_current_task(self.uid)
-        io_snap = chunk_cache.snapshot()
-        disp_snap = executor_mod.dispatch_snapshot()
-        handoff_snap = handoff_mod.snapshot()
-        device_snap = device_pool_mod.snapshot()
-        solver_snap = contraction_mod.solver_snapshot()
-        tree_snap = reduce_tree_mod.solve_snapshot()
-        ok = False
         try:
-            result = self.run_impl() or {}
-            # finalize in-memory targets INSIDE the task context: forced
-            # `spill` faults filter on the producing task's uid
-            handoff_records = self._finalize_handoffs()
-            ok = True
-        finally:
-            faults_mod.set_current_task(None)
-            if not ok:
-                # a failing task still leaves its spans behind: the error'd
-                # task.run span and everything below it flush now, so the
-                # timeline of a crashed run shows exactly where it died
-                run_span.end(error=True)
-                self._flush_trace()
-        result["runtime_s"] = run_span.end()
-        result["target"] = self.target
-        if handoff_records:
-            # the DAG engine's resume contract (complete()): a memory-only
-            # record whose handle died with this process re-runs the task
-            result["handoffs"] = handoff_records
-        # chunk-IO + dispatch + handoff attribution: the counters' movement
-        # during this task, surfaced in the success manifest AND merged
-        # (additively, across resumed runs and cluster job processes) into
-        # the run-wide io_metrics.json next to failures.json — so the
-        # sharded sweep's dispatch amortization and the fusion layer's
-        # avoided storage round-trips are observable per task
-        # (docs/PERFORMANCE.md "Sharded sweeps" / "Task-graph fusion")
-        io_metrics = chunk_cache.delta(io_snap)
-        dispatch_metrics = executor_mod.dispatch_delta(disp_snap)
-        if any(dispatch_metrics.values()):
-            io_metrics.update(dispatch_metrics)
-        handoff_metrics = handoff_mod.delta(handoff_snap)
-        if any(handoff_metrics.values()):
-            io_metrics.update(handoff_metrics)
-        # device-plane attribution (docs/PERFORMANCE.md "Device-resident
-        # data plane"): h2d/d2h traffic, resident-pool hit rates, and the
-        # bytes fused consumers never re-staged, per task
-        device_metrics = device_pool_mod.delta(device_snap)
-        if any(device_metrics.values()):
-            io_metrics.update(device_metrics)
-        # solver attribution: contraction-engine calls/rounds/edge counts
-        # plus the reduce tree's per-level solve/merge movement, so the
-        # global solve is as observable as the I/O and dispatch paths
-        # (docs/PERFORMANCE.md "Distributed agglomeration")
-        solver_metrics = contraction_mod.solver_delta(solver_snap)
-        if any(solver_metrics.values()):
-            io_metrics.update(solver_metrics)
-        tree_metrics = reduce_tree_mod.solve_delta(tree_snap)
-        if any(tree_metrics.values()):
-            io_metrics.update(tree_metrics)
-        if any(io_metrics.values()):
-            result["io_metrics"] = io_metrics
-            try:
-                fu.record_io_metrics(
-                    fu.io_metrics_path(self.tmp_folder), self.uid, io_metrics
-                )
-            except Exception:
-                self.logger.warning(
-                    f"io_metrics recording failed:\n{traceback.format_exc()}"
-                )
-        self.output().write(result)
-        # flush this process's trace shard and (re)stitch the run timeline
-        # so trace.json + trace_summary.json track the run as it executes;
-        # the restitch re-reads every shard, so it is throttled to once per
-        # MERGE_MIN_INTERVAL_S per process — build() always merges at the
-        # end, so the finished timeline is current regardless
-        self._flush_trace(merge=True)
+            with run_span:
+                # fault specs with a "tasks" filter target the running
+                # task's uid
+                faults_mod.set_current_task(self.uid)
+                io_snap = chunk_cache.snapshot()
+                disp_snap = executor_mod.dispatch_snapshot()
+                handoff_snap = handoff_mod.snapshot()
+                device_snap = device_pool_mod.snapshot()
+                solver_snap = contraction_mod.solver_snapshot()
+                tree_snap = reduce_tree_mod.solve_snapshot()
+                compile_snap = trace_mod.compile_snapshot()
+                try:
+                    result = self.run_impl() or {}
+                    # finalize in-memory targets INSIDE the task context:
+                    # forced `spill` faults filter on the producing task's uid
+                    handoff_records = self._finalize_handoffs()
+                finally:
+                    faults_mod.set_current_task(None)
+        except BaseException:
+            # a failing task still leaves its spans behind: the error'd
+            # task.run span and everything below it flush now, so the
+            # timeline of a crashed run shows exactly where it died
+            self._flush_trace()
+            raise
+        # everything after run_impl returned (the counters' movement into
+        # the manifest and io_metrics.json, the success target, the trace
+        # flush) is the task.finalize span; it follows task.run, whose
+        # seconds stay the task's own work
+        with trace_mod.span("task.finalize", task=self.uid):
+            result["runtime_s"] = run_span.elapsed_s
+            result["target"] = self.target
+            if handoff_records:
+                # the DAG engine's resume contract (complete()): a memory-only
+                # record whose handle died with this process re-runs the task
+                result["handoffs"] = handoff_records
+            # chunk-IO + dispatch + handoff attribution: the counters' movement
+            # during this task, surfaced in the success manifest AND merged
+            # (additively, across resumed runs and cluster job processes) into
+            # the run-wide io_metrics.json next to failures.json — so the
+            # sharded sweep's dispatch amortization and the fusion layer's
+            # avoided storage round-trips are observable per task
+            # (docs/PERFORMANCE.md "Sharded sweeps" / "Task-graph fusion")
+            io_metrics = chunk_cache.delta(io_snap)
+            dispatch_metrics = executor_mod.dispatch_delta(disp_snap)
+            if any(dispatch_metrics.values()):
+                io_metrics.update(dispatch_metrics)
+            handoff_metrics = handoff_mod.delta(handoff_snap)
+            if any(handoff_metrics.values()):
+                io_metrics.update(handoff_metrics)
+            # device-plane attribution (docs/PERFORMANCE.md "Device-resident
+            # data plane"): h2d/d2h traffic, resident-pool hit rates, and the
+            # bytes fused consumers never re-staged, per task
+            device_metrics = device_pool_mod.delta(device_snap)
+            if any(device_metrics.values()):
+                io_metrics.update(device_metrics)
+            # solver attribution: contraction-engine calls/rounds/edge counts
+            # plus the reduce tree's per-level solve/merge movement, so the
+            # global solve is as observable as the I/O and dispatch paths
+            # (docs/PERFORMANCE.md "Distributed agglomeration")
+            solver_metrics = contraction_mod.solver_delta(solver_snap)
+            if any(solver_metrics.values()):
+                io_metrics.update(solver_metrics)
+            tree_metrics = reduce_tree_mod.solve_delta(tree_snap)
+            if any(tree_metrics.values()):
+                io_metrics.update(tree_metrics)
+            # did this task trace, lower, compile or read a program back, and
+            # which (docs/OBSERVABILITY.md "Compiles")
+            compile_metrics = trace_mod.compile_delta(compile_snap)
+            if any(compile_metrics.values()):
+                io_metrics["compile"] = compile_metrics
+            if any(io_metrics.values()):
+                result["io_metrics"] = io_metrics
+                try:
+                    fu.record_io_metrics(
+                        fu.io_metrics_path(self.tmp_folder), self.uid, io_metrics
+                    )
+                except Exception:
+                    self.logger.warning(
+                        f"io_metrics recording failed:\n{traceback.format_exc()}"
+                    )
+            self.output().write(result)
+            # flush this process's trace shard and (re)stitch the run timeline
+            # so trace.json + trace_summary.json track the run as it executes;
+            # the restitch re-reads every shard, so it is throttled to once per
+            # MERGE_MIN_INTERVAL_S per process — build() always merges at the
+            # end, so the finished timeline is current regardless
+            self._flush_trace(merge=True)
         self.logger.info(
             f"done {self.task_name} in {result['runtime_s']:.2f}s"
         )

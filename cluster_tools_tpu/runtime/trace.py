@@ -30,11 +30,20 @@ module is that layer (docs/OBSERVABILITY.md):
   complete spans per process/thread track, ``ph="i"`` instants for the
   degrade/fault/quarantine events of the attribution plane — a failure is
   visually adjacent to the latency it caused); :func:`summarize` computes
-  per-site latency aggregates (count, total, p50/p95/p99/max), the
+  per-site latency aggregates (count, total, self time, p50/p95/p99/max), the
   critical path through the task DAG (``task.run`` spans carry their
   dependency uids), and per-process overlap/utilization figures, written
   next to ``io_metrics.json`` as ``trace_summary.json`` and rendered by
   ``scripts/failures_report.py --trace``.
+- the program's **compiles**: one ``jax.monitoring`` listener turns JAX's
+  own trace / lower / backend-compile / cache-read phases into always-on
+  counters (:func:`compile_snapshot` / :func:`compile_delta`, per task in
+  ``io_metrics.json``) and, with the tracer on, into ``jax.*`` spans.
+- **one clock with a device trace**: with the tracer on, a span entered
+  as a context also opens a ``jax.profiler.TraceAnnotation``, so under a
+  profiler session the program's spans lie on ``/host:CPU`` of the
+  profiler's own trace, above the device operations.  JAX is never
+  imported here for it (:func:`_annotate`).
 
 Timing discipline (docs/ANALYSIS.md CT008): this module is the ONE place
 ``runtime/`` reads ``time.time`` / ``time.perf_counter`` — every other
@@ -50,6 +59,7 @@ from __future__ import annotations
 import json
 import os
 import socket
+import sys
 import threading
 import time
 from collections import deque
@@ -256,21 +266,36 @@ class Span:
 
     Hot-path discipline (the <5% bench-sweep overhead bar): the tracer
     reference is captured at construction (one singleton lookup per span,
-    not two) and the timestamp reads are bound locally."""
+    not two) and the timestamp reads are bound locally.
 
-    __slots__ = ("name", "args", "t0", "elapsed_s", "_recorded", "_tracer")
+    Entered as a context manager with the tracer on, the span also opens a
+    ``jax.profiler.TraceAnnotation`` of its name, so that under a profiler
+    session it lands on ``/host:CPU`` of the profiler's own trace, on the
+    device operations' clock (see :func:`_annotate`)."""
+
+    __slots__ = ("name", "args", "t0", "elapsed_s", "_recorded", "_tracer",
+                 "_annotation")
 
     def __init__(self, name: str, args: Dict[str, Any],
                  tracer: Optional["_Tracer"] = None):
         self.name = name
         self.args = args
         self._tracer = tracer
+        self._annotation = None
         self.t0 = time.monotonic()
         self.elapsed_s: Optional[float] = None
         self._recorded = False
 
+    def note(self, **args) -> None:
+        """Attach what is known only once the work is done (``nbytes`` of
+        an array that was just read)."""
+        self.args.update(args)
+
     def end(self, discard: bool = False, **extra) -> float:
         t1 = time.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self.elapsed_s is None:
             self.elapsed_s = t1 - self.t0
         if self._recorded or discard:
@@ -287,11 +312,29 @@ class Span:
         return self.elapsed_s
 
     def __enter__(self) -> "Span":
+        if (self._tracer or _get()).enabled:
+            self._annotation = _annotate(self.name)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.end(error=True) if exc_type is not None else self.end()
         return False
+
+
+def _annotate(name: str):
+    """An entered ``jax.profiler.TraceAnnotation``, or None in a process
+    that has not imported JAX (it has no profiler session to land on, and
+    this module never imports JAX for it).  Outside a profiler session an
+    annotation is one check of a flag."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        annotation = jax.profiler.TraceAnnotation(name)
+        annotation.__enter__()
+    except Exception:  # observability never fails the work it observes
+        return None
+    return annotation
 
 
 class _NullSpan:
@@ -300,6 +343,9 @@ class _NullSpan:
 
     __slots__ = ()
     elapsed_s = 0.0
+
+    def note(self, **args) -> None:
+        pass
 
     def end(self, discard: bool = False, **extra) -> float:
         return 0.0
@@ -355,6 +401,135 @@ def instant(name: str, **args) -> None:
     if not t.enabled:
         return
     t.record("i", name, time.monotonic(), 0.0, _context_args(args))
+
+
+# -- compiles: JAX's own monitoring events, as counters and as spans ----------
+
+#: JAX's duration events -> (span name, the counter its self time adds to).
+#: A listener is called when its event ENDS, so the span is [now - secs, now].
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": ("jax.trace", "trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("jax.lower", "lower_s"),
+    "/jax/core/compile/backend_compile_duration": (
+        "jax.backend_compile", "backend_s"),
+    "/jax/compilation_cache/cache_retrieval_time_sec": (
+        "jax.cache_load", "cache_load_s"),
+}
+_COMPILE_COUNTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "requests",
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+}
+#: names kept in ``programs_missed`` per process (the count is never capped)
+_MISSED_KEPT = 4096
+#: phases a thread keeps to tell nested ones from their neighbours
+_HEARD_KEPT = 1 << 15
+#: a phase shorter than this is counted but leaves no span: tracing one step
+#: traces some thousand library functions inside it, a few microseconds each
+_PHASE_SPAN_MIN_S = 1e-3
+
+_compile_lock = threading.Lock()
+_compile_counts: Dict[str, float] = {
+    "requests": 0, "cache_hits": 0, "backend_compiles": 0,
+    "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0, "cache_load_s": 0.0,
+}
+_compile_missed: List[str] = []
+_compile_local = threading.local()
+_compile_installed = False
+
+
+def _on_compile_event(event: str, **_) -> None:
+    key = _COMPILE_COUNTS.get(event)
+    if key is None:
+        return
+    if key == "cache_hits":
+        # read by the backend_compile event that closes around this hit
+        _compile_local.hit = True
+    with _compile_lock:
+        _compile_counts[key] += 1
+
+
+def _on_compile_duration(event: str, secs: float, **kw) -> None:
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    name, key = phase
+    now = time.monotonic()
+    start = now - secs
+    fun_name = kw.get("fun_name")
+    # self time: the phases nest (an inner jit is traced inside the outer's
+    # trace, lowering traces what it meets, the cache read lies inside the
+    # backend compile), and an inner phase ends, and is heard, before the
+    # one around it.  Whatever this thread heard since `start` lies inside
+    # this phase: its seconds are not this phase's own, so the four
+    # counters add up to the wall that compiling took.
+    heard = getattr(_compile_local, "heard", None)
+    if heard is None:
+        heard = _compile_local.heard = []
+    inner = 0.0
+    while heard and heard[-1][0] >= start:
+        inner += heard.pop()[1]
+    heard.append((start, secs))
+    # tracing one step hears some thousand library functions side by side
+    # before the trace around them ends; far older entries are top-level
+    del heard[:-_HEARD_KEPT]
+    with _compile_lock:
+        _compile_counts[key] += max(secs - inner, 0.0)
+        if name == "jax.backend_compile":
+            _compile_counts["backend_compiles"] += 1
+            if not getattr(_compile_local, "hit", False) \
+                    and len(_compile_missed) < _MISSED_KEPT:
+                _compile_missed.append(str(fun_name))
+            _compile_local.hit = False
+    t = _get()
+    if t.enabled and secs >= _PHASE_SPAN_MIN_S:
+        args = {} if fun_name is None else {"fun_name": str(fun_name)}
+        t.record("X", name, start, secs, _context_args(args))
+
+
+def compile_snapshot() -> Dict[str, float]:
+    """The compile counters now; :func:`compile_delta` of it is what moved
+    since.  Always on, like the dispatch counters: JAX calls the two
+    listeners a few times per program that is traced, lowered, compiled or
+    read back from the persistent cache, and never for a cached call.  The
+    first call registers them (``jax.monitoring``, imported here and not
+    with this module)."""
+    global _compile_installed
+    if not _compile_installed:
+        with _compile_lock:
+            if not _compile_installed:
+                import jax.monitoring as monitoring
+
+                monitoring.register_event_listener(_on_compile_event)
+                monitoring.register_event_duration_secs_listener(
+                    _on_compile_duration)
+                _compile_installed = True
+    with _compile_lock:
+        return dict(_compile_counts, n_missed=len(_compile_missed))
+
+
+def compile_delta(snap: Dict[str, float]) -> Dict[str, Any]:
+    """What compiling cost since ``snap``, as ``io_metrics.json`` carries it
+    per task under ``compile``: persistent-cache ``requests``,
+    ``cache_hits`` and ``cache_misses``, ``uncached`` (backend compiles
+    that could not use the cache), the seconds of each phase as self time
+    (``trace_s``, ``lower_s``, ``backend_s`` without the cache read,
+    ``cache_load_s``), and ``programs_missed``: the ``fun_name`` of every
+    program that reached the backend's compiler without a cache hit."""
+    now = compile_snapshot()
+    requests = int(now["requests"] - snap["requests"])
+    hits = int(now["cache_hits"] - snap["cache_hits"])
+    compiles = int(now["backend_compiles"] - snap["backend_compiles"])
+    out: Dict[str, Any] = {
+        "requests": requests, "cache_hits": hits,
+        "cache_misses": requests - hits,
+        "uncached": max(compiles - requests, 0),
+    }
+    for key in ("trace_s", "lower_s", "backend_s", "cache_load_s"):
+        out[key] = round(now[key] - snap[key], 6)
+    with _compile_lock:
+        out["programs_missed"] = list(
+            _compile_missed[int(snap["n_missed"]):int(now["n_missed"])])
+    return out
 
 
 def shard_path(trace_dir: str) -> str:
@@ -551,24 +726,49 @@ def _critical_path(task_spans: List[Dict[str, Any]]) -> Optional[Dict]:
     }
 
 
+def _self_seconds(spans: List[Dict[str, Any]]) -> List[float]:
+    """Each span's own seconds: its duration less what its children cover.
+    A span's parent is the innermost span of the same process and thread
+    that contains it; spans that merely overlap are siblings."""
+    own = [float(e.get("dur", 0.0)) for e in spans]
+    tracks: Dict[Any, List[int]] = {}
+    for i, e in enumerate(spans):
+        tracks.setdefault((e.get("pid"), e.get("tid")), []).append(i)
+    eps = 0.5  # microseconds: the merged timeline rounds to a nanosecond
+    for idxs in tracks.values():
+        idxs.sort(key=lambda i: (float(spans[i]["ts"]), -own[i]))
+        open_: List[tuple] = []  # (index, end), outermost first
+        for i in idxs:
+            end = float(spans[i]["ts"]) + float(spans[i].get("dur", 0.0))
+            while open_ and end > open_[-1][1] + eps:
+                open_.pop()
+            if open_:
+                own[open_[-1][0]] -= float(spans[i].get("dur", 0.0))
+            open_.append((i, end))
+    return [max(v, 0.0) / 1e6 for v in own]
+
+
 def summarize(chrome: Dict[str, Any]) -> Dict[str, Any]:
     """Run-level aggregates over a merged timeline: per-site latency
-    percentiles, instant counts, the task-DAG critical path, and per-
-    process utilization (busy seconds by category vs wall extent — >1.0
-    concurrency means the category genuinely overlapped)."""
+    percentiles and self time, instant counts, the task-DAG critical path,
+    and per-process utilization (busy seconds by category vs wall extent —
+    >1.0 concurrency means the category genuinely overlapped)."""
     spans = [e for e in chrome.get("traceEvents", [])
              if e.get("ph") == "X"]
     instants = [e for e in chrome.get("traceEvents", [])
                 if e.get("ph") == "i"]
     sites: Dict[str, List[float]] = {}
-    for e in spans:
+    self_s: Dict[str, float] = {}
+    for e, own in zip(spans, _self_seconds(spans)):
         sites.setdefault(e["name"], []).append(float(e.get("dur", 0.0)) / 1e6)
+        self_s[e["name"]] = self_s.get(e["name"], 0.0) + own
     site_stats = {}
     for name, vals in sorted(sites.items()):
         vals.sort()
         site_stats[name] = {
             "count": len(vals),
             "total_s": round(sum(vals), 6),
+            "self_s": round(self_s[name], 6),
             "p50_ms": round(_percentile(vals, 50) * 1e3, 3),
             "p95_ms": round(_percentile(vals, 95) * 1e3, 3),
             "p99_ms": round(_percentile(vals, 99) * 1e3, 3),

@@ -69,26 +69,86 @@ class FusedSegmentationBase(BaseTask):
         import jax
 
         from ..ops.tile_ws import resolved_modes
-        from ..parallel.mesh import (
-            backend_devices,
-            describe_devices,
-            device_peak_bytes,
-            make_mesh,
+        from ..parallel.mesh import describe_devices, device_peak_bytes
+        from ..runtime import handoff
+        from ..runtime import trace as trace_mod
+
+        # the job's host phases as spans (docs/OBSERVABILITY.md "The fused
+        # job"): setup, read, dispatch, wait, then d2h / widen / write per
+        # output; all the shared null span with the tracer off
+        with trace_mod.span("fused.setup") as sp:
+            cfg = self.get_config()
+            # fusable input edge: a live in-memory boundary-map handle is
+            # consumed without a storage read
+            inp = handoff.resolve_dataset(cfg["input_path"], cfg["input_key"])
+            shape = inp.shape
+            roi_begin = tuple(cfg.get("roi_begin") or (0,) * len(shape))
+            roi_end = tuple(cfg.get("roi_end") or shape)
+            roi = tuple(slice(b, e) for b, e in zip(roi_begin, roi_end))
+            roi_shape = tuple(e - b for b, e in zip(roi_begin, roi_end))
+            step, mesh, sp_desc, execution, impl = self._build_step(
+                cfg, roi_shape)
+            sp.note(execution=execution, mesh=sp_desc)
+        self.logger.info(
+            f"{execution} step on mesh {sp_desc}, roi {roi_shape}, "
+            f"halo={int(np.max(cfg.get('halo') or 0))}; "
+            f"mesh.devices={describe_devices(mesh.devices)}; "
+            f"kernels={resolved_modes(impl)}"
         )
+        with trace_mod.span("fused.read") as sp:
+            vol = np.asarray(inp[roi]).astype(np.float32)
+            sp.note(nbytes=int(vol.nbytes))
+        # the call up to its return: trace, lower, compile or read the
+        # executable back, host-to-device copy, enqueue
+        fun_name = "ws_ccl_step" if execution == "fused" else "ws_ccl_split"
+        with trace_mod.span("fused.dispatch", fun_name=fun_name):
+            out = step(vol[None])
+        with trace_mod.span("fused.wait"):
+            ws, cc, n_fg, overflow = jax.block_until_ready(out)
+        if bool(np.asarray(overflow)):
+            raise RuntimeError(
+                "fused step overflowed a label capacity; raise "
+                "max_labels_per_shard or use the blockwise task chain"
+            )
+
+        out_f = file_reader(cfg["output_path"])
+        block_shape = tuple(cfg["block_shape"])
+        written = {}
+        for key_cfg, data in (("ws_key", ws), ("cc_key", cc)):
+            key = cfg.get(key_cfg)
+            if not key:
+                continue
+            with trace_mod.span("fused.d2h", output=key) as sp:
+                arr = np.asarray(data[0])
+                sp.note(nbytes=int(arr.nbytes))
+            with trace_mod.span("fused.widen", output=key) as sp:
+                arr = arr.astype(np.uint64)
+                sp.note(nbytes=int(arr.nbytes))
+            with trace_mod.span("fused.write", output=key,
+                                nbytes=int(arr.nbytes)):
+                ds = out_f.require_dataset(
+                    key, shape=shape, chunks=block_shape, dtype="uint64"
+                )
+                # the whole ROI is already host-resident: one sliced write
+                ds[roi] = arr
+            written[key] = int(arr.max())
+        return {
+            # float32 psum: exact below 2**24 per shard; round-to-nearest
+            # (not truncate) so a 1-ulp-low representation can't report
+            # off-by-one.  Counts past 2**24 are approximate by design.
+            "n_foreground": int(round(float(np.asarray(n_fg)))),
+            "mesh": sp_desc,
+            "written": written,
+            "device_memory": device_peak_bytes(mesh.devices),
+        }
+
+    def _build_step(self, cfg, roi_shape):
+        """The mesh over the task's devices and the step compiled for it:
+        ``(step, mesh, mesh description, execution, impl)``."""
+        from ..parallel.mesh import backend_devices, make_mesh
         from ..parallel.pipeline import make_ws_ccl_step
         from ..parallel.split_pipeline import make_ws_ccl_split
 
-        from ..runtime import handoff
-
-        cfg = self.get_config()
-        # fusable input edge: a live in-memory boundary-map handle is
-        # consumed without a storage read
-        inp = handoff.resolve_dataset(cfg["input_path"], cfg["input_key"])
-        shape = inp.shape
-        roi_begin = tuple(cfg.get("roi_begin") or (0,) * len(shape))
-        roi_end = tuple(cfg.get("roi_end") or shape)
-        roi = tuple(slice(b, e) for b, e in zip(roi_begin, roi_end))
-        roi_shape = tuple(e - b for b, e in zip(roi_begin, roi_end))
         if len(roi_shape) != 3:
             raise ValueError(f"fused segmentation is 3-D only, got {roi_shape}")
 
@@ -154,42 +214,7 @@ class FusedSegmentationBase(BaseTask):
             exact_edt=bool(cfg.get("exact_edt", False)),
             stitch_ws_threshold=cfg.get("stitch_ws_threshold"),
         )
-        self.logger.info(
-            f"{execution} step on mesh {sp_desc}, roi {roi_shape}, "
-            f"halo={halo}; mesh.devices={describe_devices(mesh.devices)}; "
-            f"kernels={resolved_modes(impl)}"
-        )
-        vol = np.asarray(inp[roi]).astype(np.float32)
-        ws, cc, n_fg, overflow = jax.block_until_ready(step(vol[None]))
-        self.logger.info(f"device.peak_bytes={device_peak_bytes(mesh.devices)}")
-        if bool(np.asarray(overflow)):
-            raise RuntimeError(
-                "fused step overflowed a label capacity; raise "
-                "max_labels_per_shard or use the blockwise task chain"
-            )
-
-        out_f = file_reader(cfg["output_path"])
-        block_shape = tuple(cfg["block_shape"])
-        written = {}
-        for key_cfg, data in (("ws_key", ws), ("cc_key", cc)):
-            key = cfg.get(key_cfg)
-            if not key:
-                continue
-            arr = np.asarray(data[0]).astype(np.uint64)
-            ds = out_f.require_dataset(
-                key, shape=shape, chunks=block_shape, dtype="uint64"
-            )
-            # the whole ROI is already host-resident: one sliced write
-            ds[roi] = arr
-            written[key] = int(arr.max())
-        return {
-            # float32 psum: exact below 2**24 per shard; round-to-nearest
-            # (not truncate) so a 1-ulp-low representation can't report
-            # off-by-one.  Counts past 2**24 are approximate by design.
-            "n_foreground": int(round(float(np.asarray(n_fg)))),
-            "mesh": sp_desc,
-            "written": written,
-        }
+        return step, mesh, sp_desc, execution, impl
 
 
 class FusedSegmentationLocal(FusedSegmentationBase):

@@ -34,6 +34,7 @@ from ..runtime.executor import (
     region_verifier,
     validate_labels,
 )
+from ..parallel.mesh import device_peak_bytes
 from ..runtime.task import BaseTask, WorkflowBase, get_task_cls
 from ..utils.volume_utils import (
     Blocking,
@@ -209,13 +210,6 @@ class _WsTaskBase(BaseTask):
             f"kernels={resolved_modes(impl if use_tiled else 'legacy')}"
         )
 
-    def _log_device_peak(self, executor):
-        from ..parallel.mesh import device_peak_bytes
-
-        self.logger.info(
-            f"device.peak_bytes={device_peak_bytes(executor.devices)}"
-        )
-
     def _store_labels(self, out, block, raw, n_outer, size_dtype=np.uint64):
         """Crop inner region from the padded-outer labels and globalize."""
         inner = raw[block.inner_in_outer_bb]
@@ -344,6 +338,7 @@ class WatershedBase(_WsTaskBase):
             bnd_stash.pop(block.block_id, None)
             self.log_block_success(block.block_id)
 
+        device_memory = {}  # the host path holds no device
         if impl == "host":
             # reference-style per-job scipy compute (ops/host.py): no
             # device, no jit — the executor's vmap+jit contract does not
@@ -430,11 +425,12 @@ class WatershedBase(_WsTaskBase):
                 degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
                 inflight_byte_budget=cfg.get("inflight_byte_budget"),
             )
-            self._log_device_peak(executor)
+            device_memory = device_peak_bytes(executor.devices)
         return {
             "n_blocks": len(block_ids),
             "n_outer": n_outer,
             "overflow_blocks": sorted(overflow_blocks),
+            "device_memory": device_memory,
         }
 
 
@@ -631,11 +627,11 @@ class TwoPassWatershedBase(_WsTaskBase):
             degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
             inflight_byte_budget=cfg.get("inflight_byte_budget"),
         )
-        self._log_device_peak(executor)
         return {
             "n_blocks": len(block_ids),
             "n_outer": n_outer,
             "overflow_blocks": sorted(overflow_blocks),
+            "device_memory": device_peak_bytes(executor.devices),
         }
 
 

@@ -243,6 +243,21 @@ def io_metrics_path(tmp_folder: str) -> str:
     return os.path.join(tmp_folder, "io_metrics.json")
 
 
+def _merge_counters(old, new):
+    """Numbers add, lists append, groups of counters (the ``compile`` block)
+    merge key by key; anything else is replaced."""
+    if isinstance(new, (int, float)) and isinstance(old, (int, float)):
+        return old + new
+    if isinstance(new, list) and isinstance(old, list):
+        return old + new
+    if isinstance(new, dict) and isinstance(old, dict):
+        merged = dict(old)
+        for k, v in new.items():
+            merged[k] = _merge_counters(old.get(k), v)
+        return merged
+    return new
+
+
 def record_io_metrics(path: str, task_name: str, metrics) -> None:
     """Merge one task's chunk-IO counter deltas into ``io_metrics.json``.
 
@@ -271,12 +286,7 @@ def record_io_metrics(path: str, task_name: str, metrics) -> None:
         cur = dict(tasks.get(task_name) or {})
         moved = []
         for k, v in dict(metrics).items():
-            if isinstance(v, (int, float)) and isinstance(
-                cur.get(k), (int, float)
-            ):
-                cur[k] = cur[k] + v
-            else:
-                cur[k] = v
+            cur[k] = _merge_counters(cur.get(k), v)
             if not isinstance(v, (int, float)) or v:
                 moved.append(str(k))
         tasks[task_name] = cur

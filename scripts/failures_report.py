@@ -331,6 +331,24 @@ def format_io_metrics(tasks, provenance=None) -> list:
                 f"discarded, {int(m.get('pages_in_use', 0))} pool "
                 f"page(s) in use"
             )
+        comp = m.get("compile") or {}
+        if comp:
+            # did this task recompile, and which program
+            # (docs/OBSERVABILITY.md "Compiles")
+            missed = comp.get("programs_missed") or []
+            lines.append(
+                f"  compiles: {int(comp.get('requests', 0))} cache "
+                f"request(s), {int(comp.get('cache_hits', 0))} hit(s), "
+                f"{int(comp.get('cache_misses', 0))} miss(es), "
+                f"{int(comp.get('uncached', 0))} uncached; trace "
+                f"{float(comp.get('trace_s', 0.0)):.2f}s, lower "
+                f"{float(comp.get('lower_s', 0.0)):.2f}s, compile "
+                f"{float(comp.get('backend_s', 0.0)):.2f}s, cache read "
+                f"{float(comp.get('cache_load_s', 0.0)):.2f}s"
+                + (f"; compiled: {', '.join(missed[:8])}"
+                   + (f" (+{len(missed) - 8} more)" if len(missed) > 8 else "")
+                   if missed else "")
+            )
         # multi-process attribution (io_metrics.json schema v2): when more
         # than one process merged into this task's counters, say which
         # host:pid contributed what — the additive totals alone cannot
@@ -374,7 +392,10 @@ def format_trace_summary(summ) -> list:
     ]
     sites = summ.get("sites") or {}
     if sites:
-        lines.append("  site                     count    p50_ms    p99_ms    total_s")
+        # self_s: a site's seconds outside the spans nested in it (its
+        # total where a summary predates the column)
+        lines.append("  site                     count    p50_ms    p99_ms"
+                     "    total_s     self_s")
         for name in sorted(sites):
             s = sites[name]
             lines.append(
@@ -382,6 +403,7 @@ def format_trace_summary(summ) -> list:
                 f" {float(s.get('p50_ms', 0)):>9.3f}"
                 f" {float(s.get('p99_ms', 0)):>9.3f}"
                 f" {float(s.get('total_s', 0)):>10.3f}"
+                f" {float(s.get('self_s', s.get('total_s', 0))):>10.3f}"
             )
     instants = summ.get("instants") or {}
     if instants:

@@ -101,18 +101,26 @@ def test_two_real_processes_flush_and_merge(tmp_path):
     anchors) flush shards into one directory via CTT_TRACE=<dir>; the
     merged timeline holds both processes' spans in wall order."""
     d = str(tmp_path / "trace")
+    # worker 1 opens its span only after worker 0 has closed its own (a
+    # file handed over, not a fixed stagger: importing the package takes
+    # seconds and varies by more than any short sleep on a loaded host)
     prog = (
         "import os, time\n"
         "from cluster_tools_tpu.runtime import trace\n"
         "idx = int(os.environ['IDX'])\n"
-        "time.sleep(0.2 * idx)\n"
+        "baton = os.environ['BATON']\n"
+        "while idx and not os.path.exists(baton):\n"
+        "    time.sleep(0.01)\n"
         "with trace.span('worker.main', worker=idx):\n"
         "    time.sleep(0.05)\n"
+        "if not idx:\n"
+        "    open(baton, 'w').close()\n"
         "assert trace.flush() is not None\n"
     )
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
     env["CTT_TRACE"] = d
+    env["BATON"] = str(tmp_path / "baton")
     env["JAX_PLATFORMS"] = "cpu"
     procs = [
         subprocess.Popen([sys.executable, "-c", prog],
