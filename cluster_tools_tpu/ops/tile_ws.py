@@ -27,13 +27,12 @@ restructures the resolution:
    minimum-spanning-forest watershed semantics, strictly closer to
    priority-flood than the old relaxation.  Two machines compute it
    (``CT_FILL_MODE``, default ``auto`` = substrate-aware): ``dense``
-   (auto on cpu only) runs sort-free scatter-min rounds over the full
-   face grids with exact per-pair min saddles
-   (:func:`fill_unseeded_basins_dense`); ``capacity`` (auto on tpu AND
-   gpu — volume-scale random access is the chip bottleneck, and the
-   host-cache rationale doesn't transfer to gpu) runs the rounds on a
-   compacted basin-boundary edge list with run-start saddle sampling
-   (~1/18 the transient memory).  Basins with no seeded reachable
+   (auto on cpu only) runs sort-free scatter-min rounds over the
+   once-harvested basin faces and a compact basin table, with exact
+   per-pair min saddles (:func:`fill_unseeded_basins_dense`);
+   ``capacity`` (auto on tpu AND gpu) runs the rounds on a compacted
+   basin-boundary edge list with run-start saddle sampling (~1/18 the
+   transient memory, not exact).  Basins with no seeded reachable
    neighbor keep label 0 (legacy behavior).  All mode env vars
    (``CT_FILL_MODE``/``CT_SEED_CCL``/``CT_TIER_MODE``) are resolved at
    the public entry points, OUTSIDE jit, and folded into the compile
@@ -105,15 +104,15 @@ def _resolve_fill_mode(fill_mode: Optional[str]) -> str:
     backends:
 
     - ``dense`` on the **cpu** backend only: sort-free scatter-min Boruvka
-      over the full face grids — exact min saddles, no caps, 3.8x faster
-      end-to-end at 128^3 on the host, where gathers are cache-friendly.
+      over the harvested basin faces and a compact basin table — exact
+      min saddles, capacities derived from the volume's size
+      (:func:`fill_unseeded_basins_dense`), 3.8x faster end-to-end at
+      128^3 on the host, where gathers are cache-friendly.
     - ``capacity`` everywhere else (tpu AND gpu): compacted lists +
-      dedup sorts.  On the chip, random gather/scatter runs ~165M elem/s
-      regardless of locality (docs/PERFORMANCE.md "Where the time goes"),
-      so the dense rounds' ~15 volume-scale passes per round project to
-      ~13s/round at 512^3; on gpu the host-cache rationale simply doesn't
-      transfer and the dense path's ~1.8GB transient at 512^3 is a real
-      risk (advisor r4) — capacity until a measured A/B says otherwise.
+      dedup sorts, saddles SAMPLED at run starts.  It is the cheaper
+      machine on the chip and not an exact one: the benchmark's
+      configuration sets ``CT_FILL_MODE=dense`` because ``capacity``
+      fails its comparison with a plain flood (PERF.md section 7).
 
     Resolved OUTSIDE the jit boundary so the value is part of the compile
     key: flipping the env var mid-process retraces instead of silently
@@ -652,30 +651,50 @@ def fill_unseeded_basins_dense(
     max_rounds: Optional[int] = None,
     face_cap: Optional[int] = None,
 ):
-    """Sort-free unseeded-basin fill: face-list scatter-min Boruvka rounds.
+    """Sort-free unseeded-basin fill: face-list scatter-min Boruvka rounds
+    over a compact basin table.
 
     Same MSF semantics as :func:`fill_unseeded_basins` with the saddle per
     basin pair the exact minimum over every shared face voxel (the
     capacity fill samples run-start saddles — see the ``keep`` flags
-    there), and still NO SORTS anywhere.  r5 restructure: the per-axis
-    basin-face candidate set is harvested ONCE into compacted lists (an
-    O(n) cumsum compact, not a sort) — sound because a face can only
-    LEAVE the edge set as basins merge, never join it — and every Boruvka
-    round then runs face-sized gathers/scatters (~9% of voxels per axis
-    on bench-like data, docs/PERFORMANCE.md "512³ capacity audit")
-    instead of ~18 full-volume passes.  ``face_cap`` (default
-    ``max(2^16, n/6)`` with a 2^24 ceiling) bounds each list: ≥1.8× the
-    measured ~9%/axis load while n/6 governs (n ≲ 100M), narrowing to
-    ~1.4× at 512³ where the int32-memory ceiling binds; regimes that
-    exceed it are truncated and REPORTED through the overflow flag,
-    never silent.  NOTE the
-    round passes are random-access gathers/scatters, which the chip runs
-    at ~165M elem/s regardless of locality — on TPU the capacity sorts
-    are the predicted-fast path and the auto default picks them; the
-    on-chip A/B has not been run (ROADMAP S3).
-    Memory: three per-axis lists of five ``face_cap`` arrays plus the
-    ``P``/``best`` tables — ~1.1GB transient at 512³ (below the old
-    full-grid formulation's ~1.8GB).
+    there), and still NO SORTS anywhere.  Two one-time passes keep every
+    round off the volume:
+
+    - the per-axis basin-face candidate set is harvested ONCE into
+      compacted lists (an O(n) cumsum compact, not a sort) — sound because
+      a face can only LEAVE the edge set as basins merge, never join it;
+    - the seedless basins get DENSE ids: a basin's code names its terminal
+      voxel, so ``cumsum(values == own code)`` ranks the terminals in flat
+      order, and the face endpoints are rewritten once from codes to
+      ``-id - 2``.  The union table ``P``, the per-round ``best`` tables,
+      the 2-cycle break and the jump-to-closure loop then all have
+      ``basin_cap`` entries, not one per voxel.  The rank is monotone in
+      the terminal index, so every tie-break (the smaller terminal stays
+      the root of a 2-cycle) picks what a voxel-indexed table picks: the
+      labels, the flag and the number of rounds are the same integers.
+
+    Capacities, both derived from ``n`` and both REPORTED through the
+    overflow flag when exceeded, never silent: ``face_cap`` (default
+    ``max(2^16, n/6)`` with a 2^24 ceiling) bounds each axis's face list —
+    ≥1.8× the measured ~9%/axis load while n/6 governs (n ≲ 100M),
+    narrowing to ~1.4× at 512³ where the int32-memory ceiling binds;
+    ``basin_cap = min(n, max(2^16, n/16))`` bounds the seedless basins
+    (80,902 measured at 512³, docs/PERFORMANCE.md "512³ capacity audit";
+    more than n/16 of them means basins under 16 voxels on average, whose
+    faces overflow ``face_cap`` first unless they are isolated voxels).
+    A code whose terminal voxel does not carry it (not what the flow phase
+    produces) has no id and raises the same flag.
+
+    Cost on the chip: the rounds' passes are random-access gathers and
+    scatters, which a TPU v5e runs at ~45M elem/s whatever the locality
+    (a gather over the 66M voxels of the 384³ step's halo-padded shard:
+    1.48 s, ledger PR 28) — which is why nothing inside the rounds may
+    be volume-sized: what is left there is face-sized or basin-sized
+    (PERF.md section 5).  Volume-sized and paid once: the harvest, the
+    rank, the final resolve.
+    Memory: three per-axis lists of five ``face_cap`` arrays, four
+    ``basin_cap`` tables and two volume-sized int32 temporaries (the rank
+    before the rounds, the code table after them).
 
     ``values``: >0 seeded label, <= -2 unseeded terminal code
     (``-flat_index - 2``), 0 invalid, and **-1 for masked/padded voxels**
@@ -689,7 +708,7 @@ def fill_unseeded_basins_dense(
     per-voxel labels with every reachable unseeded basin resolved to its
     adopted seed label (unreachable basins keep their codes; callers zero
     them), overflow set when ``max_rounds`` rounds did not converge OR a
-    face list truncated.
+    face list or the basin table truncated.
 
     Selected by ``fill_mode="dense"`` (``CT_FILL_MODE``), or by the
     substrate-aware ``auto`` default on the cpu backend — resolution
@@ -702,15 +721,35 @@ def fill_unseeded_basins_dense(
     i32max = jnp.iinfo(jnp.int32).max
     if face_cap is None:
         face_cap = min(1 << 24, max(1 << 16, n // 6))
+    basin_cap = min(n, max(1 << 16, n // 16))
     if max_rounds is None:
         max_rounds = _auto_fill_rounds(n)
 
-    # P[g] = current label of the basin whose terminal voxel is g; codes
-    # resolve through it, seeds are terminal by value
-    P0 = _match_vma(-jnp.arange(n, dtype=jnp.int32) - 2, values)
+    # ---- one-time dense basin ids ----
+    # a seedless basin's code is its terminal's own index, so the terminals
+    # are the voxels that carry their own code; their rank in flat order is
+    # the basin's id.  term_pos[id] leads back to the code after the rounds.
+    flat_idx = _match_vma(jnp.arange(n, dtype=jnp.int32), values)
+    is_term = v == -flat_idx - 2
+    (term_pos,), n_basins = _compact(is_term, (flat_idx,), basin_cap, n)
+    trunc = (n_basins > basin_cap).astype(jnp.int32)
+    term_id = jnp.where(is_term, jnp.cumsum(is_term.astype(jnp.int32)) - 1, -1)
+
+    def to_id(x):
+        """Face endpoint: code -> ``-id - 2``; seeds, -1 and 0 as they are.
+        Also: whether some code here has no id (its terminal does not
+        carry it)."""
+        coded = x <= -2
+        tid = term_id[jnp.clip(-x - 2, 0, n - 1)]
+        return jnp.where(coded, -tid - 2, x), jnp.any(coded & (tid < 0))
+
+    # P[id] = current label of basin id: a seed label, -1, or the -id - 2
+    # of the basin it was joined to; ids resolve through it, seeds are
+    # terminal by value
+    P0 = _match_vma(-jnp.arange(basin_cap, dtype=jnp.int32) - 2, values)
 
     def resolve_flat(P, x):
-        return jnp.where(x <= -2, P[jnp.clip(-x - 2, 0, n - 1)], x)
+        return jnp.where(x <= -2, P[jnp.clip(-x - 2, 0, basin_cap - 1)], x)
 
     # ---- one-time face harvest (round-invariant superset) ----
     # a face is a candidate edge iff the ORIGINAL codes differ, both are
@@ -720,8 +759,6 @@ def fill_unseeded_basins_dense(
     # index is globally distinct and seen identically from both sides, so
     # the min-edge graph is a forest plus 2-cycles (the classic
     # distinct-weight Boruvka argument, as in _fill_core).
-    flat_idx = _match_vma(jnp.arange(n, dtype=jnp.int32), values)
-    trunc = _match_vma(jnp.zeros((), jnp.int32), values)
     faces = []
     for axis in range(3):
         nb = _shift(values, -1, axis, jnp.int32(0)).ravel()
@@ -735,14 +772,15 @@ def fill_unseeded_basins_dense(
         pad = idx_c >= n
         ia = jnp.clip(idx_c, 0, n - 1)
         ib = jnp.clip(idx_c + stride, 0, n - 1)
-        va = jnp.where(pad, 0, v[ia])
-        vb = jnp.where(pad, 0, v[ib])
+        va, bad_a = to_id(jnp.where(pad, 0, v[ia]))
+        vb, bad_b = to_id(jnp.where(pad, 0, v[ib]))
+        trunc = jnp.maximum(trunc, (bad_a | bad_b).astype(jnp.int32))
         sad = jnp.maximum(h[ia], h[ib])
         eid = jnp.where(
             pad, i32max, jnp.int32(axis) * jnp.int32(n) + idx_c
         )
         faces.append((va, vb, sad, eid, pad))
-    me_idx = _match_vma(jnp.arange(n, dtype=jnp.int32), values)
+    me_idx = _match_vma(jnp.arange(basin_cap, dtype=jnp.int32), values)
 
     def round_cond(s):
         _, changed, it = s
@@ -750,8 +788,12 @@ def fill_unseeded_basins_dense(
 
     def round_body(s):
         P, _, it = s
-        best_h = _match_vma(jnp.full((n,), i32max, jnp.int32), values)
-        best_e = _match_vma(jnp.full((n,), i32max, jnp.int32), values)
+        best_h = _match_vma(
+            jnp.full((basin_cap,), i32max, jnp.int32), values
+        )
+        best_e = _match_vma(
+            jnp.full((basin_cap,), i32max, jnp.int32), values
+        )
         sides = []
         for va, vb, sad, eid, pad in faces:
             ra = resolve_flat(P, va)
@@ -761,28 +803,30 @@ def fill_unseeded_basins_dense(
             sides.append((rb, ra, sad, live, eid))
         for src, dst, sad, live, eid in sides:
             m = live & (src <= -2)
-            g = jnp.where(m, -src - 2, n)
+            g = jnp.where(m, -src - 2, basin_cap)
             best_h = best_h.at[g].min(
                 jnp.where(m, sad, i32max), mode="drop"
             )
         for src, dst, sad, live, eid in sides:
             m = live & (src <= -2)
-            tie = m & (best_h[jnp.clip(-src - 2, 0, n - 1)] == sad)
-            gt = jnp.where(tie, -src - 2, n)
+            gsafe = jnp.clip(-src - 2, 0, basin_cap - 1)
+            tie = m & (best_h[gsafe] == sad)
+            gt = jnp.where(tie, -src - 2, basin_cap)
             best_e = best_e.at[gt].min(
                 jnp.where(tie, eid, i32max), mode="drop"
             )
         P2 = P
         for src, dst, sad, live, eid in sides:
             m = live & (src <= -2)
-            gsafe = jnp.clip(-src - 2, 0, n - 1)
+            gsafe = jnp.clip(-src - 2, 0, basin_cap - 1)
             win = m & (best_h[gsafe] == sad) & (best_e[gsafe] == eid)
-            gw = jnp.where(win, -src - 2, n)
+            gw = jnp.where(win, -src - 2, basin_cap)
             P2 = P2.at[gw].set(jnp.where(win, dst, 0), mode="drop")
         # break 2-cycles (two roots that picked the same edge from both
-        # sides): the smaller terminal index stays a root
+        # sides): the smaller id, which is the smaller terminal index,
+        # stays a root
         me = me_idx
-        tgt = jnp.clip(-P2 - 2, 0, n - 1)
+        tgt = jnp.clip(-P2 - 2, 0, basin_cap - 1)
         mutual = (P2 <= -2) & (P2[tgt] == (-me - 2)) & (me < tgt)
         P2 = jnp.where(mutual, -me - 2, P2)
         # pointer-jump to CLOSURE, not a fixed count: a partially
@@ -806,7 +850,15 @@ def fill_unseeded_basins_dense(
     P, unconverged, _ = lax.while_loop(
         round_cond, round_body, (P0, _true_like(v), jnp.int32(0))
     )
-    resolved = resolve_flat(P, v).reshape(shape)
+    # ---- back to the voxels: ids -> codes at the terminals' positions,
+    # then one volume-sized gather as the codes name those positions ----
+    root_pos = term_pos[jnp.clip(-P - 2, 0, basin_cap - 1)]
+    code_table = (-flat_idx - 2).at[term_pos].set(
+        jnp.where(P <= -2, -root_pos - 2, P), mode="drop"
+    )
+    resolved = jnp.where(
+        v <= -2, code_table[jnp.clip(-v - 2, 0, n - 1)], v
+    ).reshape(shape)
     return resolved, jnp.maximum(unconverged.astype(jnp.int32), trunc)
 
 

@@ -13,7 +13,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from jax import lax
+
+from cluster_tools_tpu.ops.ccl import _match_vma, _shift, _true_like
+from cluster_tools_tpu.ops.tile_ccl import _compact
 from cluster_tools_tpu.ops.tile_ws import (
+    _auto_fill_rounds,
     _sortable_float_key,
     fill_unseeded_basins_dense,
 )
@@ -292,3 +297,250 @@ def test_mode_env_flip_retraces_without_clear_caches(rng, monkeypatch):
         "kwarg spelling compiled a separate cache entry: key drift"
     )
     np.testing.assert_array_equal(np.asarray(kw_out), np.asarray(dense_out))
+
+
+# ---------------------------------------------------------------------------
+# the compact basin table against the table as large as the volume
+# ---------------------------------------------------------------------------
+
+
+def _fill_n_table_reference(values, height):
+    """The fill as it was before the basins had dense ids, kept here as the
+    plain reference: the union table ``P``, ``best_h`` / ``best_e``, the
+    2-cycle break and the closure loop have one entry per VOXEL and are
+    indexed by a basin's terminal position.  Same harvest, same rounds,
+    same tie-breaks; the compact table must give the same integers."""
+    shape = values.shape
+    n = int(np.prod(shape))
+    v = values.ravel()
+    h = _sortable_float_key(height.astype(jnp.float32)).ravel()
+    i32max = jnp.iinfo(jnp.int32).max
+    face_cap = min(1 << 24, max(1 << 16, n // 6))
+    max_rounds = _auto_fill_rounds(n)
+    P0 = _match_vma(-jnp.arange(n, dtype=jnp.int32) - 2, values)
+
+    def resolve_flat(P, x):
+        return jnp.where(x <= -2, P[jnp.clip(-x - 2, 0, n - 1)], x)
+
+    flat_idx = _match_vma(jnp.arange(n, dtype=jnp.int32), values)
+    trunc = _match_vma(jnp.zeros((), jnp.int32), values)
+    faces = []
+    for axis in range(3):
+        nb = _shift(values, -1, axis, jnp.int32(0)).ravel()
+        ok0 = (v != nb) & (v != 0) & (nb != 0) & ((v <= -2) | (nb <= -2))
+        (idx_c,), n_faces = _compact(ok0, (flat_idx,), face_cap, n)
+        trunc = jnp.maximum(trunc, (n_faces > face_cap).astype(jnp.int32))
+        stride = int(np.prod(shape[axis + 1:], dtype=np.int64))
+        pad = idx_c >= n
+        ia = jnp.clip(idx_c, 0, n - 1)
+        ib = jnp.clip(idx_c + stride, 0, n - 1)
+        va = jnp.where(pad, 0, v[ia])
+        vb = jnp.where(pad, 0, v[ib])
+        sad = jnp.maximum(h[ia], h[ib])
+        eid = jnp.where(pad, i32max, jnp.int32(axis) * jnp.int32(n) + idx_c)
+        faces.append((va, vb, sad, eid, pad))
+
+    def round_cond(s):
+        _, changed, it = s
+        return changed & (it < max_rounds)
+
+    def round_body(s):
+        P, _, it = s
+        best_h = _match_vma(jnp.full((n,), i32max, jnp.int32), values)
+        best_e = _match_vma(jnp.full((n,), i32max, jnp.int32), values)
+        sides = []
+        for va, vb, sad, eid, pad in faces:
+            ra = resolve_flat(P, va)
+            rb = resolve_flat(P, vb)
+            live = ~pad & (ra != rb)
+            sides.append((ra, rb, sad, live, eid))
+            sides.append((rb, ra, sad, live, eid))
+        for src, dst, sad, live, eid in sides:
+            m = live & (src <= -2)
+            g = jnp.where(m, -src - 2, n)
+            best_h = best_h.at[g].min(jnp.where(m, sad, i32max), mode="drop")
+        for src, dst, sad, live, eid in sides:
+            m = live & (src <= -2)
+            tie = m & (best_h[jnp.clip(-src - 2, 0, n - 1)] == sad)
+            gt = jnp.where(tie, -src - 2, n)
+            best_e = best_e.at[gt].min(jnp.where(tie, eid, i32max), mode="drop")
+        P2 = P
+        for src, dst, sad, live, eid in sides:
+            m = live & (src <= -2)
+            gsafe = jnp.clip(-src - 2, 0, n - 1)
+            win = m & (best_h[gsafe] == sad) & (best_e[gsafe] == eid)
+            gw = jnp.where(win, -src - 2, n)
+            P2 = P2.at[gw].set(jnp.where(win, dst, 0), mode="drop")
+        me = flat_idx
+        tgt = jnp.clip(-P2 - 2, 0, n - 1)
+        mutual = (P2 <= -2) & (P2[tgt] == (-me - 2)) & (me < tgt)
+        P2 = jnp.where(mutual, -me - 2, P2)
+
+        def comp_body(t):
+            p, _ = t
+            p2 = resolve_flat(p, p)
+            return p2, jnp.any(p2 != p)
+
+        P2, _ = lax.while_loop(
+            lambda t: t[1], comp_body, (P2, _true_like(P2))
+        )
+        return P2, jnp.any(P2 != P), it + 1
+
+    P, unconverged, _ = lax.while_loop(
+        round_cond, round_body, (P0, _true_like(v), jnp.int32(0))
+    )
+    resolved = resolve_flat(P, v).reshape(shape)
+    return resolved, jnp.maximum(unconverged.astype(jnp.int32), trunc)
+
+
+def _masked_case(seed, shape, seed_frac):
+    """A random partition with seedless basins, and a corner and half a slab of
+    masked (-1) voxels cut through it: basins that border them may adopt -1."""
+    vals, height = _mk_case(np.random.default_rng(seed), shape, seed_frac)
+    vals = vals.copy()
+    flat = np.arange(vals.size).reshape(shape)
+    cut = np.zeros(shape, bool)
+    cut[:2, :3, :] = True
+    cut[shape[0] // 2:, : shape[1] // 2, shape[2] // 2] = True
+    # a code must keep naming a voxel that carries it: mask no terminal
+    cut &= vals != -flat - 2
+    vals[cut] = -1
+    return vals, height
+
+
+def _two_cycle_case():
+    """Two seedless basins A, B in a corridor between two seeds, the A|B
+    saddle lower than A's and B's saddles to their seeds: in round one both
+    roots pick the A|B face, from both sides.  The rule keeps the smaller
+    terminal (A's) as the root; round two joins the pair to seed 1 over
+    the lower of the two outer saddles.  A second pair C, D lies fenced by
+    invalid voxels: it reaches no seed and keeps the ROOT's code, which
+    shows which of the two the rule kept."""
+    shape = (3, 3, 8)
+    vals = np.zeros(shape, np.int32)
+    flat = np.arange(np.prod(shape)).reshape(shape)
+    vals[1, 1, 0:2] = 1
+    vals[1, 1, 2:4] = -int(flat[1, 1, 3]) - 2  # A: terminal at x = 3
+    vals[1, 1, 4:6] = -int(flat[1, 1, 4]) - 2  # B: terminal at x = 4
+    vals[1, 1, 6:8] = 2
+    vals[0, 0, 2:4] = -int(flat[0, 0, 3]) - 2  # C
+    vals[0, 0, 4:6] = -int(flat[0, 0, 4]) - 2  # D
+    height = np.zeros(shape, np.float32)
+    height[1, 1] = [0.0, 0.7, 0.7, 0.1, 0.1, 0.8, 0.8, 0.0]
+    height[0, 0] = height[1, 1]
+    return vals, height
+
+
+_EQUALITY_CASES = {
+    "seeds_0.5": lambda: _masked_case(11, (14, 15, 16), 0.5),
+    "seeds_0.15": lambda: _masked_case(12, (14, 15, 16), 0.15),
+    "seeds_0.02": lambda: _masked_case(13, (16, 18, 20), 0.02),
+    "two_cycle": _two_cycle_case,
+    "deep_chain": lambda: _chain_case(40),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_EQUALITY_CASES))
+def test_compact_table_equals_n_table(case):
+    vals, height = _EQUALITY_CASES[case]()
+    got, flag = fill_unseeded_basins_dense(jnp.asarray(vals), jnp.asarray(height))
+    want, want_flag = _fill_n_table_reference(
+        jnp.asarray(vals), jnp.asarray(height)
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(flag) == int(want_flag) == 0
+    assert (vals <= -2).any()  # the case has seedless basins at all
+    if case == "two_cycle":
+        assert (np.asarray(got)[1, 1] == [1, 1, 1, 1, 1, 1, 2, 2]).all()
+        c_code = -(0 * 24 + 0 * 8 + 3) - 2
+        assert (np.asarray(got)[0, 0] == [0, 0] + [c_code] * 4 + [0, 0]).all()
+    if case.startswith("seeds"):
+        assert (vals == -1).any()
+
+
+@pytest.mark.parametrize("extra_basins", [0, 1])
+def test_basin_table_overflow_raises_flag(extra_basins):
+    """More seedless basins than ``basin_cap`` (2^16 below 2^20 voxels)
+    raises the overflow return; exactly ``basin_cap`` of them does not.
+    Every voxel is a basin of its own here but a seeded head of the
+    volume, and ``face_cap`` is given room so that only the basin table
+    can truncate."""
+    shape = (41, 41, 41)
+    n = int(np.prod(shape))
+    basin_cap = 1 << 16
+    assert basin_cap < n
+    k = basin_cap + extra_basins
+    vals = -np.arange(n, dtype=np.int32) - 2
+    vals[: n - k] = 1
+    height = np.random.default_rng(3).random(shape).astype(np.float32)
+    got, flag = fill_unseeded_basins_dense(
+        jnp.asarray(vals.reshape(shape)), jnp.asarray(height), face_cap=n
+    )
+    assert int(flag) == extra_basins
+    if not extra_basins:
+        assert (np.asarray(got) == 1).all()
+
+
+def test_code_without_terminal_raises_flag():
+    """A code whose terminal voxel does not carry it has no dense id: the
+    fill reports it through the flag and never resolves it silently."""
+    vals, height = _two_cycle_case()
+    vals[1, 1, 3] = -1  # A's terminal is masked; x = 2 still carries A's code
+    _, flag = fill_unseeded_basins_dense(jnp.asarray(vals), jnp.asarray(height))
+    assert int(flag) == 1
+
+
+def _two_blocks():
+    a = _masked_case(21, (10, 11, 12), 0.15)
+    b = _masked_case(22, (10, 11, 12), 0.02)
+    vals = np.stack([a[0], b[0]])
+    height = np.stack([a[1], b[1]])
+    want = [
+        _fill_n_table_reference(jnp.asarray(v), jnp.asarray(h))
+        for v, h in zip(vals, height)
+    ]
+    return vals, height, want
+
+
+def test_compact_table_under_vmap():
+    """Two blocks with different basin counts as lanes of one program (the
+    blockwise executor's form): each lane equals its own n-table run."""
+    vals, height, want = _two_blocks()
+    got, flag = jax.vmap(fill_unseeded_basins_dense)(
+        jnp.asarray(vals), jnp.asarray(height)
+    )
+    for lane in range(2):
+        np.testing.assert_array_equal(
+            np.asarray(got[lane]), np.asarray(want[lane][0])
+        )
+        assert int(flag[lane]) == int(want[lane][1]) == 0
+
+
+@pytest.mark.parametrize("check_vma", [False, True])
+def test_compact_table_under_shard_map(check_vma):
+    """The mesh step's form: the per-shard body under ``shard_map`` on a
+    one-device (dp, sp) mesh.  The pipeline turns the vma check off (its
+    Pallas kernels); with it on, every fresh table of the fill must carry
+    the data's varying axes (``_match_vma``)."""
+    from jax.sharding import PartitionSpec
+
+    from cluster_tools_tpu.compat import shard_map
+    from cluster_tools_tpu.parallel.mesh import backend_devices, make_mesh
+
+    mesh = make_mesh(
+        1, axis_names=("dp", "sp"), devices=backend_devices("local")[:1]
+    )
+    vals, height, want = _two_blocks()
+
+    def body(v, h):
+        out, flag = fill_unseeded_basins_dense(v[0], h[0])
+        return out[None], lax.pmax(flag, ("dp", "sp"))
+
+    spec = PartitionSpec("dp", "sp")
+    step = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=(spec, spec),
+        out_specs=(spec, PartitionSpec()), check_vma=check_vma,
+    ))
+    got, flag = step(jnp.asarray(vals[:1]), jnp.asarray(height[:1]))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0][0]))
+    assert int(flag) == 0
