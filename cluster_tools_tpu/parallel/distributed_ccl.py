@@ -299,6 +299,13 @@ def _globalize_and_merge(
     return labels
 
 
+def default_pair_cap(n_rows: int) -> int:
+    """Rows each shard's pair list is deduped to before the ``all_gather``
+    (:func:`merge_labels_by_pairs`); at or above ``n_rows`` the dedup is
+    skipped."""
+    return max(16384, n_rows // 8)
+
+
 def merge_labels_by_pairs(
     glob: jnp.ndarray,
     pairs: jnp.ndarray,
@@ -332,7 +339,7 @@ def merge_labels_by_pairs(
     """
     n_in = int(pairs.shape[0])
     if pair_cap is None:
-        pair_cap = max(16384, n_in // 8)
+        pair_cap = default_pair_cap(n_in)
 
     def _tail(shard_pairs):
         all_pairs = shard_pairs

@@ -277,11 +277,13 @@ def _ws_ccl_shard(
     ws_lab = jnp.stack(ws_out)
     cc_lab = jnp.stack(cc_out)
     n_fg = count_foreground(cc_lab, sp_axes, dp_axis)
-    # mesh-wide label-compaction overflow flag (always False w/o compaction)
-    for _, name, _ in sp_axes:
-        ws_overflow = lax.pmax(ws_overflow, name)
-    overflow = jnp.maximum(ws_overflow, cc_overflow)
-    overflow = lax.pmax(overflow, dp_axis) > 0
+    # mesh-wide label-compaction overflow flag (always False w/o compaction);
+    # its all-reduces sit with the count's, the step's other global scalar
+    with jax.named_scope("step.count"):
+        for _, name, _ in sp_axes:
+            ws_overflow = lax.pmax(ws_overflow, name)
+        overflow = jnp.maximum(ws_overflow, cc_overflow)
+        overflow = lax.pmax(overflow, dp_axis) > 0
     return ws_lab, cc_lab, n_fg, overflow
 
 

@@ -23,6 +23,34 @@ from ..runtime.task import BaseTask, WorkflowBase, get_task_cls
 from ..utils.volume_utils import file_reader
 
 
+def collective_bytes(roi_shape, grid, halo):
+    """What one job's collectives move, reckoned from the shapes the step
+    is built for (nothing is read back from a device): ``cuts``, the faces
+    between neighbouring shards; ``halo_bytes``, the float32 planes that
+    cross them in the halo exchange, both ways, all cuts together;
+    ``gathered_pair_bytes``, the label-pair list of the cross-shard merge
+    as each device holds it after the ``all_gather`` (the deduped branch of
+    ``merge_labels_by_pairs``).  ``grid`` holds the mesh sizes over the
+    leading volume axes."""
+    from ..parallel.distributed_ccl import default_pair_cap
+
+    n_shards = int(np.prod(grid))
+    local = [s // g for s, g in zip(roi_shape, grid)] + list(roi_shape[len(grid):])
+    cuts = sum((g - 1) * n_shards // g for g in grid)
+    # as exchange_all: a later axis forwards the halos an earlier one received
+    padded, halo_voxels = list(local), 0
+    for a, g in enumerate(grid):
+        slab = halo * int(np.prod(padded)) // padded[a]
+        halo_voxels += 2 * (g - 1) * (n_shards // g) * slab
+        padded[a] += 2 * halo
+    pair_rows = 0
+    if n_shards > 1:
+        faces = sum(int(np.prod(local)) // local[a] for a in range(len(grid)))
+        pair_rows = n_shards * min(faces, default_pair_cap(faces))
+    return {"cuts": int(cuts), "halo_bytes": 4 * int(halo_voxels),
+            "gathered_pair_bytes": 8 * int(pair_rows)}
+
+
 class FusedSegmentationBase(BaseTask):
     """Whole-ROI fused watershed + merged CC on the device mesh.
 
@@ -67,6 +95,7 @@ class FusedSegmentationBase(BaseTask):
 
     def run_impl(self):
         import jax
+        from jax.sharding import NamedSharding, PartitionSpec
 
         from ..ops.tile_ws import resolved_modes
         from ..parallel.mesh import describe_devices, device_peak_bytes
@@ -89,20 +118,30 @@ class FusedSegmentationBase(BaseTask):
             step, mesh, sp_desc, execution, impl = self._build_step(
                 cfg, roi_shape)
             sp.note(execution=execution, mesh=sp_desc)
+        halo = int(np.max(cfg.get("halo") or 0))
         self.logger.info(
             f"{execution} step on mesh {sp_desc}, roi {roi_shape}, "
-            f"halo={int(np.max(cfg.get('halo') or 0))}; "
+            f"halo={halo}; "
             f"mesh.devices={describe_devices(mesh.devices)}; "
             f"kernels={resolved_modes(impl)}"
         )
         with trace_mod.span("fused.read") as sp:
             vol = np.asarray(inp[roi]).astype(np.float32)
             sp.note(nbytes=int(vol.nbytes))
+        # the input onto the step's own input sharding (batch over dp, the
+        # leading volume axes over the spatial mesh axes), one shard a
+        # device, so that the copy has a span of its own
+        in_sharding = NamedSharding(mesh, PartitionSpec(*mesh.axis_names))
+        with trace_mod.span("fused.h2d", nbytes=int(vol.nbytes)) as sp:
+            x = jax.block_until_ready(jax.device_put(vol[None], in_sharding))
+            sp.note(shards=len(x.addressable_shards))
+        del vol
         # the call up to its return: trace, lower, compile or read the
-        # executable back, host-to-device copy, enqueue
+        # executable back, enqueue
         fun_name = "ws_ccl_step" if execution == "fused" else "ws_ccl_split"
         with trace_mod.span("fused.dispatch", fun_name=fun_name):
-            out = step(vol[None])
+            out = step(x)
+        del x
         with trace_mod.span("fused.wait"):
             ws, cc, n_fg, overflow = jax.block_until_ready(out)
         if bool(np.asarray(overflow)):
@@ -118,7 +157,8 @@ class FusedSegmentationBase(BaseTask):
             key = cfg.get(key_cfg)
             if not key:
                 continue
-            with trace_mod.span("fused.d2h", output=key) as sp:
+            with trace_mod.span("fused.d2h", output=key,
+                                shards=len(data.addressable_shards)) as sp:
                 arr = np.asarray(data[0])
                 sp.note(nbytes=int(arr.nbytes))
             with trace_mod.span("fused.widen", output=key) as sp:
@@ -139,6 +179,8 @@ class FusedSegmentationBase(BaseTask):
             "n_foreground": int(round(float(np.asarray(n_fg)))),
             "mesh": sp_desc,
             "written": written,
+            "collectives": collective_bytes(
+                roi_shape, mesh.devices.shape[1:], halo),
             "device_memory": device_peak_bytes(mesh.devices),
         }
 
@@ -253,6 +295,6 @@ class FusedSegmentationWorkflow(WorkflowBase):
             return {}
         return {
             k: doc[k]
-            for k in ("n_foreground", "written", "mesh")
+            for k in ("n_foreground", "written", "mesh", "collectives")
             if k in doc
         }

@@ -21,9 +21,10 @@ from cluster_tools_tpu.utils.volume_utils import file_reader
 SHAPE = (32, 32, 32)
 
 #: every span of the fused job's table; task.finalize follows task.run
-FUSED_SPANS = ("fused.setup", "fused.read", "io.read", "fused.dispatch",
-               "fused.wait", "fused.d2h", "fused.widen", "fused.write",
-               "io.write", "jax.trace", "jax.lower", "jax.backend_compile")
+FUSED_SPANS = ("fused.setup", "fused.read", "io.read", "fused.h2d",
+               "fused.dispatch", "fused.wait", "fused.d2h", "fused.widen",
+               "fused.write", "io.write", "jax.trace", "jax.lower",
+               "jax.backend_compile")
 
 #: every stage scope the compiled step carries, per fill mode
 STAGE_SCOPES = ("step.halo", "step.globalize", "step.stitch", "step.count",
@@ -103,6 +104,12 @@ def test_nbytes_are_the_arrays_sizes(traced_job):
         assert len(found) == 2                      # once per output
         assert [s["args"]["nbytes"] for s in found] == [width * n] * 2
     assert [s["args"]["output"] for s in _spans(traced_job, "fused.d2h")] == ["ws", "cc"]
+    # the copy to the devices is a span of its own, before the dispatch;
+    # one shard a device, there and back
+    (h2d,), (dispatch,) = _spans(traced_job, "fused.h2d"), _spans(traced_job, "fused.dispatch")
+    assert h2d["args"]["nbytes"] == 4 * n and h2d["args"]["shards"] == 8
+    assert read["ts"] + read["dur"] <= h2d["ts"] and h2d["ts"] + h2d["dur"] <= dispatch["ts"]
+    assert [s["args"]["shards"] for s in _spans(traced_job, "fused.d2h")] == [8, 8]
     (setup,) = _spans(traced_job, "fused.setup")
     assert setup["args"]["execution"] == "fused" and setup["args"]["mesh"] == "sp=8"
 

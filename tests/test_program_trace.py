@@ -246,7 +246,8 @@ def test_every_new_metric_is_declared_for_the_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-10:] == list(NEW_METRICS)
+    # PR 28's ten follow PR 26's six; later cells append themselves to the lists
+    assert [m["name"] for m in bench["per_layer"]][6:16] == list(NEW_METRICS)
     for name in NEW_METRICS:
-        assert declared[name]["workloads"] == ["fused384.volumes"]
+        assert declared[name]["workloads"][0] == "fused384.volumes"
         assert declared[name]["moves"] == "voxels_per_s"

@@ -251,7 +251,9 @@ def test_executor_hung_block_quarantined_and_speculated(tmp_path):
         lambda x: x + 1, blocks,
         lambda b: (data[b.bb],),
         lambda b, raw: out.__setitem__(b.bb, np.asarray(raw)),
-        block_deadline_s=0.15,
+        # above a cold compile of the kernel on a loaded box (block 0 waits
+        # for it and would be called hung too), well under the 0.7 s hang
+        block_deadline_s=0.3,
         watchdog_period_s=0.05,
         failures_path=fp,
         task_name="hang_unit",
@@ -292,7 +294,7 @@ def test_executor_speculative_duplicate_agreement(tmp_path):
         lambda b: (data[b.bb],),
         store,
         on_block_done=lambda b: done.append(int(b.block_id)),
-        block_deadline_s=0.15,
+        block_deadline_s=0.3,    # as above; the hang is 0.5 s
         watchdog_period_s=0.05,
         failures_path=fp,
         task_name="spec_unit",
