@@ -651,8 +651,8 @@ def fill_unseeded_basins_dense(
     max_rounds: Optional[int] = None,
     face_cap: Optional[int] = None,
 ):
-    """Sort-free unseeded-basin fill: face-list scatter-min Boruvka rounds
-    over a compact basin table.
+    """Sort-free unseeded-basin fill: scatter-min Boruvka rounds over a
+    compact basin table and the live prefix of one face list.
 
     Same MSF semantics as :func:`fill_unseeded_basins` with the saddle per
     basin pair the exact minimum over every shared face voxel (the
@@ -673,28 +673,52 @@ def fill_unseeded_basins_dense(
       the root of a 2-cycle) picks what a voxel-indexed table picks: the
       labels, the flag and the number of rounds are the same integers.
 
+    The rounds then cost what their live faces cost.  The three axes' faces
+    are ONE list ``(va, vb, sad, eid)`` whose first ``n_live`` slots are
+    the faces that can still matter.  Every pass of a round (lowest saddle
+    per basin, first face among the ties, the winners' hook) walks that
+    prefix in chunks of ``face_cap / 16`` slots under a loop of
+    ``ceil(n_live / chunk)`` trips — one compiled body, the trip count read
+    from the data.  After a round's closure the list is re-compacted in
+    place: a face whose resolved sides are equal, or with no seedless basin
+    left on either side, is gone for good, and the survivors carry their
+    RESOLVED endpoints (``P`` is closed, so they resolve through every
+    later table as the original ones would; no pass resolves through
+    ``P``).  ``eid``, ``sad`` and both tie-breaks travel with the face:
+    the integers are those of three lists padded to ``face_cap``
+    (``tests/test_dense_fill.py::_fill_n_table_reference``).
+
     Capacities, both derived from ``n`` and both REPORTED through the
     overflow flag when exceeded, never silent: ``face_cap`` (default
-    ``max(2^16, n/6)`` with a 2^24 ceiling) bounds each axis's face list —
+    ``max(2^16, n/6)`` with a 2^24 ceiling) bounds each axis's harvest —
     ≥1.8× the measured ~9%/axis load while n/6 governs (n ≲ 100M),
     narrowing to ~1.4× at 512³ where the int32-memory ceiling binds;
     ``basin_cap = min(n, max(2^16, n/16))`` bounds the seedless basins
-    (80,902 measured at 512³, docs/PERFORMANCE.md "512³ capacity audit";
-    more than n/16 of them means basins under 16 voxels on average, whose
+    (more than n/16 of them means basins under 16 voxels on average, whose
     faces overflow ``face_cap`` first unless they are isolated voxels).
     A code whose terminal voxel does not carry it (not what the flow phase
-    produces) has no id and raises the same flag.
+    produces) has no id and raises the same flag.  An input with exact
+    height ties (a clipped or quantised boundary map: every plateau voxel a
+    basin of its own) truncates the harvest at the default ``face_cap`` and
+    raises it too (ROADMAP D4).
 
-    Cost on the chip: the rounds' passes are random-access gathers and
-    scatters, which a TPU v5e runs at ~45M elem/s whatever the locality
-    (a gather over the 66M voxels of the 384³ step's halo-padded shard:
-    1.48 s, ledger PR 28) — which is why nothing inside the rounds may
-    be volume-sized: what is left there is face-sized or basin-sized
-    (PERF.md section 5).  Volume-sized and paid once: the harvest, the
-    rank, the final resolve.
-    Memory: three per-axis lists of five ``face_cap`` arrays, four
+    Cost on the chip (TPU v5e, the 384³ step's 448×384×384 shard: n = 66M,
+    ``face_cap`` 11.0M, ``basin_cap`` 4.1M; PERF.md section 5 has the
+    traced run).  A round is, per LIVE face, six gathers from
+    ``basin_cap``-sized tables, four ``scatter-min`` and six ``scatter``
+    (two hooks, four of the re-compaction), plus the closure loop on the
+    table; nothing in it is volume-sized or ``face_cap``-sized.  Traced
+    on one volume: five rounds of 14, 6, 2, 1 and 0 trips (9.6M live faces
+    of 33M slots, about 0.4 of them alive a round later) take 2.8 s where
+    the padded lists took 17.7 s; a trip is 0.09 s, a chunk's gather from a
+    4.1M-entry table running at 60M elements/s.  Volume-sized and paid once
+    a job, 7.3 s and now most of the fill: the harvest's twelve gathers
+    over the padded per-axis lists, the rank, the four compactions, the
+    final resolve.
+    Memory: one list of four ``3 * face_cap`` int32 arrays, four
     ``basin_cap`` tables and two volume-sized int32 temporaries (the rank
-    before the rounds, the code table after them).
+    before the rounds, the code table after them); the per-axis arrays of
+    the harvest live beside the list until they are copied into it.
 
     ``values``: >0 seeded label, <= -2 unseeded terminal code
     (``-flat_index - 2``), 0 invalid, and **-1 for masked/padded voxels**
@@ -736,12 +760,16 @@ def fill_unseeded_basins_dense(
     term_id = jnp.where(is_term, jnp.cumsum(is_term.astype(jnp.int32)) - 1, -1)
 
     def to_id(x):
-        """Face endpoint: code -> ``-id - 2``; seeds, -1 and 0 as they are.
-        Also: whether some code here has no id (its terminal does not
-        carry it)."""
+        """Face endpoint: code -> ``-id - 2`` as ``P0`` resolves it (an id
+        past a truncated table reads the table's last entry); seeds, -1 and
+        0 as they are.  Also: whether some code here has no id (its
+        terminal does not carry it)."""
         coded = x <= -2
         tid = term_id[jnp.clip(-x - 2, 0, n - 1)]
-        return jnp.where(coded, -tid - 2, x), jnp.any(coded & (tid < 0))
+        return (
+            jnp.where(coded, jnp.maximum(-tid - 2, -basin_cap - 1), x),
+            jnp.any(coded & (tid < 0)),
+        )
 
     # P[id] = current label of basin id: a seed label, -1, or the -id - 2
     # of the basin it was joined to; ids resolve through it, seeds are
@@ -754,12 +782,20 @@ def fill_unseeded_basins_dense(
     # ---- one-time face harvest (round-invariant superset) ----
     # a face is a candidate edge iff the ORIGINAL codes differ, both are
     # nonzero, and at least one side is an unseeded basin; merging only
-    # shrinks this set (equal-resolved faces drop out via the per-round
-    # predicate), so harvesting once is exact.  eid = axis * n + voxel
+    # shrinks this set, so harvesting once is exact.  eid = axis * n + voxel
     # index is globally distinct and seen identically from both sides, so
     # the min-edge graph is a forest plus 2-cycles (the classic
     # distinct-weight Boruvka argument, as in _fill_core).
-    faces = []
+    # The three axes' faces go into ONE list (va, vb, sad, eid) of 48
+    # chunks (>= 3 * face_cap slots) whose first n_live slots are the
+    # faces: each axis's compacted block lands at the running count, over
+    # the padding of the block before it.  No slot past n_live is read.
+    chunk = -(-face_cap // 16)
+    lists = tuple(
+        _match_vma(jnp.zeros((48 * chunk,), jnp.int32), values)
+        for _ in range(4)
+    )
+    n_live = _match_vma(jnp.zeros((), jnp.int32), values)
     for axis in range(3):
         nb = _shift(values, -1, axis, jnp.int32(0)).ravel()
         ok0 = (
@@ -776,52 +812,74 @@ def fill_unseeded_basins_dense(
         vb, bad_b = to_id(jnp.where(pad, 0, v[ib]))
         trunc = jnp.maximum(trunc, (bad_a | bad_b).astype(jnp.int32))
         sad = jnp.maximum(h[ia], h[ib])
-        eid = jnp.where(
-            pad, i32max, jnp.int32(axis) * jnp.int32(n) + idx_c
+        eid = jnp.int32(axis) * jnp.int32(n) + idx_c
+        lists = tuple(
+            lax.dynamic_update_slice(buf, x, (n_live,))
+            for buf, x in zip(lists, (va, vb, sad, eid))
         )
-        faces.append((va, vb, sad, eid, pad))
+        n_live = n_live + jnp.minimum(n_faces, face_cap)
     me_idx = _match_vma(jnp.arange(basin_cap, dtype=jnp.int32), values)
+    slot = jnp.arange(chunk, dtype=jnp.int32)
+
+    def take(lists, n_live, k):
+        """Chunk ``k`` of the face list, and which of its slots are faces."""
+        a, b, sad, eid = (
+            lax.dynamic_slice(x, (k * chunk,), (chunk,)) for x in lists
+        )
+        return a, b, sad, eid, k * chunk + slot < n_live
 
     def round_cond(s):
-        _, changed, it = s
+        _, changed, it, _, _ = s
         return changed & (it < max_rounds)
 
     def round_body(s):
-        P, _, it = s
-        best_h = _match_vma(
+        # the list holds RESOLVED endpoints: ids of roots, seed labels, -1
+        # (the harvest's under P0, every later round's by the rewrite
+        # below), so no pass resolves through P.  Every pass walks the live
+        # prefix only, chunk by chunk; each is complete over all chunks
+        # before the next starts (a basin's best_h must be final before its
+        # ties are taken), and neither min nor the one winner's set depends
+        # on the order of the chunks.
+        P, _, it, lists, n_live = s
+        trips = (n_live + chunk - 1) // chunk
+
+        def sides(k):
+            a, b, sad, eid, face = take(lists, n_live, k)
+            face = face & (a != b)
+            return [
+                (src, dst, sad, eid, face & (src <= -2),
+                 jnp.clip(-src - 2, 0, basin_cap - 1))
+                for src, dst in ((a, b), (b, a))
+            ]
+
+        def lowest_saddle(k, best_h):
+            for src, _, sad, _, m, _ in sides(k):
+                g = jnp.where(m, -src - 2, basin_cap)
+                best_h = best_h.at[g].min(sad, mode="drop")
+            return best_h
+
+        def first_face(k, best_e):
+            for src, _, sad, eid, m, gsafe in sides(k):
+                tie = m & (best_h[gsafe] == sad)
+                gt = jnp.where(tie, -src - 2, basin_cap)
+                best_e = best_e.at[gt].min(eid, mode="drop")
+            return best_e
+
+        def hook(k, P2):
+            # eid names one face and src one of its sides, so best_e[src]
+            # == eid only where this side tied at best_h[src] and won
+            for src, dst, _, eid, m, gsafe in sides(k):
+                win = m & (best_e[gsafe] == eid)
+                gw = jnp.where(win, -src - 2, basin_cap)
+                P2 = P2.at[gw].set(dst, mode="drop")
+            return P2
+
+        unset = _match_vma(
             jnp.full((basin_cap,), i32max, jnp.int32), values
         )
-        best_e = _match_vma(
-            jnp.full((basin_cap,), i32max, jnp.int32), values
-        )
-        sides = []
-        for va, vb, sad, eid, pad in faces:
-            ra = resolve_flat(P, va)
-            rb = resolve_flat(P, vb)
-            live = ~pad & (ra != rb)
-            sides.append((ra, rb, sad, live, eid))
-            sides.append((rb, ra, sad, live, eid))
-        for src, dst, sad, live, eid in sides:
-            m = live & (src <= -2)
-            g = jnp.where(m, -src - 2, basin_cap)
-            best_h = best_h.at[g].min(
-                jnp.where(m, sad, i32max), mode="drop"
-            )
-        for src, dst, sad, live, eid in sides:
-            m = live & (src <= -2)
-            gsafe = jnp.clip(-src - 2, 0, basin_cap - 1)
-            tie = m & (best_h[gsafe] == sad)
-            gt = jnp.where(tie, -src - 2, basin_cap)
-            best_e = best_e.at[gt].min(
-                jnp.where(tie, eid, i32max), mode="drop"
-            )
-        P2 = P
-        for src, dst, sad, live, eid in sides:
-            m = live & (src <= -2)
-            gsafe = jnp.clip(-src - 2, 0, basin_cap - 1)
-            win = m & (best_h[gsafe] == sad) & (best_e[gsafe] == eid)
-            gw = jnp.where(win, -src - 2, basin_cap)
-            P2 = P2.at[gw].set(jnp.where(win, dst, 0), mode="drop")
+        best_h = lax.fori_loop(0, trips, lowest_saddle, unset)
+        best_e = lax.fori_loop(0, trips, first_face, unset)
+        P2 = lax.fori_loop(0, trips, hook, P)
         # break 2-cycles (two roots that picked the same edge from both
         # sides): the smaller id, which is the smaller terminal index,
         # stays a root
@@ -845,11 +903,37 @@ def fill_unseeded_basins_dense(
 
         P2, _ = lax.while_loop(comp_cond, comp_body, (P2, _true_like(P2)))
         changed = jnp.any(P2 != P)
-        return P2, changed, it + 1
 
-    P, unconverged, _ = lax.while_loop(
-        round_cond, round_body, (P0, _true_like(v), jnp.int32(0))
-    )
+        # drop the dead, rewrite the living: a face whose resolved sides
+        # are equal never parts again (merging only coarsens), and one with
+        # no basin on either side never hooks (seed labels and -1 are
+        # final).  P2 is closed, so a survivor's resolved endpoints resolve
+        # through every later table as its original ones would.  Compacted
+        # in place, chunk by chunk: a chunk's survivors land at the running
+        # count, which never passes the chunk's own start.
+        def drop_dead(k, c):
+            kept, n_kept = c
+            a, b, sad, eid, face = take(kept, n_live, k)
+            ra = resolve_flat(P2, a)
+            rb = resolve_flat(P2, b)
+            keep = face & (ra != rb) & ((ra <= -2) | (rb <= -2))
+            packed, n_keep = _compact(keep, (ra, rb, sad, eid), chunk, 0)
+            kept = tuple(
+                lax.dynamic_update_slice(buf, x, (n_kept,))
+                for buf, x in zip(kept, packed)
+            )
+            return kept, n_kept + n_keep
+
+        kept, n_kept = lax.fori_loop(
+            0, trips, drop_dead, (lists, jnp.zeros_like(n_live))
+        )
+        return P2, changed, it + 1, kept, n_kept
+
+    with jax.named_scope("ws.fill.rounds"):
+        P, unconverged, _, _, _ = lax.while_loop(
+            round_cond, round_body,
+            (P0, _true_like(v), jnp.int32(0), lists, n_live),
+        )
     # ---- back to the voxels: ids -> codes at the terminals' positions,
     # then one volume-sized gather as the codes name those positions ----
     root_pos = term_pos[jnp.clip(-P - 2, 0, basin_cap - 1)]

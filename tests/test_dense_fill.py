@@ -7,6 +7,8 @@ minima — the semantics both fill implementations target; the dense fill
 must match it bit-for-bit since it examines every face voxel.
 """
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -304,18 +306,25 @@ def test_mode_env_flip_retraces_without_clear_caches(rng, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _fill_n_table_reference(values, height):
-    """The fill as it was before the basins had dense ids, kept here as the
-    plain reference: the union table ``P``, ``best_h`` / ``best_e``, the
-    2-cycle break and the closure loop have one entry per VOXEL and are
-    indexed by a basin's terminal position.  Same harvest, same rounds,
-    same tie-breaks; the compact table must give the same integers."""
+def _fill_n_table_reference(values, height, face_cap=None):
+    """The fill as it was before the basins had dense ids and before the
+    rounds walked a live prefix, kept here as the plain reference: the union
+    table ``P``, ``best_h`` / ``best_e``, the 2-cycle break and the closure
+    loop have one entry per VOXEL and are indexed by a basin's terminal
+    position, and every round passes over three lists padded to
+    ``face_cap``, resolving the ORIGINAL endpoints.  Same harvest, same
+    rounds, same tie-breaks; the fill must give the same integers.
+
+    Returns ``(resolved, flag, live)``: ``live[r]`` counts, at the start of
+    round ``r``, the faces that can still matter (sides resolved unequal, a
+    seedless basin on one of them): what the fill's live prefix holds."""
     shape = values.shape
     n = int(np.prod(shape))
     v = values.ravel()
     h = _sortable_float_key(height.astype(jnp.float32)).ravel()
     i32max = jnp.iinfo(jnp.int32).max
-    face_cap = min(1 << 24, max(1 << 16, n // 6))
+    if face_cap is None:
+        face_cap = min(1 << 24, max(1 << 16, n // 6))
     max_rounds = _auto_fill_rounds(n)
     P0 = _match_vma(-jnp.arange(n, dtype=jnp.int32) - 2, values)
 
@@ -341,11 +350,11 @@ def _fill_n_table_reference(values, height):
         faces.append((va, vb, sad, eid, pad))
 
     def round_cond(s):
-        _, changed, it = s
+        _, changed, it, _ = s
         return changed & (it < max_rounds)
 
     def round_body(s):
-        P, _, it = s
+        P, _, it, n_live = s
         best_h = _match_vma(jnp.full((n,), i32max, jnp.int32), values)
         best_e = _match_vma(jnp.full((n,), i32max, jnp.int32), values)
         sides = []
@@ -353,6 +362,9 @@ def _fill_n_table_reference(values, height):
             ra = resolve_flat(P, va)
             rb = resolve_flat(P, vb)
             live = ~pad & (ra != rb)
+            n_live = n_live.at[it].add(
+                jnp.sum(live & ((ra <= -2) | (rb <= -2)), dtype=jnp.int32)
+            )
             sides.append((ra, rb, sad, live, eid))
             sides.append((rb, ra, sad, live, eid))
         for src, dst, sad, live, eid in sides:
@@ -384,13 +396,15 @@ def _fill_n_table_reference(values, height):
         P2, _ = lax.while_loop(
             lambda t: t[1], comp_body, (P2, _true_like(P2))
         )
-        return P2, jnp.any(P2 != P), it + 1
+        return P2, jnp.any(P2 != P), it + 1, n_live
 
-    P, unconverged, _ = lax.while_loop(
-        round_cond, round_body, (P0, _true_like(v), jnp.int32(0))
+    n_live0 = _match_vma(jnp.zeros((max_rounds,), jnp.int32), values)
+    P, unconverged, _, n_live = lax.while_loop(
+        round_cond, round_body, (P0, _true_like(v), jnp.int32(0), n_live0)
     )
     resolved = resolve_flat(P, v).reshape(shape)
-    return resolved, jnp.maximum(unconverged.astype(jnp.int32), trunc)
+    flag = jnp.maximum(unconverged.astype(jnp.int32), trunc)
+    return resolved, flag, n_live
 
 
 def _masked_case(seed, shape, seed_frac):
@@ -431,31 +445,90 @@ def _two_cycle_case():
     return vals, height
 
 
+def _plateau_case():
+    """ROADMAP D4's input at the fill's door: a boundary map whose clipped
+    noise leaves a sixth of the heights at exactly 0.0
+    (``utils/synthetic.py``), through the seed and flow phases.  Every
+    plateau voxel is a basin of its own, the face lists truncate at the
+    default ``face_cap`` and the flag is raised: a fault this fill keeps
+    exactly as the padded lists have it."""
+    from cluster_tools_tpu.ops import tile_ws
+    from cluster_tools_tpu.utils.synthetic import synthetic_em_volume
+
+    boundaries, _, _ = synthetic_em_volume(
+        (64, 64, 64), n_objects=12, sampling=(1, 1, 1), with_mask=False, seed=1
+    )
+    assert 0.1 < (boundaries == 0.0).mean() < 0.25
+    b = jnp.asarray(boundaries)
+    kernels = dict(
+        impl="xla", tile=None, table_cap=tile_ws.DEFAULT_TABLE_CAP,
+        interpret=False,
+    )
+    seeds, valid, _ = tile_ws._dt_seeds_core(
+        b, None, None, threshold=0.5, sigma_seeds=0.0, min_seed_distance=0.0,
+        sampling=None, dt_max_distance=None, pair_cap=None, edge_cap=None,
+        seed_cap=None, seed_mode="tiled", **kernels,
+    )
+    vals, height, _ = tile_ws._ws_flow_core(
+        b, seeds, valid, exit_cap=None, **kernels
+    )
+    return np.asarray(vals), np.asarray(height)
+
+
+#: name -> (inputs, ``face_cap`` (None: the default), the flag both raise,
+#: the chunks the live faces span at the start of each round (None: not held))
 _EQUALITY_CASES = {
-    "seeds_0.5": lambda: _masked_case(11, (14, 15, 16), 0.5),
-    "seeds_0.15": lambda: _masked_case(12, (14, 15, 16), 0.15),
-    "seeds_0.02": lambda: _masked_case(13, (16, 18, 20), 0.02),
-    "two_cycle": _two_cycle_case,
-    "deep_chain": lambda: _chain_case(40),
+    "seeds_0.5": (lambda: _masked_case(11, (14, 15, 16), 0.5), None, 0, (1, 1, 0)),
+    "seeds_0.15": (lambda: _masked_case(12, (14, 15, 16), 0.15), None, 0, None),
+    "seeds_0.02": (lambda: _masked_case(13, (16, 18, 20), 0.02), None, 0, None),
+    "two_cycle": (_two_cycle_case, None, 0, None),
+    "deep_chain": (lambda: _chain_case(40), None, 0, (1, 0)),
+    # several chunks in round one, one in round two
+    "chunks_7_1": (lambda: _masked_case(11, (14, 15, 16), 0.5), 4000, 0, (7, 1, 0)),
+    # fewer chunks every round, never one
+    "chunks_9_6_4_3": (
+        lambda: _masked_case(22, (10, 11, 12), 0.02), 1600, 0, (9, 6, 4, 3, 0)
+    ),
+    # the list empties after round one; round two finds nothing and ends the loop
+    "deep_chain_chunks_14_0": (lambda: _chain_case(40), 41, 0, (14, 0)),
+    "two_cycle_chunks_4_2": (_two_cycle_case, 4, 0, (4, 2, 0)),
+    "plateau_d4": (_plateau_case, None, 1, None),
+    "face_cap_truncated": (lambda: _masked_case(12, (14, 15, 16), 0.15), 200, 1, None),
+    "face_cap_truncated_one_slot": (_two_cycle_case, 1, 1, None),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_EQUALITY_CASES))
 def test_compact_table_equals_n_table(case):
-    vals, height = _EQUALITY_CASES[case]()
-    got, flag = fill_unseeded_basins_dense(jnp.asarray(vals), jnp.asarray(height))
-    want, want_flag = _fill_n_table_reference(
-        jnp.asarray(vals), jnp.asarray(height)
+    make, face_cap, raised, chunks = _EQUALITY_CASES[case]
+    vals, height = make()
+    got, flag = fill_unseeded_basins_dense(
+        jnp.asarray(vals), jnp.asarray(height), face_cap=face_cap
+    )
+    want, want_flag, live = _fill_n_table_reference(
+        jnp.asarray(vals), jnp.asarray(height), face_cap=face_cap
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert int(flag) == int(want_flag) == 0
+    assert int(flag) == int(want_flag) == raised
     assert (vals <= -2).any()  # the case has seedless basins at all
+    if chunks is not None:
+        chunk = -(-(face_cap or 1 << 16) // 16)
+        spans = [-(-int(x) // chunk) for x in np.asarray(live)]
+        assert tuple(spans[: len(chunks)]) == chunks
     if case == "two_cycle":
         assert (np.asarray(got)[1, 1] == [1, 1, 1, 1, 1, 1, 2, 2]).all()
         c_code = -(0 * 24 + 0 * 8 + 3) - 2
         assert (np.asarray(got)[0, 0] == [0, 0] + [c_code] * 4 + [0, 0]).all()
     if case.startswith("seeds"):
         assert (vals == -1).any()
+    if case == "plateau_d4":
+        # with room for every face the same input converges: D4's flag is
+        # the lists' truncation, not the rounds' tie-break
+        n = vals.size
+        _, roomy = fill_unseeded_basins_dense(
+            jnp.asarray(vals), jnp.asarray(height), face_cap=n
+        )
+        assert int(roomy) == 0
 
 
 @pytest.mark.parametrize("extra_basins", [0, 1])
@@ -490,23 +563,28 @@ def test_code_without_terminal_raises_flag():
     assert int(flag) == 1
 
 
-def _two_blocks():
+def _two_blocks(face_cap=None):
     a = _masked_case(21, (10, 11, 12), 0.15)
     b = _masked_case(22, (10, 11, 12), 0.02)
     vals = np.stack([a[0], b[0]])
     height = np.stack([a[1], b[1]])
     want = [
-        _fill_n_table_reference(jnp.asarray(v), jnp.asarray(h))
+        _fill_n_table_reference(jnp.asarray(v), jnp.asarray(h), face_cap=face_cap)
         for v, h in zip(vals, height)
     ]
     return vals, height, want
 
 
-def test_compact_table_under_vmap():
+#: ``face_cap`` 400 makes a chunk 25 slots: the two blocks' 863 and 829 faces
+#: are 35 and 34 chunks in round one and 20 and 24 in round two
+@pytest.mark.parametrize("face_cap", [None, 400])
+def test_compact_table_under_vmap(face_cap):
     """Two blocks with different basin counts as lanes of one program (the
-    blockwise executor's form): each lane equals its own n-table run."""
-    vals, height, want = _two_blocks()
-    got, flag = jax.vmap(fill_unseeded_basins_dense)(
+    blockwise executor's form): each lane equals its own n-table run.  With
+    the small ``face_cap`` the lanes' loops run different trip counts in
+    every round."""
+    vals, height, want = _two_blocks(face_cap)
+    got, flag = jax.vmap(partial(fill_unseeded_basins_dense, face_cap=face_cap))(
         jnp.asarray(vals), jnp.asarray(height)
     )
     for lane in range(2):
@@ -514,14 +592,20 @@ def test_compact_table_under_vmap():
             np.asarray(got[lane]), np.asarray(want[lane][0])
         )
         assert int(flag[lane]) == int(want[lane][1]) == 0
+    if face_cap is not None:
+        chunk = -(-face_cap // 16)
+        spans = [[-(-int(x) // chunk) for x in np.asarray(w[2])[:2]] for w in want]
+        assert spans == [[35, 20], [34, 24]]
 
 
+@pytest.mark.parametrize("face_cap", [None, 400])
 @pytest.mark.parametrize("check_vma", [False, True])
-def test_compact_table_under_shard_map(check_vma):
+def test_compact_table_under_shard_map(check_vma, face_cap):
     """The mesh step's form: the per-shard body under ``shard_map`` on a
     one-device (dp, sp) mesh.  The pipeline turns the vma check off (its
-    Pallas kernels); with it on, every fresh table of the fill must carry
-    the data's varying axes (``_match_vma``)."""
+    Pallas kernels); with it on, every fresh table and list of the fill must
+    carry the data's varying axes (``_match_vma``).  With the small
+    ``face_cap`` the list is 35 chunks in round one."""
     from jax.sharding import PartitionSpec
 
     from cluster_tools_tpu.compat import shard_map
@@ -530,10 +614,10 @@ def test_compact_table_under_shard_map(check_vma):
     mesh = make_mesh(
         1, axis_names=("dp", "sp"), devices=backend_devices("local")[:1]
     )
-    vals, height, want = _two_blocks()
+    vals, height, want = _two_blocks(face_cap)
 
     def body(v, h):
-        out, flag = fill_unseeded_basins_dense(v[0], h[0])
+        out, flag = fill_unseeded_basins_dense(v[0], h[0], face_cap=face_cap)
         return out[None], lax.pmax(flag, ("dp", "sp"))
 
     spec = PartitionSpec("dp", "sp")

@@ -251,3 +251,74 @@ def test_every_new_metric_is_declared_for_the_cell():
     for name in NEW_METRICS:
         assert declared[name]["workloads"][0] == "fused384.volumes"
         assert declared[name]["moves"] == "voxels_per_s"
+
+
+# -- PR 32's reader: the fill's round loop ------------------------------------
+
+FILL = STEP + "ws.fill/ws.fill.dense/"
+ROUNDS = FILL + "ws.fill.rounds/while/body/"
+#: two rounds of the loop: a pass of 3 trips then 1, the closure loop, and a
+#: once-a-job gather outside the scope
+ROUND_OPS = (
+    ("fusion.20", "fusion", 16.0, 1.0, FILL + "gather:"),
+    ("while.21", "while", 17.0, 4.5, ""),          # a TPU trace gives a while no path
+    ("while.22", "while", 17.0, 3.0, ""),
+    ("fusion.23", "fusion", 17.0, 1.0, ROUNDS + "while/body/scatter-min:"),
+    ("fusion.23", "fusion", 18.0, 1.0, ROUNDS + "while/body/scatter-min:"),
+    ("fusion.23", "fusion", 19.0, 1.0, ROUNDS + "while/body/scatter-min:"),
+    ("while.24", "while", 20.0, 0.5, ""),
+    ("fusion.25", "fusion", 20.0, 0.25, ROUNDS + "while/body/gather:"),
+    ("fusion.25", "fusion", 20.25, 0.25, ROUNDS + "while/body/gather:"),
+    ("while.22", "while", 20.5, 0.5, ""),
+    ("fusion.23", "fusion", 20.5, 0.5, ROUNDS + "while/body/scatter-min:"),
+    ("while.24", "while", 21.0, 0.25, ""),
+    ("fusion.25", "fusion", 21.0, 0.25, ROUNDS + "while/body/gather:"),
+    ("fusion.26", "fusion", 21.25, 0.125, ROUNDS + "any:"),
+    # a last round that finds no face: its passes run no trip and leave no event
+    ("while.24", "while", 21.375, 0.125, ""),
+    ("fusion.25", "fusion", 21.375, 0.125, ROUNDS + "while/body/gather:"),
+)
+
+
+@pytest.fixture
+def traced_rounds(traced):
+    """``traced`` with the fill's single fusion replaced by a round loop."""
+    ops = [op for op in DEVICE_OPS if op[0] != "fusion.6"] + list(ROUND_OPS)
+    texts = {}
+    for n, oc, _, _, tf in ops:
+        texts.setdefault(_op_text(n, oc), tf)
+    device = _plane(
+        "/device:TPU:0", stat_names=("tf_op",),
+        event_metadata=[(i + 1, text, {"tf_op": tf}) for i, (text, tf) in enumerate(texts.items())])
+    with open(program_trace.trace_file(traced), "wb") as f:
+        f.write(device)
+    program_trace._read.cache_clear()
+    traced["trace"].ops = [reduce_trace.Op(n, oc, _op_text(n, oc), s, d) for n, oc, s, d, _ in ops]
+    return traced
+
+
+def test_rounds_reader_sums_the_loop_and_lists_its_rounds(traced_rounds, capfd):
+    assert _read_metric("ws_fill_rounds_device_s", traced_rounds) == pytest.approx(4.5)
+    listed = [line for line in capfd.readouterr().err.splitlines()
+              if line.startswith("[ws_fill_rounds]")]
+    assert len(listed) == 3
+    assert "round 1:   3.500s  while.22 x3 3.000s  while.24 x2 0.500s" in listed[0]
+    assert "round 2:   0.875s  while.22 x1 0.500s  while.24 x1 0.250s" in listed[1]
+    assert "round 3:   0.125s  while.24 x1 0.125s" in listed[2]
+    # the stage metric keeps the whole of ws.fill: the loop and the gather outside it
+    assert _read_metric("ws_fill_device_s", traced_rounds) == pytest.approx(5.5)
+
+
+def test_rounds_reader_returns_nothing_without_the_scope(traced):
+    """The parent commit's program has ``ws.fill.dense`` and no
+    ``ws.fill.rounds``; ``selfcheck``'s trace has no stage at all."""
+    assert _read_metric("ws_fill_rounds_device_s", traced) is None
+    assert _read_metric("ws_fill_rounds_device_s", _selfcheck_traced()) is None
+
+
+def test_rounds_metric_is_declared_for_both_cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (mine,) = [m for m in bench["per_layer"] if m["name"] == "ws_fill_rounds_device_s"]
+    assert mine["workloads"][:2] == ["fused384.volumes", "fused4x384.volumes.sp4"]
+    assert (mine["layer"], mine["moves"]) == ("kernels", "voxels_per_s")
