@@ -2356,13 +2356,6 @@ def main():
         accel = None
     else:
         accel = _probe_accelerator(PROBE_TIMEOUT)
-    # bench choice, ALL substrates: sparse seed-plateau labeling (exact
-    # below ~6% maxima density — the bench volume measures ~1.4%; any
-    # truncation lands in the JSON's overflow flag).  Drops the largest
-    # single contributor to the fused step's compile cost AND a
-    # full tiled-CCL pass at runtime; the cpu smoke's device-shaped
-    # sub-entry measures the same program that ships on the accelerator.
-    os.environ.setdefault("CT_SEED_CCL", "sparse")
     # fill machinery follows the library's substrate-aware auto default
     # (dense on cpu, capacity on tpu — see tile_ws)
     if accel is None:
@@ -2860,16 +2853,7 @@ def main():
         msd2 = min_seed_distance * min_seed_distance
         mx = jax.jit(lambda d, m: local_maxima(d, 1) & m & (d >= msd2))
         stages["maxima"], maxima_ = _timeit("stage maxima", mx, dist_, fg_, runs=2)
-        # time the seed-labeling program the fused step ACTUALLY runs
-        # (CT_SEED_CCL governs both, set above for every substrate)
-        if os.environ.get("CT_SEED_CCL") == "sparse":
-            from cluster_tools_tpu.ops.tile_ccl import label_components_sparse
-
-            sccl = jax.jit(lambda m: label_components_sparse(m)[0])
-        else:
-            sccl = jax.jit(
-                lambda m: label_components_tiled(m, impl=sub_impl)[0]
-            )
+        sccl = jax.jit(lambda m: label_components_tiled(m, impl=sub_impl)[0])
         stages["seed_ccl"], _ = _timeit("stage seed CCL", sccl, maxima_, runs=2)
         return stages
 

@@ -34,7 +34,6 @@ helpers).  Overflow of any capacity is reported, never silently wrong.
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional, Tuple
 
@@ -56,6 +55,19 @@ DEFAULT_PAIR_CAP = 1 << 21
 # single-shard volumes (docs/PERFORMANCE.md "512³ capacity audit").
 DEFAULT_EDGE_CAP = 1 << 21
 DEFAULT_TABLE_CAP = 64
+
+
+def resolve_impl(impl: str) -> str:
+    """Which kernels the tiled CCL and watershed compile for ``impl``:
+    ``pallas`` (Mosaic VMEM kernels) or ``xla`` (their portable twins).
+    ``auto`` is ``pallas`` exactly when the default backend is a TPU;
+    ``tiled`` is the mesh step's name for ``xla``.  Called while tracing,
+    from what the caller passed and the platform alone."""
+    if impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if impl == "tiled":
+        return "xla"
+    return impl
 
 
 def _round_up(v: int, m: int) -> int:
@@ -138,33 +150,8 @@ def _compact(
     return tuple(out), n_kept
 
 
-def tier_mode() -> str:
-    """Capacity-tier compile mode, from ``CT_TIER_MODE``.
-
-    - ``cond`` (default): both tiers compiled, selected at runtime by
-      ``lax.cond`` — exact for any input.
-    - ``big``: only the full-capacity tier is compiled.  Exact for any
-      input; gives up the small tier's runtime win.
-    - ``small``: only the 1/16 tier is compiled.  Exact whenever the live
-      count fits the small tier (the common case the tier exists for);
-      inputs that don't fit are truncated and reported through the site's
-      overflow channel, never silently.
-
-    ``big``/``small`` exist to shrink the compiled program: every tiered
-    site otherwise duplicates a sort-heavy merge core into both branches
-    of its cond (~24% of the fused step's HLO), which matters on backends
-    where compile time, not runtime, is the binding constraint.
-    """
-    mode = os.environ.get("CT_TIER_MODE", "cond")
-    if mode not in ("cond", "big", "small"):
-        raise ValueError(
-            f"CT_TIER_MODE must be cond/big/small, got {mode!r}"
-        )
-    return mode
-
-
 def run_capacity_tiered(arrays, n_total, big_cap, core, n_padded,
-                        max_rounds, vma_like, trunc_fold=None):
+                        max_rounds, vma_like):
     """Run ``core(*arrays, cap, max_rounds, vma_like)`` at 1/16 capacity
     when the runtime entry count allows.
 
@@ -183,15 +170,8 @@ def run_capacity_tiered(arrays, n_total, big_cap, core, n_padded,
     live in :func:`build_remap_tables` (this module),
     ``tile_ws.chase_exits``, and ``tile_ws.value_join`` — retune the
     ratio in ALL of these together.
-
-    :func:`tier_mode` selects which tiers are compiled.  In ``small``
-    mode an input that doesn't fit is truncated and the truncation is
-    folded into the output's LAST element (``max`` against an int32 flag
-    by default; pass ``trunc_fold(last, trunc_int32)`` when the last
-    element is a count rather than a flag).
     """
     small_n = min(big_cap, max(3 * 16384, arrays[0].shape[0] // 16))
-    mode = tier_mode()
 
     def _small(args):
         compacted, _ = _compact(args[0] < BIG, args, small_n, BIG)
@@ -208,16 +188,8 @@ def run_capacity_tiered(arrays, n_total, big_cap, core, n_padded,
     def _big(args):
         return core(*args, big_cap, max_rounds, vma_like)
 
-    if mode == "big" or small_n >= big_cap:
+    if small_n >= big_cap:
         return _big(tuple(arrays))
-    if mode == "small":
-        out = _small(tuple(arrays))
-        trunc = (n_total > small_n).astype(jnp.int32)
-        last = (
-            trunc_fold(out[-1], trunc) if trunc_fold is not None
-            else jnp.maximum(out[-1], trunc)
-        )
-        return out[:-1] + (last,)
     return lax.cond(n_total <= small_n, _small, _big, tuple(arrays))
 
 
@@ -374,8 +346,7 @@ def build_remap_tables(
     """
     n_in = tile_ids.shape[0]
     small_n = max(16384, n_in // 16)
-    mode = tier_mode()
-    if small_n < n_in and mode != "big":
+    if small_n < n_in:
         n_live = (tile_ids < BIG).sum()
 
         def _small(args):
@@ -384,12 +355,6 @@ def build_remap_tables(
 
         def _big(args):
             return _remap_tables_core(*args, n_tiles, table_cap)
-
-        if mode == "small":
-            old_tbl, new_tbl, overflow = _small(
-                (tile_ids, old_vals, new_vals)
-            )
-            return old_tbl, new_tbl, overflow | (n_live > small_n)
 
         return lax.cond(
             n_live <= small_n, _small, _big, (tile_ids, old_vals, new_vals)
@@ -437,82 +402,13 @@ def resolve_labels_gather(
     return jnp.where(flat >= BIG, jnp.int32(BIG), out).reshape(labels.shape)
 
 
-@partial(jax.jit, static_argnames=("cap",))
-def label_components_sparse(mask: jnp.ndarray, cap: Optional[int] = None):
-    """Connected components (connectivity 1) of a SPARSE 3-D mask.
-
-    Output shape of :func:`label_components_tiled` — int32 labels holding
-    a per-component representative flat index, ``mask.size`` for
-    background — but the representative is the component's minimum flat
-    index in ARRAY order, where the tiled labeler picks the minimum in
-    its padded/tiled order: the two agree for components contained in one
-    tile and may differ (same partition, different id) for tile-spanning
-    components.  Callers treat these ids as opaque distinct tokens
-    (relabel/offset downstream), so the modes are interchangeable as
-    segmentations, not as raw id values.
-
-    Cost scales with the POPCOUNT capacity ``cap`` (default
-    ``max(3*16384, size/16)``), not with the tile grid: set voxels are
-    compacted, a 3-axis adjacency is built in compacted-slot space via
-    the dense rank array (one gather per axis — no sorts anywhere), and
-    the slot-space union-find resolves in one
-    :func:`~cluster_tools_tpu.ops.unionfind.union_find` while-loop.
-
-    Built for the watershed's seed-plateau labeling (maxima measure ~1.4%
-    of the bench volume at ``min_seed_distance=2``): the full tiled CCL
-    machinery is ~1.4k HLO lines and was the largest single contributor
-    to the fused step's remote-compile cost; this is ~1/10 the program.
-    Returns ``(labels, overflow)`` — overflow True when set voxels exceed
-    ``cap`` (labels then unreliable; raise ``cap``).
-    """
-    if mask.ndim != 3:
-        raise ValueError("label_components_sparse expects a 3-D mask")
-    from .unionfind import union_find
-
-    z, y, x = mask.shape
-    n = z * y * x
-    if n >= BIG:
-        raise ValueError(f"volume {mask.shape} has >= 2**30 voxels; shard it")
-    if cap is None:
-        cap = min(n, max(3 * 16384, n // 16))
-    flat = mask.ravel()
-    idx = _match_vma(jnp.arange(n, dtype=jnp.int32), mask)
-    (cidx,), n_live = _compact(flat, (idx,), cap, n)
-    overflow = n_live > cap
-    # dense rank: slot of any set voxel (the same cumsum _compact used)
-    rank = jnp.cumsum(flat.astype(jnp.int32)) - 1
-    pair_lists = []
-    slot_ids = _match_vma(jnp.arange(cap, dtype=jnp.int32), mask)
-    live = cidx < n
-    for step, bound_ok in (
-        (y * x, (cidx // (y * x)) + 1 < z),
-        (x, (cidx // x) % y + 1 < y),
-        (1, cidx % x + 1 < x),
-    ):
-        nb = jnp.clip(cidx + step, 0, n - 1)
-        ok = live & bound_ok & flat[nb]
-        # (slot, neighbor slot); invalid pairs become self-loop no-ops
-        pair_lists.append(
-            jnp.stack(
-                [
-                    jnp.where(ok, slot_ids, 0),
-                    jnp.where(ok, rank[nb], 0),
-                ],
-                axis=1,
-            )
-        )
-    parent = union_find(jnp.concatenate(pair_lists, axis=0), cap)
-    # representative flat index per slot; ascending compaction makes the
-    # min slot the min flat index
-    rep = cidx[parent]
-    out = jnp.full((n + 1,), jnp.int32(n))
-    out = _match_vma(out, mask)
-    out = out.at[jnp.where(live, cidx, n)].set(
-        jnp.where(live, rep, n), mode="drop"
-    )
-    return out[:n].reshape(mask.shape), overflow
-
-
+@partial(
+    jax.jit,
+    static_argnames=(
+        "connectivity", "impl", "tile", "pair_cap", "edge_cap", "table_cap",
+        "interpret",
+    ),
+)
 def label_components_tiled(
     mask: jnp.ndarray,
     connectivity: int = 1,
@@ -534,53 +430,17 @@ def label_components_tiled(
     component's minimum index in the *padded, tiled* order, which is a
     canonical choice but not necessarily the minimum in array order.
 
-    ``impl``: "pallas" (TPU VMEM kernels), "xla" (portable), or "auto"
-    (pallas exactly when the default backend is TPU).  ``connectivity`` must
-    be 1 (face connectivity) — callers needing the full neighborhood use the
-    legacy kernel.  Capacities default to volume-scaled values (static,
-    shape-derived); pass explicit caps for workloads with unusually many
-    fragments per tile face.
-
-    ``CT_TIER_MODE`` is resolved here, OUTSIDE the jit boundary, and passed
-    down as a static argument — flipping the env var mid-process correctly
-    retraces (no stale-cache surprise).  Callers that wrap this function in
-    their own ``jax.jit`` capture the mode at their own trace time, the
-    usual closure semantics.
+    ``impl``: "pallas", "xla", "tiled" or "auto" (:func:`resolve_impl`).
+    ``connectivity`` must be 1 (face connectivity) — callers needing the full
+    neighborhood use the legacy kernel.  Capacities default to volume-scaled
+    values (static, shape-derived); pass explicit caps for workloads with
+    unusually many fragments per tile face.
     """
-    return _label_components_tiled_jit(
-        mask, connectivity=connectivity, impl=impl, tile=tile,
-        pair_cap=pair_cap, edge_cap=edge_cap, table_cap=table_cap,
-        interpret=interpret, _tier=tier_mode(),
-    )
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "connectivity", "impl", "tile", "pair_cap", "edge_cap", "table_cap",
-        "interpret", "_tier",
-    ),
-)
-def _label_components_tiled_jit(
-    mask: jnp.ndarray,
-    connectivity: int = 1,
-    impl: str = "auto",
-    tile: Optional[Tuple[int, int, int]] = None,
-    pair_cap: Optional[int] = None,
-    edge_cap: Optional[int] = None,
-    table_cap: int = DEFAULT_TABLE_CAP,
-    interpret: bool = False,
-    _tier: str = "cond",
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    # _tier is keying-only: the tiered sites below read tier_mode() at trace
-    # time, and including the resolved value in the static key guarantees
-    # that read always matches the cache entry being built.
     if mask.ndim != 3:
         raise ValueError("label_components_tiled expects a 3-D mask")
     if connectivity != 1:
         raise ValueError("tiled CCL supports connectivity=1 only")
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    impl = resolve_impl(impl)
 
     z, y, x = mask.shape
     tile = _tile_for(mask.shape) if tile is None else tile

@@ -33,11 +33,10 @@ restructures the resolution:
    ``capacity`` (auto on tpu AND gpu) runs the rounds on a compacted
    basin-boundary edge list with run-start saddle sampling (~1/18 the
    transient memory, not exact).  Basins with no seeded reachable
-   neighbor keep label 0 (legacy behavior).  All mode env vars
-   (``CT_FILL_MODE``/``CT_SEED_CCL``/``CT_TIER_MODE``) are resolved at
-   the public entry points, OUTSIDE jit, and folded into the compile
-   key — flipping one mid-process retraces, no ``jax.clear_caches()``
-   needed.
+   neighbor keep label 0 (legacy behavior).  ``CT_FILL_MODE`` is the
+   one environment variable the kernels read: at the public entry points,
+   OUTSIDE jit, and folded into the compile key — flipping it mid-process
+   retraces, no ``jax.clear_caches()`` needed.
 
 When every basin is seeded (e.g. the oracle test's fully-seeded minima) the
 result is bit-identical to the legacy kernel; only unseeded-basin fill order
@@ -67,8 +66,9 @@ from .tile_ccl import (
     _tile_for,
     _tile_id_of,
     build_remap_tables,
+    label_components_tiled,
+    resolve_impl,
     run_capacity_tiered,
-    tier_mode,
 )
 
 _BIGF = np.float32(3e38)
@@ -106,8 +106,7 @@ def _resolve_fill_mode(fill_mode: Optional[str]) -> str:
     - ``dense`` on the **cpu** backend only: sort-free scatter-min Boruvka
       over the harvested basin faces and a compact basin table — exact
       min saddles, capacities derived from the volume's size
-      (:func:`fill_unseeded_basins_dense`), 3.8x faster end-to-end at
-      128^3 on the host, where gathers are cache-friendly.
+      (:func:`fill_unseeded_basins_dense`).
     - ``capacity`` everywhere else (tpu AND gpu): compacted lists +
       dedup sorts, saddles SAMPLED at run starts.  It is the cheaper
       machine on the chip and not an exact one: the benchmark's
@@ -127,19 +126,6 @@ def _resolve_fill_mode(fill_mode: Optional[str]) -> str:
             f"CT_FILL_MODE must be auto/capacity/dense, got {fill_mode!r}"
         )
     return fill_mode
-
-
-def _resolve_seed_mode(seed_mode: Optional[str]) -> str:
-    """Resolve the seed-plateau CCL program (``None`` -> ``CT_SEED_CCL``).
-
-    Like :func:`_resolve_fill_mode`, resolved pre-jit so the env var is
-    folded into the compile key.
-    """
-    if seed_mode is None:
-        seed_mode = os.environ.get("CT_SEED_CCL", "tiled")
-    if seed_mode not in ("tiled", "sparse"):
-        raise ValueError(f"CT_SEED_CCL must be tiled/sparse, got {seed_mode!r}")
-    return seed_mode
 
 
 def _sortable_float_key(f: jnp.ndarray) -> jnp.ndarray:
@@ -392,15 +378,9 @@ def collect_negative_values(
     # the value-dedup sort runs at the static sum-of-family-caps concat
     # size (≤ 6*cap; ~half of it at 512³ thanks to the strip-size bounds
     # above) — tier it like the merge cores (shared rationale in
-    # run_capacity_tiered).  Note the 1/16 small tier's exact envelope
-    # scales with this concat, so CT_TIER_MODE=small covers ~half the
-    # live-entry range it did with untrimmed buffers — cond mode (the
-    # default) is unaffected
+    # run_capacity_tiered)
     cv, ct, n_kept = run_capacity_tiered(
-        (v, t_), n_total, cap, _collect_core, 2, 0, values,
-        # last output is a COUNT checked against ``cap`` by the caller:
-        # in small tier_mode a truncated input must read as overflowing
-        trunc_fold=lambda n, trunc: jnp.where(trunc > 0, cap + 1, n),
+        (v, t_), n_total, cap, _collect_core, 2, 0, values
     )
     overflow = jnp.maximum(overflow, (n_kept > cap).astype(jnp.int32))
     return cv, ct, overflow > 0
@@ -433,11 +413,6 @@ def value_join(
     nt = table_vals.shape[0]
     small_q = max(16384, nq // 16)
     small_t = max(16384, nt // 16)
-    # tier_mode "small" keeps the cond here: value_join returns no
-    # overflow channel, so a truncated table would lose mappings silently
-    # — the cond's big branch is the only safe fallback
-    if tier_mode() == "big":
-        return _value_join_core(query_vals, table_vals, table_finals)
     if small_q < nq and small_t < nt:
         n_q = (query_vals < BIG).sum()
         n_t = (table_vals < BIG).sum()
@@ -531,8 +506,7 @@ def chase_exits(values: jnp.ndarray, codes: jnp.ndarray, max_hops: int = 256):
     # one buffer, not a 3-axis concat)
     cap = codes.shape[0]
     small_n = max(16384, cap // 16)
-    mode = tier_mode()
-    if small_n >= cap or mode == "big":
+    if small_n >= cap:
         return _core(codes)
 
     def _small(c):
@@ -545,11 +519,6 @@ def chase_exits(values: jnp.ndarray, codes: jnp.ndarray, max_hops: int = 256):
         return out, moved
 
     n_active = (codes <= -2).sum()
-    if mode == "small":
-        fin, moved = _small(codes)
-        # truncated chains were never chased: report through the
-        # documented unconverged channel (callers fold into overflow)
-        return fin, moved | (n_active > small_n)
     return lax.cond(n_active <= small_n, _small, _core, codes)
 
 
@@ -1093,15 +1062,15 @@ def seeded_watershed_tiled(
     reports capacity truncation and ``adj_cap`` is the knob to raise.
 
     ``fill_mode``: ``dense``/``capacity``/``None`` (= ``CT_FILL_MODE``,
-    default substrate-aware ``auto`` — see :func:`_resolve_fill_mode`).
-    Mode env vars are resolved HERE, outside jit, so flipping one
-    mid-process retraces instead of reusing a stale cache entry.
+    default substrate-aware ``auto`` — see :func:`_resolve_fill_mode`),
+    resolved HERE, outside jit, so flipping the variable mid-process
+    retraces instead of reusing a stale cache entry.
     """
     return _seeded_watershed_tiled_jit(
         height, seeds, mask, impl=impl, tile=tile, exit_cap=exit_cap,
         fill_cap=fill_cap, table_cap=table_cap, interpret=interpret,
         adj_cap=adj_cap, fill_rounds=fill_rounds,
-        fill_mode=_resolve_fill_mode(fill_mode), _tier=tier_mode(),
+        fill_mode=_resolve_fill_mode(fill_mode),
     )
 
 
@@ -1109,7 +1078,7 @@ def seeded_watershed_tiled(
     jax.jit,
     static_argnames=(
         "impl", "tile", "exit_cap", "fill_cap", "table_cap", "interpret",
-        "adj_cap", "fill_rounds", "fill_mode", "_tier",
+        "adj_cap", "fill_rounds", "fill_mode",
     ),
 )
 def _seeded_watershed_tiled_jit(
@@ -1125,10 +1094,7 @@ def _seeded_watershed_tiled_jit(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: str = "capacity",
-    _tier: str = "cond",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    # _tier is keying-only (the tiered sites read tier_mode() at trace time;
-    # the static arg pins the cache entry to the resolved value).
     # The body is flow-phase + fill-phase cores so the split execution mode
     # (parallel/split_pipeline.py) can jit each phase as its OWN program —
     # composing them here compiles the identical fused program.
@@ -1144,28 +1110,20 @@ def _seeded_watershed_tiled_jit(
     return out, flow_overflow | fill_overflow
 
 
-def _resolve_impl(impl: str) -> str:
-    return ("pallas" if jax.default_backend() == "tpu" else "xla") \
-        if impl == "auto" else impl
-
-
 def resolved_modes(impl: str = "auto") -> dict:
     """What the watershed tasks will actually compile in this process for
     their ``impl`` config — kernel family, flow formulation, fill
-    machinery, seed CCL and capacity tier — for their logs.  A run's log
-    must say which program ran: ``auto`` means Mosaic kernels on a TPU and
-    the portable XLA twins elsewhere, and the two share no compiled code.
-    ``tiled`` is the mesh step's name for the XLA twins; ``legacy`` /
-    ``host`` are not tiled kernels and have none of these modes."""
+    machinery — for their logs.  A run's log must say which program ran:
+    ``auto`` means Mosaic kernels on a TPU and the portable XLA twins
+    elsewhere, and the two share no compiled code.  ``legacy`` / ``host``
+    are not tiled kernels and have none of these modes."""
     if impl in ("legacy", "host"):
         return {"impl": impl}
-    kernels = _resolve_impl("xla" if impl == "tiled" else impl)
+    kernels = resolve_impl(impl)
     return {
         "impl": kernels,
         "flow": "mosaic_in_tile" if kernels == "pallas" else _xla_flow_variant(),
         "fill_mode": _resolve_fill_mode(None),
-        "seed_mode": _resolve_seed_mode(None),
-        "tier": tier_mode(),
     }
 
 
@@ -1225,7 +1183,7 @@ def _ws_flow_core(
     padded float32 heights the fill phase needs."""
     if height.ndim != 3:
         raise ValueError("seeded_watershed_tiled expects a 3-D volume")
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     z, y, x = height.shape
     tile, (zp, yp, xp), exit_cap, _ = _ws_static_plan(
         height.shape, tile, exit_cap, 0
@@ -1319,7 +1277,7 @@ def _ws_fill_core(
     """Fill phase: unseeded-basin fill across lowest saddles (fill_mode
     selects the machinery — see :func:`_resolve_fill_mode`), remap, squash
     leftovers to 0, crop the tile padding back to ``orig_shape``."""
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     z, y, x = orig_shape
     tile, (zp, yp, xp), exit_cap, fill_cap = _ws_static_plan(
         orig_shape, tile, exit_cap, fill_cap
@@ -1410,8 +1368,6 @@ def _dt_seeds_core(
     edge_cap: Optional[int],
     table_cap: int,
     interpret: bool,
-    seed_cap: Optional[int],
-    seed_mode: str,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Seed phase of the DT watershed: threshold -> (capped) EDT -> optional
     smoothing -> maxima plateaus -> seed CCL.  Returns ``(seeds, valid,
@@ -1422,6 +1378,7 @@ def _dt_seeds_core(
     from .filters import gaussian_smooth
     from .watershed import local_maxima
 
+    impl = resolve_impl(impl)
     valid = jnp.ones(boundaries.shape, bool) if mask is None else mask.astype(bool)
     fg = (boundaries < threshold) & valid
     if dist is None:
@@ -1444,43 +1401,13 @@ def _dt_seeds_core(
         & fg
         & (dist >= min_seed_distance * min_seed_distance)
     )
-    raw, seed_overflow = _seed_ccl(
-        maxima, seed_cap, mode=seed_mode, impl=impl, tile=tile,
-        pair_cap=pair_cap, edge_cap=edge_cap, table_cap=table_cap,
-        interpret=interpret,
+    raw, seed_overflow = label_components_tiled(
+        maxima, impl=impl, tile=tile, pair_cap=pair_cap, edge_cap=edge_cap,
+        table_cap=table_cap, interpret=interpret,
     )
     n = int(np.prod(boundaries.shape))
     seeds = jnp.where(raw == n, 0, raw + 1).astype(jnp.int32)
     return seeds, valid, seed_overflow
-
-
-def _seed_ccl(maxima, seed_cap, *, mode, impl, tile, pair_cap, edge_cap,
-              table_cap, interpret):
-    """Label seed plateaus: ``mode`` picks the program.
-
-    - ``tiled`` (the API default): the full two-level CCL machinery —
-      exact for any maxima density.
-    - ``sparse``: :func:`~.tile_ccl.label_components_sparse` — ~1/10 the
-      compiled program (the single biggest compile-size lever in the
-      fused step, see docs/PERFORMANCE.md "program-size analysis");
-      exact while maxima fit ``seed_cap`` (default volume/16 — bench-like
-      volumes measure ~1.4% at ``min_seed_distance=2``), overflow-flagged
-      beyond.
-
-    ``mode`` is a static argument resolved from ``CT_SEED_CCL`` by the
-    public entry points (:func:`_resolve_seed_mode`), never read from the
-    environment here.
-    """
-    if mode == "sparse":
-        from .tile_ccl import label_components_sparse
-
-        return label_components_sparse(maxima, cap=seed_cap)
-    from .tile_ccl import label_components_tiled
-
-    return label_components_tiled(
-        maxima, impl=impl, tile=tile, pair_cap=pair_cap, edge_cap=edge_cap,
-        table_cap=table_cap, interpret=interpret,
-    )
 
 
 def dt_watershed_tiled(
@@ -1500,11 +1427,9 @@ def dt_watershed_tiled(
     fill_cap: Optional[int] = None,
     table_cap: int = DEFAULT_TABLE_CAP,
     interpret: bool = False,
-    seed_cap: Optional[int] = None,
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: Optional[str] = None,
-    seed_mode: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused distance-transform watershed on the two-level machinery.
 
@@ -1520,9 +1445,9 @@ def dt_watershed_tiled(
     transform from :mod:`cluster_tools_tpu.parallel.distributed_edt`); when
     given, the internal EDT (and ``dt_max_distance``) is skipped.
 
-    ``fill_mode`` / ``seed_mode``: explicit machinery selection; ``None``
-    resolves ``CT_FILL_MODE`` / ``CT_SEED_CCL`` here, OUTSIDE jit, so the
-    env values are part of the compile key (see :func:`_resolve_fill_mode`).
+    ``fill_mode``: explicit machinery selection; ``None`` resolves
+    ``CT_FILL_MODE`` here, OUTSIDE jit, so the env value is part of the
+    compile key (see :func:`_resolve_fill_mode`).
     """
     return _dt_watershed_tiled_jit(
         boundaries, threshold=threshold, sigma_seeds=sigma_seeds,
@@ -1530,9 +1455,8 @@ def dt_watershed_tiled(
         dist=dist, dt_max_distance=dt_max_distance, impl=impl, tile=tile,
         pair_cap=pair_cap, edge_cap=edge_cap, exit_cap=exit_cap,
         fill_cap=fill_cap, table_cap=table_cap, interpret=interpret,
-        seed_cap=seed_cap, adj_cap=adj_cap, fill_rounds=fill_rounds,
+        adj_cap=adj_cap, fill_rounds=fill_rounds,
         fill_mode=_resolve_fill_mode(fill_mode),
-        seed_mode=_resolve_seed_mode(seed_mode), _tier=tier_mode(),
     )
 
 
@@ -1541,8 +1465,8 @@ def dt_watershed_tiled(
     static_argnames=(
         "threshold", "sigma_seeds", "min_seed_distance", "sampling",
         "dt_max_distance", "impl", "tile", "pair_cap", "edge_cap",
-        "exit_cap", "fill_cap", "table_cap", "interpret", "seed_cap",
-        "adj_cap", "fill_rounds", "fill_mode", "seed_mode", "_tier",
+        "exit_cap", "fill_cap", "table_cap", "interpret", "adj_cap",
+        "fill_rounds", "fill_mode",
     ),
 )
 def _dt_watershed_tiled_jit(
@@ -1562,25 +1486,22 @@ def _dt_watershed_tiled_jit(
     fill_cap: Optional[int] = None,
     table_cap: int = DEFAULT_TABLE_CAP,
     interpret: bool = False,
-    seed_cap: Optional[int] = None,
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: str = "capacity",
-    seed_mode: str = "tiled",
-    _tier: str = "cond",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     seeds, valid, seed_overflow = _dt_seeds_core(
         boundaries, mask, dist, threshold=threshold, sigma_seeds=sigma_seeds,
         min_seed_distance=min_seed_distance, sampling=sampling,
         dt_max_distance=dt_max_distance, impl=impl, tile=tile,
         pair_cap=pair_cap, edge_cap=edge_cap, table_cap=table_cap,
-        interpret=interpret, seed_cap=seed_cap, seed_mode=seed_mode,
+        interpret=interpret,
     )
     labels, ws_overflow = _seeded_watershed_tiled_jit(
         boundaries, seeds, mask=valid, impl=impl, tile=tile,
         exit_cap=exit_cap, fill_cap=fill_cap, table_cap=table_cap,
         interpret=interpret, adj_cap=adj_cap, fill_rounds=fill_rounds,
-        fill_mode=fill_mode, _tier=_tier,
+        fill_mode=fill_mode,
     )
     return labels, seed_overflow | ws_overflow
 
@@ -1602,11 +1523,9 @@ def dt_watershed_seeded_tiled(
     fill_cap: Optional[int] = None,
     table_cap: int = DEFAULT_TABLE_CAP,
     interpret: bool = False,
-    seed_cap: Optional[int] = None,
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: Optional[str] = None,
-    seed_mode: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Two-pass-mode DT watershed on the tiled machinery.
 
@@ -1618,8 +1537,8 @@ def dt_watershed_seeded_tiled(
     (+N offset, N = voxel count); 1..N are new internal fragments.  Returns
     ``(labels, overflow)``.
 
-    ``fill_mode`` / ``seed_mode`` as in :func:`dt_watershed_tiled` —
-    resolved pre-jit so the env values join the compile key.
+    ``fill_mode`` as in :func:`dt_watershed_tiled` — resolved pre-jit so
+    the env value joins the compile key.
     """
     return _dt_watershed_seeded_tiled_jit(
         boundaries, ext_seeds, threshold=threshold, sigma_seeds=sigma_seeds,
@@ -1627,9 +1546,8 @@ def dt_watershed_seeded_tiled(
         dt_max_distance=dt_max_distance, impl=impl, tile=tile,
         pair_cap=pair_cap, edge_cap=edge_cap, exit_cap=exit_cap,
         fill_cap=fill_cap, table_cap=table_cap, interpret=interpret,
-        seed_cap=seed_cap, adj_cap=adj_cap, fill_rounds=fill_rounds,
+        adj_cap=adj_cap, fill_rounds=fill_rounds,
         fill_mode=_resolve_fill_mode(fill_mode),
-        seed_mode=_resolve_seed_mode(seed_mode), _tier=tier_mode(),
     )
 
 
@@ -1638,8 +1556,8 @@ def dt_watershed_seeded_tiled(
     static_argnames=(
         "threshold", "sigma_seeds", "min_seed_distance", "sampling",
         "dt_max_distance", "impl", "tile", "pair_cap", "edge_cap",
-        "exit_cap", "fill_cap", "table_cap", "interpret", "seed_cap",
-        "adj_cap", "fill_rounds", "fill_mode", "seed_mode", "_tier",
+        "exit_cap", "fill_cap", "table_cap", "interpret", "adj_cap",
+        "fill_rounds", "fill_mode",
     ),
 )
 def _dt_watershed_seeded_tiled_jit(
@@ -1659,12 +1577,9 @@ def _dt_watershed_seeded_tiled_jit(
     fill_cap: Optional[int] = None,
     table_cap: int = DEFAULT_TABLE_CAP,
     interpret: bool = False,
-    seed_cap: Optional[int] = None,
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: str = "capacity",
-    seed_mode: str = "tiled",
-    _tier: str = "cond",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     n = int(np.prod(boundaries.shape))
     internal, valid, seed_overflow = _dt_seeds_core(
@@ -1672,7 +1587,7 @@ def _dt_watershed_seeded_tiled_jit(
         min_seed_distance=min_seed_distance, sampling=sampling,
         dt_max_distance=dt_max_distance, impl=impl, tile=tile,
         pair_cap=pair_cap, edge_cap=edge_cap, table_cap=table_cap,
-        interpret=interpret, seed_cap=seed_cap, seed_mode=seed_mode,
+        interpret=interpret,
     )
     ext = ext_seeds.astype(jnp.int32)
     # external seeds dominate; internal ids live in 1..N, external in N+1..
@@ -1681,6 +1596,6 @@ def _dt_watershed_seeded_tiled_jit(
         boundaries, seeds, mask=valid, impl=impl, tile=tile,
         exit_cap=exit_cap, fill_cap=fill_cap, table_cap=table_cap,
         interpret=interpret, adj_cap=adj_cap, fill_rounds=fill_rounds,
-        fill_mode=fill_mode, _tier=_tier,
+        fill_mode=fill_mode,
     )
     return labels, seed_overflow | ws_overflow

@@ -216,9 +216,8 @@ def sharded_label_components(
     if use_tiled:
         from ..ops.tile_ccl import label_components_tiled
 
-        tiled_impl = "xla" if impl == "tiled" else impl
         raw, tiled_overflow = label_components_tiled(
-            mask, connectivity=connectivity, impl=tiled_impl
+            mask, connectivity=connectivity, impl=impl
         )
     else:
         with jax.named_scope("ccl.tile"):

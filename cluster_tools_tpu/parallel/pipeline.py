@@ -205,7 +205,6 @@ def _ws_ccl_shard(
         if tiled_ok:
             from ..ops.tile_ws import dt_watershed_tiled
 
-            tiled_impl = "xla" if impl == "tiled" else impl
             dist_pad = None
             if exact_edt:
                 # globally exact squared EDT (all-to-all reshard per axis
@@ -232,7 +231,7 @@ def _ws_ccl_shard(
                 dist=dist_pad,
                 dt_max_distance=dt_max_distance,
                 min_seed_distance=min_seed_distance,
-                impl=tiled_impl,
+                impl=impl,
             )
             ws_overflow = jnp.maximum(ws_overflow, ws_over.astype(jnp.int32))
         else:

@@ -54,7 +54,6 @@ from ..ops.tile_ccl import DEFAULT_TABLE_CAP
 from ..ops.tile_ws import (
     _dt_seeds_core,
     _resolve_fill_mode,
-    _resolve_seed_mode,
     _ws_flow_core,
     _ws_fill_core,
 )
@@ -108,7 +107,6 @@ def make_ws_ccl_split(
     exact_edt: bool = False,
     stitch_ws_threshold: Optional[float] = None,
     fill_mode: Optional[str] = None,
-    seed_mode: Optional[str] = None,
 ) -> SplitWsCclStep:
     """Build the split-mode twin of ``make_ws_ccl_step`` for ``mesh``.
 
@@ -118,9 +116,9 @@ def make_ws_ccl_split(
     backends — "legacy" has no phase seams to cut (its fused program is
     small enough to compile everywhere).  3-D volumes, connectivity 1.
 
-    ``fill_mode``/``seed_mode``: as in ``dt_watershed_tiled`` — ``None``
-    resolves ``CT_FILL_MODE``/``CT_SEED_CCL`` here, at build time, so the
-    env values are fixed into the stage programs.
+    ``fill_mode``: as in ``dt_watershed_tiled`` — ``None`` resolves
+    ``CT_FILL_MODE`` here, at build time, so the env value is fixed into
+    the stage programs.
     """
     if impl == "legacy":
         raise ValueError("split mode covers the tiled kernels only")
@@ -130,12 +128,6 @@ def make_ws_ccl_split(
     sp_axes = sp_axes_for_mesh(mesh, sp_axis)
     n_shards = int(np.prod([s for _, _, s in sp_axes]))
     fill_mode = _resolve_fill_mode(fill_mode)
-    seed_mode = _resolve_seed_mode(seed_mode)
-    # tier_mode() is read at trace time inside the tiered sites; each call
-    # to this builder returns FRESH jitted closures (fresh caches), so the
-    # env value at first use is the one compiled — same contract as the
-    # fused builder.
-    tiled_impl = "xla" if impl == "tiled" else impl
     spec = P(dp_axis, *names)
     rep = P()
 
@@ -180,9 +172,8 @@ def make_ws_ccl_split(
                 padded, None, dist_pad, threshold=threshold,
                 sigma_seeds=0.0, min_seed_distance=min_seed_distance,
                 sampling=None, dt_max_distance=dt_max_distance,
-                impl=tiled_impl, tile=None, pair_cap=None, edge_cap=None,
+                impl=impl, tile=None, pair_cap=None, edge_cap=None,
                 table_cap=DEFAULT_TABLE_CAP, interpret=False,
-                seed_cap=None, seed_mode=seed_mode,
             )
             ovf = jnp.maximum(ovf, s_ovf.astype(jnp.int32))
             pad_out.append(padded)
@@ -196,7 +187,7 @@ def make_ws_ccl_split(
         ovf = ovf_in
         for b in range(local_b):
             values, h, o = _ws_flow_core(
-                padded[b], seeds[b], None, impl=tiled_impl, tile=None,
+                padded[b], seeds[b], None, impl=impl, tile=None,
                 exit_cap=None, table_cap=DEFAULT_TABLE_CAP, interpret=False,
             )
             ovf = jnp.maximum(ovf, o.astype(jnp.int32))
@@ -220,7 +211,7 @@ def make_ws_ccl_split(
         ovf = ovf_in
         for b in range(local_b):
             ws, o = _ws_fill_core(
-                values[b], h[b], pad_shape, impl=tiled_impl, tile=None,
+                values[b], h[b], pad_shape, impl=impl, tile=None,
                 exit_cap=None, fill_cap=None, table_cap=DEFAULT_TABLE_CAP,
                 interpret=False, adj_cap=None, fill_rounds=None,
                 fill_mode=fill_mode,

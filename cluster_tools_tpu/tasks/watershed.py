@@ -54,7 +54,7 @@ def _tiled_cap_knobs(cfg):
     return {
         k: int(cfg[k])
         for k in ("exit_cap", "fill_cap", "adj_cap", "fill_rounds",
-                  "seed_cap", "table_cap", "pair_cap", "edge_cap")
+                  "table_cap", "pair_cap", "edge_cap")
         if cfg.get(k) is not None
     }
 
@@ -102,14 +102,12 @@ class _WsTaskBase(BaseTask):
             # tiled-kernel capacity knobs (None = the ops-level defaults;
             # ignored by the legacy kernel).  Raise on overflow reports:
             # exit/fill/adj govern the cross-tile exit and saddle-fill
-            # buffers, seed_cap the sparse seed labeler (CT_SEED_CCL),
-            # fill_rounds the Boruvka round count, table_cap the VMEM
-            # remap tables, pair/edge_cap the seed CCL's face merge.
+            # buffers, fill_rounds the Boruvka round count, table_cap the
+            # VMEM remap tables, pair/edge_cap the seed CCL's face merge.
             "exit_cap": None,
             "fill_cap": None,
             "adj_cap": None,
             "fill_rounds": None,
-            "seed_cap": None,
             "table_cap": None,
             "pair_cap": None,
             "edge_cap": None,

@@ -1,8 +1,8 @@
 """512³ headline-geometry rehearsal on the host substrate.
 
-Runs the full TPU-shaped program — capacity fill, sparse seeds, the
-four-program split chain, halo 32 — at the REAL bench geometry (512³)
-on XLA:CPU, and FAILS on any overflow flag.  This is the run that
+Runs the full TPU-shaped program — capacity fill, the four-program
+split chain, halo 32 — at the REAL bench geometry (512³) on XLA:CPU,
+and FAILS on any overflow flag.  This is the run that
 caught two headline-scale cap bugs in round 5 (fill_rounds' 2^16 bound
 vs 80,902 measured basins; adj_cap n/128 vs the measured n/85 unique
 adjacency load — docs/PERFORMANCE.md "512³ host-substrate rehearsal"),
@@ -26,7 +26,6 @@ import time
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
-os.environ["CT_SEED_CCL"] = "sparse"
 os.environ["CT_FILL_MODE"] = "capacity"  # the TPU-shaped machinery
 
 import jax

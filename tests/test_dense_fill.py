@@ -467,7 +467,7 @@ def _plateau_case():
     seeds, valid, _ = tile_ws._dt_seeds_core(
         b, None, None, threshold=0.5, sigma_seeds=0.0, min_seed_distance=0.0,
         sampling=None, dt_max_distance=None, pair_cap=None, edge_cap=None,
-        seed_cap=None, seed_mode="tiled", **kernels,
+        **kernels,
     )
     vals, height, _ = tile_ws._ws_flow_core(
         b, seeds, valid, exit_cap=None, **kernels

@@ -12,10 +12,17 @@ import numpy as np
 import pytest
 import scipy.ndimage as ndi
 
+import jax
 import jax.numpy as jnp
 
 from cluster_tools_tpu.ops.ccl import finalize_labels
-from cluster_tools_tpu.ops.tile_ccl import label_components_tiled
+from cluster_tools_tpu.ops.tile_ccl import (
+    BIG,
+    _remap_tables_core,
+    build_remap_tables,
+    label_components_tiled,
+    resolve_impl,
+)
 from .helpers import assert_labels_equivalent, random_blobs
 
 
@@ -29,6 +36,19 @@ def _check(mask, **kw):
     assert_labels_equivalent(np.asarray(finalize_labels(jnp.asarray(lab))), ref)
 
 
+def _border_plateaus(shape):
+    """Seed-like plateaus touching every border of the volume."""
+    z, y, x = shape
+    mask = np.zeros(shape, bool)
+    mask[0, 0, :7] = True            # ridge along x at the corner
+    mask[5:8, 5:8, 5:8] = True       # cube plateau
+    mask[z - 1, :, x - 1] = True     # edge line on the far border
+    mask[:, y - 1, 0] = True         # and one down the z axis
+    mask[12, 12, 20] = True          # singleton
+    mask[12, 12, 22] = True          # near-but-separate singleton
+    return mask
+
+
 @pytest.mark.parametrize(
     "shape,p",
     [
@@ -36,10 +56,15 @@ def _check(mask, **kw):
         ((48, 48, 256), 0.3),
         ((16, 16, 128), 0.7),
         ((64, 64, 128), 0.08),
+        # what the watershed's seed CCL labels: 1-2 % scattered maxima, and
+        # plateaus on the borders (p None), both at shapes that pad
+        ((32, 48, 40), 0.02),
+        ((24, 24, 40), None),
     ],
 )
 def test_tiled_vs_scipy(rng, shape, p):
-    _check(rng.random(shape) < p, impl="xla")
+    mask = _border_plateaus(shape) if p is None else rng.random(shape) < p
+    _check(mask, impl="xla")
 
 
 def test_tiled_nondivisible_shapes(rng):
@@ -52,15 +77,102 @@ def test_tiled_blobs(rng):
     _check(random_blobs(rng, (40, 48, 140), p=0.45), impl="xla")
 
 
-def test_tiled_empty_full():
-    empty = np.zeros((16, 16, 128), bool)
+@pytest.mark.parametrize("shape", [(16, 16, 128), (32, 16, 128), (8, 8, 16)])
+def test_tiled_empty_full(shape):
+    empty = np.zeros(shape, bool)
     lab, ovf = label_components_tiled(jnp.asarray(empty), impl="xla")
     assert not bool(ovf) and (np.asarray(lab) == empty.size).all()
-    full = np.ones((32, 16, 128), bool)
+    full = np.ones(shape, bool)
     lab, ovf = label_components_tiled(jnp.asarray(full), impl="xla")
     assert not bool(ovf)
     lab = np.asarray(lab)
     assert len(np.unique(lab)) == 1  # one component
+    assert lab[0, 0, 0] < full.size  # a voxel of the volume, not padding
+
+
+# The capacity tiers (run_capacity_tiered and its inline twins) choose at run
+# time between one machine at two sizes, so a caller can never see which ran.
+# Each site is driven with capacities large enough that it really tiers, once
+# with a live count that fits the small tier and once with one that does not.
+
+
+def _n_face_positions(mask, tile):
+    """Face positions with foreground on both sides of a tile boundary: an
+    upper bound of merge_face_pairs' ``n_total`` (run-dedup only removes)."""
+    total = 0
+    for axis, t in enumerate(tile):
+        a = np.take(mask, range(t - 1, mask.shape[axis] - 1, t), axis=axis)
+        b = np.take(mask, range(t, mask.shape[axis], t), axis=axis)
+        total += int((a & b).sum())
+    return total
+
+
+@pytest.mark.parametrize("p,fits", [(0.3, True), (0.7, False)])
+def test_merge_face_pairs_tier_is_invisible(rng, p, fits):
+    shape, tile, cap = (64, 64, 256), (8, 8, 128), 65536
+    small_n = max(3 * 16384, 3 * cap // 16)
+    assert small_n < cap  # the merge tiers at these capacities
+    mask = rng.random(shape) < p
+    # foreground on even x only: no two face positions are neighbours along
+    # the run-dedup axis, so the bound above IS n_total
+    mask[:, :, 1::2] = False
+    assert (_n_face_positions(mask, tile) <= small_n) == fits
+    _check(mask, impl="xla", tile=tile, pair_cap=cap, edge_cap=cap)
+
+
+@pytest.mark.parametrize("n_live,fits", [(1000, True), (20000, False)])
+def test_build_remap_tables_tier_matches_core(n_live, fits):
+    n_in, n_tiles, table_cap = 32768, 512, 128
+    small_n = max(16384, n_in // 16)
+    assert small_n < n_in and (n_live <= small_n) == fits
+    r = np.random.default_rng(3)
+    tids = np.full(n_in, BIG, np.int32)
+    old = np.full(n_in, BIG, np.int32)
+    new = np.full(n_in, BIG, np.int32)
+    slots = r.choice(n_in, size=n_live, replace=False)  # live entries scattered
+    tids[slots] = r.integers(0, n_tiles, size=n_live)
+    old[slots] = r.integers(0, 1 << 20, size=n_live)
+    new[slots] = r.integers(0, 1 << 20, size=n_live)
+    args = (jnp.asarray(tids), jnp.asarray(old), jnp.asarray(new))
+    got = build_remap_tables(*args, n_tiles, table_cap=table_cap)
+    want = _remap_tables_core(*args, n_tiles, table_cap)
+    assert not bool(want[2])
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # and the tables hold what went in: one slot for each (tile, old) pair
+    pairs = np.unique(np.stack([tids[slots], old[slots]]), axis=1)
+    assert int((np.asarray(got[0]) >= 0).sum()) == pairs.shape[1]
+
+
+@pytest.mark.parametrize(
+    "impl,backend,want",
+    [
+        ("tiled", "cpu", "xla"),
+        ("tiled", "tpu", "xla"),
+        ("xla", "tpu", "xla"),
+        ("pallas", "cpu", "pallas"),
+        ("auto", "tpu", "pallas"),
+        ("auto", "cpu", "xla"),
+        ("auto", "gpu", "xla"),
+    ],
+)
+def test_resolve_impl(monkeypatch, impl, backend, want):
+    """One resolver decides which kernels the tiled CCL and watershed
+    compile: ``tiled`` is ``xla``, ``auto`` goes by the platform."""
+    from cluster_tools_tpu.ops.tile_ws import resolved_modes
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_impl(impl) == want
+    modes = resolved_modes(impl)
+    assert modes["impl"] == want
+    assert set(modes) == {"impl", "flow", "fill_mode"}
+
+
+def test_tiled_alias_runs_the_xla_kernels(rng):
+    mask = jnp.asarray(rng.random((20, 24, 130)) < 0.4)
+    a, _ = label_components_tiled(mask, impl="tiled")
+    b, _ = label_components_tiled(mask, impl="xla")
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_tiled_overflow_flag(rng):

@@ -143,7 +143,15 @@ def test_overflow_flag(rng, monkeypatch):
     jax.clear_caches()
 
 
-def test_chase_exits_small_tier_matches_oracle(rng):
+# The capacity tiers choose at run time between one machine at two sizes, so
+# a caller can never see which ran (tests/test_tile_ccl.py has the merge's and
+# the remap tables').  Each site below is driven with buffers large enough
+# that it really tiers, once with a live count that fits the small tier and
+# once with one that does not.
+
+
+@pytest.mark.parametrize("n_active", [512, 20000])
+def test_chase_exits_small_tier_matches_oracle(rng, n_active):
     """The chase's small tier (compact -> chase -> scatter-back) only
     engages for capacity buffers > 16*16384, which no workflow test
     reaches — drive it directly against a numpy chain-following oracle."""
@@ -158,7 +166,7 @@ def test_chase_exits_small_tier_matches_oracle(rng):
     for g in range(3584, n):
         values[g] = 0 if g % 3 == 0 else (g % 97) + 1
     cap = 16 * 16384 + 1024  # force small_n < cap -> tiered path
-    n_active = 512  # << small_n -> the small tier is taken
+    # small_n = cap // 16 = 16448: 512 codes take the small tier, 20000 the big
     rng_ = np.random.default_rng(0)
     codes = np.full(cap, BIG, np.int32)
     codes[:n_active] = -(rng_.integers(0, n, size=n_active) + 2)
@@ -183,10 +191,12 @@ def test_chase_exits_small_tier_matches_oracle(rng):
     np.testing.assert_array_equal(finals[n_active:], codes[n_active:])
 
 
-def test_value_join_small_tier_matches_core(rng):
+@pytest.mark.parametrize("n_t,n_q", [(300, 500), (300, 20000), (20000, 500)])
+def test_value_join_small_tier_matches_core(rng, n_t, n_q):
     """value_join's tiered path (compact both sides -> join -> scatter
     back) only engages above 16*16384 capacities — drive it directly
-    against the untiered core."""
+    against the untiered core.  The small tier (16448 slots a side) is
+    taken when both live counts fit, the big one when either does not."""
     from cluster_tools_tpu.ops.tile_ws import (
         BIG, _value_join_core, value_join,
     )
@@ -195,13 +205,12 @@ def test_value_join_small_tier_matches_core(rng):
     rng_ = np.random.default_rng(1)
     table = np.full(cap, BIG, np.int32)
     finals = np.full(cap, BIG, np.int32)
-    n_t = 300
-    tv = -(rng_.choice(5000, size=n_t, replace=False).astype(np.int32) + 2)
+    tv = -(rng_.choice(50000, size=n_t, replace=False).astype(np.int32) + 2)
     table[:n_t] = np.sort(tv)
     finals[:n_t] = rng_.integers(1, 100, size=n_t)
     queries = np.full(cap, BIG, np.int32)
-    n_q = 500  # half hit the table, half miss
-    queries[:n_q] = -(rng_.integers(0, 10000, size=n_q).astype(np.int32) + 2)
+    # some hit the table, the rest miss
+    queries[:n_q] = -(rng_.integers(0, 100000, size=n_q).astype(np.int32) + 2)
 
     import jax.numpy as jnp
 
@@ -214,6 +223,41 @@ def test_value_join_small_tier_matches_core(rng):
     lut = {int(v): int(f) for v, f in zip(table[:n_t], finals[:n_t])}
     for i in range(n_q):
         assert got[i] == lut.get(int(queries[i]), int(queries[i])), i
+
+
+@pytest.mark.parametrize("p,fits", [(0.1, True), (0.42, False)])
+def test_collect_negative_values_tier_matches_oracle(rng, p, fits):
+    """The (value, tile) dedup behind the exits and the fill remaps, with a
+    capacity at which it tiers, against the set numpy finds on the strips."""
+    from cluster_tools_tpu.ops.tile_ws import BIG, collect_negative_values
+
+    shape, tile, cap = (32, 64, 256), (16, 16, 128), 65536
+    n = int(np.prod(shape))
+    # every code distinct, so no run collapses and the dedup only drops the
+    # strips' shared edges: n_total is the strips' negative voxels, counted
+    # once per strip family they lie on
+    values = np.where(
+        rng.random(shape) < p, -np.arange(n).reshape(shape) - 2, 7
+    ).astype(np.int32)
+    idx = np.indices(shape)
+    # the six strip families: first and last plane of a tile along each axis
+    strips = [idx[a] % tile[a] == e for a in range(3) for e in (0, tile[a] - 1)]
+    neg = values <= -2
+    n_total = sum(int((neg & m).sum()) for m in strips)
+    # the 1/16 tier of the six families' concatenated buffers
+    small_n = max(3 * 16384, (4 * 32768 + 2 * 4096) // 16)
+    assert small_n < cap and (n_total <= small_n) == fits
+
+    cv, ct, overflow = collect_negative_values(jnp.asarray(values), tile, cap)
+    cv, ct = np.asarray(cv), np.asarray(ct)
+    assert not bool(overflow)
+    tid = (idx[0] // 16 * (shape[1] // 16) + idx[1] // 16) * (shape[2] // 128) \
+        + idx[2] // 128
+    sel = neg & np.logical_or.reduce(strips)
+    want = sorted(zip(values[sel].tolist(), tid[sel].tolist()))
+    live = cv < BIG
+    assert sorted(zip(cv[live].tolist(), ct[live].tolist())) == want
+    assert live[: len(want)].all() and not live[len(want):].any()
 
 
 def test_sparse_seed_noise_fill_knobs(rng, monkeypatch):

@@ -339,3 +339,23 @@ def test_capacity_knobs_reach_the_tiled_kernel(rng, workspace):
     )
     assert labels.shape == vol.shape
     assert "overflowed" in all_logs()
+
+
+def test_cap_knobs_pick_keys_by_name_and_ignore_a_stale_seed_cap():
+    """A config file written before PR 33 may still carry ``seed_cap`` (the
+    sparse seed labeler's knob, deleted with it): the key is ignored, the
+    others arrive, and what the kernel entry points accept is what the task
+    forwards."""
+    import inspect
+
+    from cluster_tools_tpu.ops.tile_ws import dt_watershed_tiled
+    from cluster_tools_tpu.tasks.watershed import WatershedBase, _tiled_cap_knobs
+
+    cfg = dict(WatershedBase.default_task_config(), seed_cap=4096,
+               fill_cap=1 << 20, table_cap=32)
+    knobs = _tiled_cap_knobs(cfg)
+    assert knobs == {"fill_cap": 1 << 20, "table_cap": 32}
+    accepted = set(inspect.signature(dt_watershed_tiled).parameters)
+    assert "seed_cap" not in accepted
+    every = _tiled_cap_knobs(dict.fromkeys(cfg, 1))
+    assert set(every) <= accepted and len(every) == 7
