@@ -265,6 +265,7 @@ class BaseTask:
         from ..ops import contraction as contraction_mod
         from ..parallel import device_pool as device_pool_mod
         from ..parallel import reduce_tree as reduce_tree_mod
+        from ..parallel import step_cache as step_cache_mod
 
         self.logger.info(f"start {self.task_name} (target={self.target})")
         # unified tracing plane (docs/OBSERVABILITY.md): every task of a run
@@ -295,6 +296,7 @@ class BaseTask:
                 solver_snap = contraction_mod.solver_snapshot()
                 tree_snap = reduce_tree_mod.solve_snapshot()
                 compile_snap = trace_mod.compile_snapshot()
+                step_snap = step_cache_mod.totals()
                 try:
                     result = self.run_impl() or {}
                     # finalize in-memory targets INSIDE the task context:
@@ -354,6 +356,11 @@ class BaseTask:
             compile_metrics = trace_mod.compile_delta(compile_snap)
             if any(compile_metrics.values()):
                 io_metrics["compile"] = compile_metrics
+            # which level gave a mesh step to this task: process, store or a
+            # build (docs/OBSERVABILITY.md "The step cache")
+            step_metrics = step_cache_mod.delta(step_snap)
+            if step_metrics:
+                io_metrics["step_cache"] = step_metrics
             if any(io_metrics.values()):
                 result["io_metrics"] = io_metrics
                 try:

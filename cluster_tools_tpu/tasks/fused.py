@@ -98,6 +98,7 @@ class FusedSegmentationBase(BaseTask):
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ..ops.tile_ws import resolved_modes
+        from ..parallel import step_cache
         from ..parallel.mesh import describe_devices, device_peak_bytes
         from ..runtime import handoff
         from ..runtime import trace as trace_mod
@@ -115,7 +116,7 @@ class FusedSegmentationBase(BaseTask):
             roi_end = tuple(cfg.get("roi_end") or shape)
             roi = tuple(slice(b, e) for b, e in zip(roi_begin, roi_end))
             roi_shape = tuple(e - b for b, e in zip(roi_begin, roi_end))
-            step, mesh, sp_desc, execution, impl = self._build_step(
+            builder, build_args, mesh, sp_desc, execution = self._build_step(
                 cfg, roi_shape)
             sp.note(execution=execution, mesh=sp_desc)
         halo = int(np.max(cfg.get("halo") or 0))
@@ -123,7 +124,7 @@ class FusedSegmentationBase(BaseTask):
             f"{execution} step on mesh {sp_desc}, roi {roi_shape}, "
             f"halo={halo}; "
             f"mesh.devices={describe_devices(mesh.devices)}; "
-            f"kernels={resolved_modes(impl)}"
+            f"kernels={resolved_modes(build_args['impl'])}"
         )
         with trace_mod.span("fused.read") as sp:
             vol = np.asarray(inp[roi]).astype(np.float32)
@@ -136,12 +137,16 @@ class FusedSegmentationBase(BaseTask):
             x = jax.block_until_ready(jax.device_put(vol[None], in_sharding))
             sp.note(shards=len(x.addressable_shards))
         del vol
-        # the call up to its return: trace, lower, compile or read the
-        # executable back, enqueue
+        # the call up to its return: the step looked up under the key of
+        # what it is built from (process, then store), loaded or built,
+        # then enqueued; only the one-program step goes to the store
         fun_name = "ws_ccl_step" if execution == "fused" else "ws_ccl_split"
         with trace_mod.span("fused.dispatch", fun_name=fun_name):
+            step, step_info = step_cache.step_for(
+                mesh, x, execution, builder, build_args)
             out = step(x)
         del x
+        self.logger.info(f"step_cache={step_info}")
         with trace_mod.span("fused.wait"):
             ws, cc, n_fg, overflow = jax.block_until_ready(out)
         if bool(np.asarray(overflow)):
@@ -182,11 +187,14 @@ class FusedSegmentationBase(BaseTask):
             "collectives": collective_bytes(
                 roi_shape, mesh.devices.shape[1:], halo),
             "device_memory": device_peak_bytes(mesh.devices),
+            "step_cache": dict(step_info, totals=step_cache.totals()),
         }
 
     def _build_step(self, cfg, roi_shape):
-        """The mesh over the task's devices and the step compiled for it:
-        ``(step, mesh, mesh description, execution, impl)``."""
+        """The mesh over the task's devices and what the step for it is
+        built from: ``(builder, build_args, mesh, mesh description,
+        execution)``; ``builder(mesh, **build_args)`` makes the step, and
+        the step's key holds every one of ``build_args``."""
         from ..parallel.mesh import backend_devices, make_mesh
         from ..parallel.pipeline import make_ws_ccl_step
         from ..parallel.split_pipeline import make_ws_ccl_split
@@ -242,21 +250,19 @@ class FusedSegmentationBase(BaseTask):
             raise ValueError(
                 f"execution must be 'fused' or 'split', got {execution!r}"
             )
-        impl = str(cfg.get("impl", "auto"))
-        build_step = make_ws_ccl_step if execution == "fused" else make_ws_ccl_split
-        step = build_step(
-            mesh,
+        builder = make_ws_ccl_step if execution == "fused" else make_ws_ccl_split
+        build_args = dict(
             halo=halo,
             threshold=float(cfg["threshold"]),
             sp_axis=sp_axis,
             dt_max_distance=dt_max,
             min_seed_distance=float(cfg.get("min_seed_distance") or 0.0),
             max_labels_per_shard=cfg.get("max_labels_per_shard"),
-            impl=impl,
+            impl=str(cfg.get("impl", "auto")),
             exact_edt=bool(cfg.get("exact_edt", False)),
             stitch_ws_threshold=cfg.get("stitch_ws_threshold"),
         )
-        return step, mesh, sp_desc, execution, impl
+        return builder, build_args, mesh, sp_desc, execution
 
 
 class FusedSegmentationLocal(FusedSegmentationBase):
@@ -295,6 +301,7 @@ class FusedSegmentationWorkflow(WorkflowBase):
             return {}
         return {
             k: doc[k]
-            for k in ("n_foreground", "written", "mesh", "collectives")
+            for k in ("n_foreground", "written", "mesh", "collectives",
+                      "step_cache")
             if k in doc
         }

@@ -1,6 +1,29 @@
 """Shared test helpers."""
 
+import contextlib
+
 import numpy as np
+
+
+@contextlib.contextmanager
+def fused_step_built_here():
+    """Fused jobs inside build their step from the program as it is in
+    memory now: the process holds no ready step and there is no step store.
+    ``parallel/step_cache.py`` keys the compiled step on source *files*, so
+    a test that patches the program in memory, or wants to see the build,
+    says so; the patched step does not outlive the block either."""
+    import jax
+
+    from cluster_tools_tpu.parallel import step_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    step_cache.forget()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        step_cache.forget()
 
 
 def assert_labels_equivalent(a: np.ndarray, b: np.ndarray):
