@@ -49,8 +49,9 @@ volume kernels' substrate dispatch:
 - ``"numpy"``  the vectorized reference implementation and the parity
                oracle for both of the above.
 
-``impl="auto"`` resolves device-JAX on an accelerator backend, else
-native when the library loads, else numpy; the sequential heap solvers
+``impl="auto"`` resolves device-JAX on an accelerator backend for a graph of
+65,536 edges or more (``_DEVICE_MIN_EDGES``), else native when the library
+loads, else numpy; the sequential heap solvers
 remain available as ``impl="heap"`` (and are the quality oracle in tests).
 """
 
@@ -103,10 +104,19 @@ def _record_solver_metrics(**deltas) -> None:
             _SOLVER_COUNTERS[k] += int(v)
 
 
-def _resolve_impl(impl: str) -> str:
+#: a solve of fewer edges stays on the host whatever the backend.  The device
+#: program is a loop of some tens of rounds, each a handful of scatters and
+#: a sort that cost milliseconds however few slots they hold: 216 block
+#: subproblems of about 1.3 k edges took 176 ms each on a TPU v5e, 38 s of a
+#: job, where the host rungs take under a millisecond (PERF.md section 6,
+#: PR 35).  Where the device first wins has not been measured (ROADMAP R5).
+_DEVICE_MIN_EDGES = 1 << 16
+
+
+def _resolve_impl(impl: str, n_edges: int) -> str:
     if impl != "auto":
         return impl
-    if jax.default_backend() in _ACCEL_PLATFORMS:
+    if jax.default_backend() in _ACCEL_PLATFORMS and n_edges >= _DEVICE_MIN_EDGES:
         return "jax"
     from .. import native
 
@@ -344,9 +354,10 @@ def _device_contract(u, v, pay, threshold, n_nodes, mode, k):
         return new_u, new_v, new_pay, labels, jnp.any(mutual)
 
     labels0 = jnp.arange(n + 1, dtype=jnp.int32)
-    u, v, pay, labels, _ = lax.while_loop(
-        cond, body, (u, v, pay, labels0, jnp.bool_(True))
-    )
+    with jax.named_scope("mc.contract"):
+        u, v, pay, labels, _ = lax.while_loop(
+            cond, body, (u, v, pay, labels0, jnp.bool_(True))
+        )
     return labels[:n]
 
 
@@ -530,7 +541,7 @@ def parallel_contraction(
     payload = np.asarray(payload, dtype=np.float64).reshape(len(edges), -1)
 
     labels = None
-    resolved = _resolve_impl(impl)
+    resolved = _resolve_impl(impl, len(edges))
     if resolved == "jax":
         labels = _contract_rounds_jax(n_nodes, edges, payload, mode, threshold)
     elif resolved == "native":

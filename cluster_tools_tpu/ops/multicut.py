@@ -31,6 +31,7 @@ is penalized.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import heapq
 import os
@@ -102,6 +103,24 @@ class SolverCheckpoint:
                 pass
 
 
+def _solve_span(solver):
+    """The host solvers of this module run inside an ``mc.solve`` span
+    (docs/OBSERVABILITY.md "Multicut"): which solver, on how many nodes and
+    edges.  A solver that calls another (Kernighan-Lin starts from GAEC)
+    nests their spans.  The shared null span with the tracer off."""
+
+    @functools.wraps(solver)
+    def traced(n_nodes, edges, costs, *args, **kwargs):
+        # imported here: runtime/ imports utils/, which imports this module
+        from ..runtime import trace as trace_mod
+
+        with trace_mod.span("mc.solve", solver=solver.__name__,
+                            n_nodes=int(n_nodes), n_edges=len(edges)):
+            return solver(n_nodes, edges, costs, *args, **kwargs)
+
+    return traced
+
+
 def multicut_energy(
     edges: np.ndarray, costs: np.ndarray, node_labels: np.ndarray
 ) -> float:
@@ -117,6 +136,7 @@ def _relabel_consecutive(parent: np.ndarray) -> np.ndarray:
     return labels.astype(np.int64)
 
 
+@_solve_span
 def greedy_additive(
     n_nodes: int, edges: np.ndarray, costs: np.ndarray, stop_cost: float = 0.0
 ) -> np.ndarray:
@@ -332,6 +352,7 @@ def _kl_refine_pair(
     return 0.0
 
 
+@_solve_span
 def kernighan_lin(
     n_nodes: int,
     edges: np.ndarray,
@@ -454,6 +475,7 @@ def _kernighan_lin_python(
     return _relabel_consecutive(labels)
 
 
+@_solve_span
 def fusion_moves(
     n_nodes: int,
     edges: np.ndarray,
@@ -506,6 +528,7 @@ def fusion_moves(
     return _relabel_consecutive(best)
 
 
+@_solve_span
 def decompose_solve(
     n_nodes: int,
     edges: np.ndarray,

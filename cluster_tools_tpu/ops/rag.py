@@ -23,8 +23,9 @@ per-edge counts add up correctly across blocks.
 
 from __future__ import annotations
 
+import threading
 from functools import partial
-from typing import Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,28 @@ import numpy as np
 
 # per-edge accumulated statistics, in column order
 FEATURE_NAMES = ("mean", "min", "max", "count", "variance")
+
+# device programs of this module dispatched by the process, by what they
+# extract (docs/OBSERVABILITY.md "Multicut"): ``BaseTask.run`` puts each
+# task's share in its manifest and in io_metrics.json
+_COUNTERS = {"rag_dispatches": 0, "rag_cap_retries": 0}
+_COUNTERS_LOCK = threading.Lock()
+
+
+def dispatch_snapshot() -> Dict[str, int]:
+    with _COUNTERS_LOCK:
+        return dict(_COUNTERS)
+
+
+def dispatch_delta(snap: Dict[str, int]) -> Dict[str, int]:
+    with _COUNTERS_LOCK:
+        return {k: v - snap.get(k, 0) for k, v in _COUNTERS.items()}
+
+
+def _count(**deltas: int) -> None:
+    with _COUNTERS_LOCK:
+        for k, v in deltas.items():
+            _COUNTERS[k] += int(v)
 
 
 @partial(jax.jit, static_argnames=("axis", "with_values"))
@@ -53,17 +76,18 @@ def axis_edge_scan(
     ndim = seg.ndim
     sl_a = tuple(slice(0, -1) if d == axis else slice(None) for d in range(ndim))
     sl_b = tuple(slice(1, None) if d == axis else slice(None) for d in range(ndim))
-    u = seg[sl_a].ravel()
-    v = seg[sl_b].ravel()
-    valid = (u != v) & (u != 0) & (v != 0)
-    lo = jnp.where(valid, jnp.minimum(u, v), 0)
-    hi = jnp.where(valid, jnp.maximum(u, v), 0)
-    if with_values:
-        va = values[sl_a].ravel()
-        vb = values[sl_b].ravel()
-        val = jnp.where(valid, jnp.maximum(va, vb), 0)
-    else:
-        val = jnp.zeros_like(lo, dtype=jnp.float32)
+    with jax.named_scope("rag.scan"):
+        u = seg[sl_a].ravel()
+        v = seg[sl_b].ravel()
+        valid = (u != v) & (u != 0) & (v != 0)
+        lo = jnp.where(valid, jnp.minimum(u, v), 0)
+        hi = jnp.where(valid, jnp.maximum(u, v), 0)
+        if with_values:
+            va = values[sl_a].ravel()
+            vb = values[sl_b].ravel()
+            val = jnp.where(valid, jnp.maximum(va, vb), 0)
+        else:
+            val = jnp.zeros_like(lo, dtype=jnp.float32)
     return lo, hi, val, valid
 
 
@@ -89,30 +113,42 @@ def device_edge_aggregate(
     with static length ``edge_cap`` (slots past ``n_edges`` hold lo=hi=0);
     ``n_edges > edge_cap`` means overflow (results truncated).
     """
-    from jax import lax
-
     INT_MAX = jnp.int32(np.iinfo(np.int32).max)
     inner = tuple(inner_shape) if inner_shape is not None else seg.shape
     los, his, vals = [], [], []
-    for axis in range(seg.ndim):
-        # the block-ownership halo convention (module docstring): inner+1
-        # along the scan axis, inner along the others
-        bb = tuple(
-            slice(0, min(inner[d] + 1, seg.shape[d]))
-            if d == axis
-            else slice(0, inner[d])
-            for d in range(seg.ndim)
-        )
-        lo, hi, val, valid = axis_edge_scan(
-            seg[bb], None if values is None else values[bb], axis,
-            with_values=with_values,
-        )
-        los.append(jnp.where(valid, lo, INT_MAX))
-        his.append(jnp.where(valid, hi, INT_MAX))
-        vals.append(val)
-    lo = jnp.concatenate(los).astype(jnp.int32)
-    hi = jnp.concatenate(his).astype(jnp.int32)
-    val = jnp.concatenate(vals).astype(jnp.float32)
+    # the scan and the three axes' pair lists laid end to end are one
+    # stage, ``rag.scan``: labels and values in, (lo, hi, val) out
+    with jax.named_scope("rag.scan"):
+        for axis in range(seg.ndim):
+            # the block-ownership halo convention (module docstring):
+            # inner+1 along the scan axis, inner along the others
+            bb = tuple(
+                slice(0, min(inner[d] + 1, seg.shape[d]))
+                if d == axis
+                else slice(0, inner[d])
+                for d in range(seg.ndim)
+            )
+            lo, hi, val, valid = axis_edge_scan(
+                seg[bb], None if values is None else values[bb], axis,
+                with_values=with_values,
+            )
+            los.append(jnp.where(valid, lo, INT_MAX))
+            his.append(jnp.where(valid, hi, INT_MAX))
+            vals.append(val)
+        lo = jnp.concatenate(los).astype(jnp.int32)
+        hi = jnp.concatenate(his).astype(jnp.int32)
+        val = jnp.concatenate(vals).astype(jnp.float32)
+    with jax.named_scope("rag.aggregate"):
+        return _aggregate_sorted(lo, hi, val, edge_cap, with_values)
+
+
+def _aggregate_sorted(lo, hi, val, edge_cap: int, with_values: bool):
+    """The ``rag.aggregate`` stage of :func:`device_edge_aggregate`: one
+    two-key sort of the pair list, then every edge's pair and count read off
+    its run and, with values, segmented reductions per edge."""
+    from jax import lax
+
+    INT_MAX = jnp.int32(np.iinfo(np.int32).max)
     lo, hi, val = lax.sort((lo, hi, val), num_keys=2)
     valid = lo != INT_MAX
     is_first = valid & (
@@ -121,16 +157,22 @@ def device_edge_aggregate(
     )
     seg_id = jnp.cumsum(is_first.astype(jnp.int32)) - 1
     n_edges = jnp.where(valid.any(), seg_id[-1] + 1, 0)
-    sid = jnp.where(valid, jnp.minimum(seg_id, edge_cap), edge_cap)
-    ones = valid.astype(jnp.int32)
-    count = jax.ops.segment_sum(ones, sid, num_segments=edge_cap + 1)[:-1]
-    out_lo = jnp.zeros((edge_cap + 1,), jnp.int32).at[sid].max(
-        jnp.where(valid, lo, 0), mode="drop"
-    )[:-1]
-    out_hi = jnp.zeros((edge_cap + 1,), jnp.int32).at[sid].max(
-        jnp.where(valid, hi, 0), mode="drop"
-    )[:-1]
+    # the list is sorted by edge, so an edge is a run of it: the run's first
+    # slot by a binary search of the run ids, its pair read there, its count
+    # the distance to the next run's first slot.  No scatter: the three that
+    # stood here cost 20 ms a dispatch on a TPU v5e, all of a graph block's
+    # device time (PERF.md section 6, PR 35)
+    slots = jnp.arange(edge_cap, dtype=jnp.int32)
+    first = jnp.searchsorted(seg_id, slots, side="left").astype(jnp.int32)
+    n_valid = jnp.sum(valid.astype(jnp.int32))
+    following = jnp.minimum(jnp.concatenate([first[1:], n_valid[None]]), n_valid)
+    live = slots < n_edges
+    at = jnp.minimum(first, lo.shape[0] - 1)
+    count = jnp.where(live, following - first, 0)
+    out_lo = jnp.where(live, lo[at], 0)
+    out_hi = jnp.where(live, hi[at], 0)
     if with_values:
+        sid = jnp.where(valid, jnp.minimum(seg_id, edge_cap), edge_cap)
         vsum = jax.ops.segment_sum(
             jnp.where(valid, val, 0.0), sid, num_segments=edge_cap + 1
         )[:-1]
@@ -207,13 +249,14 @@ def device_rag_costs(
         seg, values, edge_cap, with_values=True, inner_shape=inner_shape
     )
     valid = jnp.arange(edge_cap) < n_edges
-    mean = jnp.where(valid, vsum / jnp.maximum(count, 1), 0.0)
-    eps = jnp.float32(1e-5)
-    p = jnp.clip(mean, eps, 1.0 - eps)
-    beta = jnp.clip(jnp.asarray(beta, jnp.float32), eps, 1.0 - eps)
-    costs = jnp.where(
-        valid, jnp.log((1.0 - p) / p) + jnp.log((1.0 - beta) / beta), 0.0
-    )
+    with jax.named_scope("rag.costs"):
+        mean = jnp.where(valid, vsum / jnp.maximum(count, 1), 0.0)
+        eps = jnp.float32(1e-5)
+        p = jnp.clip(mean, eps, 1.0 - eps)
+        beta = jnp.clip(jnp.asarray(beta, jnp.float32), eps, 1.0 - eps)
+        costs = jnp.where(
+            valid, jnp.log((1.0 - p) / p) + jnp.log((1.0 - beta) / beta), 0.0
+        )
     # dense node compaction over the endpoint slots (sort-compact idiom)
     lab = jnp.concatenate(
         [jnp.where(valid, lo, INT_MAX), jnp.where(valid, hi, INT_MAX)]
@@ -278,23 +321,25 @@ def block_rag_fused(
             seg_j, vals_j, cap, float(beta), inner_shape=inner
         )
         n = int(n_edges)
+        _count(rag_dispatches=1, rag_cap_retries=n > cap)
         if n <= cap:
             break
         while cap < n:
             cap *= 2
     k = int(n_nodes)
-    nodes = np.asarray(node_table[:k]).astype(np.int64)
+    # fetched whole and cut on the host (see _block_rag_device)
+    node_table, lo, hi, costs, count, mean = jax.device_get(
+        (node_table, lo, hi, costs, count, mean))
+    nodes = node_table[:k].astype(np.int64)
     if orig_table is not None:
         nodes = orig_table[nodes]
-    edges = np.stack(
-        [np.asarray(lo[:n]), np.asarray(hi[:n])], axis=1
-    ).astype(np.int64)
+    edges = np.stack([lo[:n], hi[:n]], axis=1).astype(np.int64)
     return (
         nodes,
         edges,
-        np.asarray(costs[:n], np.float32),
-        np.asarray(count[:n]).astype(np.int64),
-        np.asarray(mean[:n], np.float32),
+        costs[:n].astype(np.float32),
+        count[:n].astype(np.int64),
+        mean[:n].astype(np.float32),
     )
 
 
@@ -359,6 +404,7 @@ def _block_rag_host(
         lo, hi, val, valid = axis_edge_scan(
             seg_j[bb], None if val_j is None else val_j[bb], axis, with_values
         )
+        _count(rag_dispatches=1)
         valid = np.asarray(valid)
         los.append(np.asarray(lo)[valid])
         his.append(np.asarray(hi)[valid])
@@ -411,6 +457,14 @@ def _block_rag_device(
     """
     with_values = values is not None
     dense, uniq = _densify_labels(seg)
+    # a block at the volume's upper faces comes without its halo plane
+    # there: padded with background (no pair holds a 0) to the shape of an
+    # inner block, so that one compiled program serves every block of a
+    # grid instead of one per combination of faces
+    pad = [(0, max(i + 1 - s, 0)) for i, s in zip(inner, dense.shape)]
+    if any(p for _, p in pad):
+        dense = np.pad(dense, pad)
+        values = None if values is None else np.pad(values, pad)
     vals_j = None if values is None else jnp.asarray(values, jnp.float32)
 
     cap = 1 << 14
@@ -421,13 +475,17 @@ def _block_rag_device(
             inner_shape=tuple(inner),
         )
         n = int(n_edges)
+        _count(rag_dispatches=1, rag_cap_retries=n > cap)
         if n <= cap:
             break
         while cap < n:
             cap *= 2
-    lo = np.asarray(lo[:n]).astype(np.int64)
-    hi = np.asarray(hi[:n]).astype(np.int64)
-    sizes = np.asarray(count[:n]).astype(np.int64)
+    # fetched whole and cut on the host: a slice of a device array to a
+    # length that the data decide would compile a program per length
+    lo, hi, count = jax.device_get((lo, hi, count))
+    lo = lo[:n].astype(np.int64)
+    hi = hi[:n].astype(np.int64)
+    sizes = count[:n].astype(np.int64)
     uv = np.stack([uniq[lo], uniq[hi]], axis=1).astype(np.uint64)
     nodes: Tuple = ()
     if return_nodes:
@@ -439,6 +497,7 @@ def _block_rag_device(
         nodes = (inner_lab[inner_lab != 0],)
     if not with_values:
         return (uv, sizes, None) + nodes
+    vsum, vsumsq, vmin, vmax = jax.device_get((vsum, vsumsq, vmin, vmax))
     s = np.asarray(vsum[:n], np.float64)
     sq = np.asarray(vsumsq[:n], np.float64)
     mean = s / np.maximum(sizes, 1)

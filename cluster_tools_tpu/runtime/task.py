@@ -263,6 +263,7 @@ class BaseTask:
         from . import executor as executor_mod
 
         from ..ops import contraction as contraction_mod
+        from ..ops import rag as rag_mod
         from ..parallel import device_pool as device_pool_mod
         from ..parallel import reduce_tree as reduce_tree_mod
         from ..parallel import step_cache as step_cache_mod
@@ -294,6 +295,7 @@ class BaseTask:
                 handoff_snap = handoff_mod.snapshot()
                 device_snap = device_pool_mod.snapshot()
                 solver_snap = contraction_mod.solver_snapshot()
+                rag_snap = rag_mod.dispatch_snapshot()
                 tree_snap = reduce_tree_mod.solve_snapshot()
                 compile_snap = trace_mod.compile_snapshot()
                 step_snap = step_cache_mod.totals()
@@ -351,6 +353,11 @@ class BaseTask:
             tree_metrics = reduce_tree_mod.solve_delta(tree_snap)
             if any(tree_metrics.values()):
                 io_metrics.update(tree_metrics)
+            # device programs of the RAG extraction this task dispatched
+            # (docs/OBSERVABILITY.md "Multicut")
+            rag_metrics = rag_mod.dispatch_delta(rag_snap)
+            if any(rag_metrics.values()):
+                io_metrics.update(rag_metrics)
             # did this task trace, lower, compile or read a program back, and
             # which (docs/OBSERVABILITY.md "Compiles")
             compile_metrics = trace_mod.compile_delta(compile_snap)

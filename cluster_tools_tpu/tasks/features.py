@@ -22,6 +22,7 @@ import numpy as np
 
 from ..ops.rag import block_rag, merge_feature_lists
 from ..runtime import handoff
+from ..runtime import trace as trace_mod
 from ..runtime.task import BaseTask, WorkflowBase
 from ..utils.volume_utils import Blocking, blocks_in_volume, file_reader
 from .graph import _upper_halo_bb, graph_dir, load_global_graph
@@ -84,12 +85,18 @@ class BlockEdgeFeaturesBase(BaseTask):
         def process(block_id: int):
             block = blocking.get_block(block_id)
             bb = _upper_halo_bb(block, shape)
-            seg = np.asarray(ds_labels[bb])
-            val = _read_boundary_map(ds_in, bb, channel)
-            uv, _, feats = block_rag(seg, values=val, inner_shape=block.shape)
-            self.save_handoff_arrays(
-                block_features_path(self.tmp_folder, block_id), uv=uv, feats=feats
-            )
+            with trace_mod.span("features.block", block_id=int(block_id)) as sp:
+                seg = np.asarray(ds_labels[bb])
+                val = _read_boundary_map(ds_in, bb, channel)
+                sp.note(nbytes=int(seg.nbytes + val.nbytes),
+                        shape=list(seg.shape), inner=[int(s) for s in block.shape])
+                uv, _, feats = block_rag(
+                    seg, values=val, inner_shape=block.shape
+                )
+                self.save_handoff_arrays(
+                    block_features_path(self.tmp_folder, block_id),
+                    uv=uv, feats=feats,
+                )
 
         n = self.host_block_map(block_ids, process)
         return {"n_blocks": n}
@@ -126,7 +133,8 @@ class MergeEdgeFeaturesBase(BaseTask):
                 )
                 yield f["uv"], f["feats"]
 
-        feats = merge_feature_lists(uv_global, parts())
+        with trace_mod.span("features.merge", n_blocks=len(block_ids)):
+            feats = merge_feature_lists(uv_global, parts())
         self.save_handoff_array(features_path(self.tmp_folder), feats)
         return {"n_edges": len(feats)}
 

@@ -24,6 +24,7 @@ import numpy as np
 
 from ..ops.rag import block_rag, find_edge_ids, merge_edge_lists
 from ..runtime import handoff
+from ..runtime import trace as trace_mod
 from ..runtime.task import BaseTask, WorkflowBase
 from ..utils.volume_utils import Blocking, blocks_in_volume
 
@@ -87,20 +88,24 @@ class InitialSubGraphsBase(BaseTask):
 
         def process(block_id: int):
             block = blocking.get_block(block_id)
-            seg = np.asarray(ds[_upper_halo_bb(block, shape)])
-            # return_nodes: the inner node set comes out of the extraction's
-            # own dense-label pass instead of a second host np.unique scan
-            # over the block's voxels (ISSUE 1 fused-path satellite)
-            uv, sizes, _, nodes = block_rag(
-                seg, inner_shape=block.shape, return_nodes=True
-            )
-            nodes = nodes.astype(np.uint64)
-            self.save_handoff_arrays(
-                block_graph_path(self.tmp_folder, block_id),
-                nodes=nodes,
-                uv=uv,
-                sizes=sizes,
-            )
+            with trace_mod.span("graph.block", block_id=int(block_id)) as sp:
+                seg = np.asarray(ds[_upper_halo_bb(block, shape)])
+                sp.note(nbytes=int(seg.nbytes), shape=list(seg.shape),
+                        inner=[int(s) for s in block.shape])
+                # return_nodes: the inner node set comes out of the
+                # extraction's own dense-label pass instead of a second host
+                # np.unique scan over the block's voxels (ISSUE 1 fused-path
+                # satellite)
+                uv, sizes, _, nodes = block_rag(
+                    seg, inner_shape=block.shape, return_nodes=True
+                )
+                nodes = nodes.astype(np.uint64)
+                self.save_handoff_arrays(
+                    block_graph_path(self.tmp_folder, block_id),
+                    nodes=nodes,
+                    uv=uv,
+                    sizes=sizes,
+                )
 
         n = self.host_block_map(block_ids, process)
         return {"n_blocks": n}
@@ -130,18 +135,19 @@ class MergeSubGraphsBase(BaseTask):
             shape, tuple(cfg["block_shape"]), cfg.get("roi_begin"), cfg.get("roi_end")
         )
         edge_lists, node_lists = [], []
-        for b in block_ids:
-            f = handoff.load_arrays(block_graph_path(self.tmp_folder, b))
-            edge_lists.append((f["uv"], f["sizes"]))
-            node_lists.append(f["nodes"])
-        uv, sizes = merge_edge_lists(edge_lists)
-        nodes = (
-            np.unique(np.concatenate(node_lists))
-            if node_lists
-            else np.zeros(0, np.uint64)
-        )
-        # dense edge representation for solvers: rows index into `nodes`
-        edges = np.searchsorted(nodes, uv).astype(np.int64)
+        with trace_mod.span("graph.merge", n_blocks=len(block_ids)):
+            for b in block_ids:
+                f = handoff.load_arrays(block_graph_path(self.tmp_folder, b))
+                edge_lists.append((f["uv"], f["sizes"]))
+                node_lists.append(f["nodes"])
+            uv, sizes = merge_edge_lists(edge_lists)
+            nodes = (
+                np.unique(np.concatenate(node_lists))
+                if node_lists
+                else np.zeros(0, np.uint64)
+            )
+            # dense edge representation for solvers: rows index into `nodes`
+            edges = np.searchsorted(nodes, uv).astype(np.int64)
         self.save_handoff_arrays(
             global_graph_path(self.tmp_folder),
             nodes=nodes,

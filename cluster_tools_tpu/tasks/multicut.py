@@ -35,6 +35,7 @@ import numpy as np
 
 from ..ops.multicut import contract_graph, multicut_energy
 from ..runtime import handoff
+from ..runtime import trace as trace_mod
 from ..runtime.task import BaseTask, WorkflowBase
 from ..utils.segmentation_utils import get_multicut_solver
 from ..utils.volume_utils import Blocking, blocks_in_volume
@@ -215,7 +216,9 @@ class SolveSubproblemsBase(BaseTask):
         solver = get_multicut_solver(cfg.get("agglomerator", "gaec_parallel"))
         edges, costs, node_labeling = _load_problem(self.tmp_folder, scale)
         solver_snap = contraction_mod.solver_snapshot()
-        block_nodes = _scale_block_nodes(self.tmp_folder, cfg, scale, node_labeling)
+        with trace_mod.span("mc.block_nodes", scale=scale):
+            block_nodes = _scale_block_nodes(
+                self.tmp_folder, cfg, scale, node_labeling)
 
         cut = np.zeros(len(edges), dtype=bool)
         seen = np.zeros(len(edges), dtype=bool)
@@ -224,19 +227,22 @@ class SolveSubproblemsBase(BaseTask):
             block_id, nodes = item
             if len(nodes) < 2:
                 return None
-            in_set_u = np.isin(edges[:, 0], nodes)
-            in_set_v = np.isin(edges[:, 1], nodes)
-            sub_mask = in_set_u & in_set_v
-            if not sub_mask.any():
-                return None
-            sub_edges = edges[sub_mask]
-            sub_costs = costs[sub_mask]
-            # compact node ids for the solver
-            sub_nodes, sub_e = np.unique(sub_edges, return_inverse=True)
-            sub_e = sub_e.reshape(sub_edges.shape)
-            labels = solver(len(sub_nodes), sub_e, sub_costs)
-            is_cut = labels[sub_e[:, 0]] != labels[sub_e[:, 1]]
-            return sub_mask, is_cut
+            with trace_mod.span("mc.subproblem", block_id=int(block_id),
+                                scale=scale) as sp:
+                in_set_u = np.isin(edges[:, 0], nodes)
+                in_set_v = np.isin(edges[:, 1], nodes)
+                sub_mask = in_set_u & in_set_v
+                if not sub_mask.any():
+                    return None
+                sub_edges = edges[sub_mask]
+                sub_costs = costs[sub_mask]
+                # compact node ids for the solver
+                sub_nodes, sub_e = np.unique(sub_edges, return_inverse=True)
+                sub_e = sub_e.reshape(sub_edges.shape)
+                sp.note(n_nodes=len(sub_nodes), n_edges=len(sub_e))
+                labels = solver(len(sub_nodes), sub_e, sub_costs)
+                is_cut = labels[sub_e[:, 0]] != labels[sub_e[:, 1]]
+                return sub_mask, is_cut
 
         with ThreadPoolExecutor(max_workers=max(1, self.max_jobs)) as pool:
             for res in pool.map(process, sorted(block_nodes.items())):
@@ -294,13 +300,14 @@ class ReduceProblemBase(BaseTask):
 
         from ..ops.unionfind import union_find_host
 
-        merge_pairs = edges[seen & ~cut]
-        roots = union_find_host(merge_pairs, n_nodes)
-        _, new_ids = np.unique(roots, return_inverse=True)
-        new_ids = new_ids.astype(np.int64)
+        with trace_mod.span("mc.reduce", scale=scale, n_edges=len(edges)):
+            merge_pairs = edges[seen & ~cut]
+            roots = union_find_host(merge_pairs, n_nodes)
+            _, new_ids = np.unique(roots, return_inverse=True)
+            new_ids = new_ids.astype(np.int64)
 
-        new_edges, new_costs = contract_graph(edges, costs, new_ids)
-        new_labeling = new_ids[node_labeling]
+            new_edges, new_costs = contract_graph(edges, costs, new_ids)
+            new_labeling = new_ids[node_labeling]
         self.save_handoff_arrays(
             problem_path(self.tmp_folder, scale + 1),
             edges=new_edges,

@@ -14,6 +14,7 @@ import os
 import numpy as np
 
 from ..runtime import handoff
+from ..runtime import trace as trace_mod
 from ..runtime.executor import region_verifier
 from ..runtime.task import BaseTask
 from ..utils.volume_utils import Blocking, blocks_in_volume, file_reader
@@ -63,8 +64,10 @@ class WriteBase(BaseTask):
 
         def process(block_id):
             block = blocking.get_block(block_id)
-            labels = inp[block.bb]
-            out[block.bb] = apply_assignment_np(labels, keys, values)
+            with trace_mod.span("write.block", block_id=int(block_id)) as sp:
+                labels = inp[block.bb]
+                sp.note(nbytes=int(labels.nbytes))
+                out[block.bb] = apply_assignment_np(labels, keys, values)
 
         n = self.host_block_map(
             block_ids, process,

@@ -18,8 +18,10 @@ import os
 from typing import Any, Dict
 
 from .runtime.task import WorkflowBase, get_task_cls
+from .utils import function_utils as fu
 from .tasks import costs as costs_mod
 from .tasks import features as feat_mod
+from .tasks import fused as fused_mod
 from .tasks import graph as graph_mod
 from .tasks import multicut as mc_mod
 from .tasks import watershed as ws_mod
@@ -31,6 +33,16 @@ def _pick(p: Dict[str, Any], *names: str) -> Dict[str, Any]:
     return {k: p[k] for k in names if k in p}
 
 
+def _tasks_below(task, seen=None):
+    """Every task of ``task``'s DAG but itself, each uid once."""
+    seen = set() if seen is None else seen
+    for dep in task.requires():
+        if dep.uid not in seen:
+            seen.add(dep.uid)
+            yield dep
+            yield from _tasks_below(dep, seen)
+
+
 class MulticutSegmentationWorkflow(WorkflowBase):
     """boundary map -> supervoxels -> RAG -> features -> costs -> multicut
     -> segmentation.
@@ -38,11 +50,20 @@ class MulticutSegmentationWorkflow(WorkflowBase):
     Params:
       ``input_path/input_key``    boundary/affinity map (float),
       ``ws_path/ws_key``          supervoxel dataset (created unless
-                                  ``skip_ws``),
+                                  ``skip_ws``); ``ws_path`` defaults to
+                                  ``output_path``,
       ``output_path/output_key``  final segmentation,
       ``skip_ws``                 use an existing supervoxel dataset,
       ``two_pass_ws``             checkerboard two-pass watershed,
-      watershed params (``threshold``, ``sigma_seeds``, ``halo``, ...),
+      ``execution``               left out: the blockwise watershed chain
+                                  makes the supervoxels.  ``"fused"`` /
+                                  ``"split"``: the ROI fits the device(s),
+                                  and the mesh-resident step
+                                  (:mod:`.tasks.fused`, which this is a
+                                  parameter of) makes them in one program,
+      watershed params (``threshold``, ``sigma_seeds``, ``halo``, ...; with
+      ``execution`` the fused task's: ``dt_max_distance``, ``impl``,
+      ``decomposition``, ...),
       ``channel``                 boundary-map channel selector for features,
       ``beta``/``weighting_scheme`` cost transform,
       ``n_scales``                subproblem levels,
@@ -58,36 +79,13 @@ class MulticutSegmentationWorkflow(WorkflowBase):
             config_dir=self.config_dir,
             max_jobs=self.max_jobs,
         )
-        ws_path, ws_key = p["ws_path"], p["ws_key"]
+        ws_path, ws_key = p.get("ws_path") or p["output_path"], p["ws_key"]
         deps = list(self.dependencies)
+        grid = _pick(p, "block_shape", "roi_begin", "roi_end")
 
         if not p.get("skip_ws", False):
-            ws = ws_mod.WatershedWorkflow(
-                **common,
-                target=self.target,
-                dependencies=deps,
-                input_path=p["input_path"],
-                input_key=p["input_key"],
-                output_path=ws_path,
-                output_key=ws_key,
-                two_pass=p.get("two_pass_ws", False),
-                **_pick(
-                    p,
-                    "threshold",
-                    "sigma_seeds",
-                    "min_seed_distance",
-                    "sampling",
-                    "size_filter",
-                    "two_d",
-                    "halo",
-                    "block_shape",
-                    "mask_path",
-                    "mask_key",
-                ),
-            )
-            deps = [ws]
+            deps = [self._supervoxels(common, deps, ws_path, ws_key, grid)]
 
-        grid = _pick(p, "block_shape", "roi_begin", "roi_end")
         g = graph_mod.GraphWorkflow(
             **common,
             target=self.target,
@@ -136,12 +134,98 @@ class MulticutSegmentationWorkflow(WorkflowBase):
         )
         return [write]
 
+    def _supervoxels(self, common, deps, ws_path, ws_key, grid):
+        """The task that makes the supervoxels: the mesh-resident step where
+        ``execution`` is given (its watershed output only, no ``cc_key``;
+        every other parameter the fused task's own), else the blockwise
+        watershed chain."""
+        p = self.params
+        if p.get("execution") is not None:
+            return get_task_cls(fused_mod, "FusedSegmentation", self.target)(
+                **common,
+                dependencies=deps,
+                input_path=p["input_path"],
+                input_key=p["input_key"],
+                output_path=ws_path,
+                ws_key=ws_key,
+                **_pick(p, *fused_mod.FusedSegmentationBase.default_task_config()),
+                **grid,
+            )
+        return ws_mod.WatershedWorkflow(
+            **common,
+            target=self.target,
+            dependencies=deps,
+            input_path=p["input_path"],
+            input_key=p["input_key"],
+            output_path=ws_path,
+            output_key=ws_key,
+            two_pass=p.get("two_pass_ws", False),
+            **_pick(
+                p,
+                "threshold",
+                "sigma_seeds",
+                "min_seed_distance",
+                "sampling",
+                "size_filter",
+                "two_d",
+                "halo",
+                "block_shape",
+                "mask_path",
+                "mask_key",
+            ),
+        )
+
+    def run_impl(self):
+        """What the chain did, gathered from its tasks' manifests into the
+        workflow's own and into ``io_metrics.json`` (docs/OBSERVABILITY.md
+        "Multicut"): the graph's size, the device dispatches of the two
+        voxel-bound stages, how often a consumer was served from memory or
+        went to the store, the energy of the partition, and where the
+        fused step came from."""
+        docs: Dict[str, Dict[str, Any]] = {}
+        for task in _tasks_below(self):
+            try:
+                docs[task.task_name] = task.output().read()
+            except OSError:
+                continue
+
+        def of(task_name, *keys):
+            doc = docs.get(task_name, {})
+            for k in keys:
+                doc = (doc or {}).get(k)
+            return doc
+
+        io = [d.get("io_metrics") or {} for d in docs.values()]
+        summary = {
+            "n_blocks": of("initial_sub_graphs", "n_blocks"),
+            "n_nodes": of("merge_sub_graphs", "n_nodes"),
+            "n_edges": of("merge_sub_graphs", "n_edges"),
+            "n_segments": of("solve_global", "n_segments"),
+            "energy": of("solve_global", "energy"),
+            "rag_dispatches": {
+                "graph": of("initial_sub_graphs", "io_metrics", "rag_dispatches"),
+                "features": of("block_edge_features", "io_metrics", "rag_dispatches"),
+            },
+            "handoff_hits": sum(m.get("handoffs_served", 0) for m in io),
+            "store_reads": sum(
+                m.get("misses", 0) + m.get("direct_reads", 0) for m in io),
+            "store_read_bytes": sum(m.get("bytes_from_storage", 0) for m in io),
+        }
+        step = of("fused_segmentation", "step_cache")
+        if step:
+            summary["step_cache"] = _pick(step, "from", "fallback")
+        fu.record_io_metrics(
+            fu.io_metrics_path(self.tmp_folder), self.uid, {"multicut": summary}
+        )
+        return {"multicut": summary}
+
     @staticmethod
     def get_config() -> Dict[str, Dict[str, Any]]:
         """Aggregated per-task default configs (reference pattern: workflows
         expose ``get_config()`` so users can materialize + edit the JSONs)."""
         return {
             "global": WorkflowBase.default_global_config(),
+            "fused_segmentation": fused_mod.FusedSegmentationBase.default_task_config(),
             "watershed": ws_mod.WatershedBase.default_task_config(),
             "two_pass_watershed": ws_mod.TwoPassWatershedBase.default_task_config(),
             "initial_sub_graphs": graph_mod.InitialSubGraphsBase.default_task_config(),

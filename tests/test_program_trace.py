@@ -365,7 +365,7 @@ def test_step_load_reader_returns_nothing_without_the_span(traced, which):
                         else traced) is None
 
 
-def test_step_load_metric_is_declared_for_both_cells():
+def test_step_load_metric_is_declared_for_every_cell_with_the_fused_step():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     (mine,) = [m for m in bench["per_layer"] if m["name"] == "step_load_s"]
@@ -373,7 +373,8 @@ def test_step_load_metric_is_declared_for_both_cells():
         meta = json.load(f)
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert mine[key] == meta[key]
-    assert mine["workloads"] == ["fused384.volumes", "fused4x384.volumes.sp4"]
+    assert mine["workloads"] == ["fused384.volumes", "fused4x384.volumes.sp4",
+                                 "multicut384.volumes"]
     assert (mine["unit"], mine["better"], mine["source"]) == ("s", "lower", "program_span")
     assert (mine["layer"], mine["moves"]) == ("entry and workflow", "voxels_per_s")
     assert meta["spans"] == ["fused.step_load"]
