@@ -629,9 +629,11 @@ def fill_unseeded_basins_dense(
     there), and still NO SORTS anywhere.  Two one-time passes keep every
     round off the volume:
 
-    - the per-axis basin-face candidate set is harvested ONCE into
-      compacted lists (an O(n) cumsum compact, not a sort) — sound because
-      a face can only LEAVE the edge set as basins merge, never join it;
+    - the per-axis basin-face candidate set is harvested ONCE (an O(n)
+      cumsum compact of the face positions, not a sort; endpoints, saddles
+      and edge ids then gathered a chunk at a time, up to the axis's count)
+      — sound because a face can only LEAVE the edge set as basins merge,
+      never join it;
     - the seedless basins get DENSE ids: a basin's code names its terminal
       voxel, so ``cumsum(values == own code)`` ranks the terminals in flat
       order, and the face endpoints are rewritten once from codes to
@@ -680,14 +682,18 @@ def fill_unseeded_basins_dense(
     on one volume: five rounds of 14, 6, 2, 1 and 0 trips (9.6M live faces
     of 33M slots, about 0.4 of them alive a round later) take 2.8 s where
     the padded lists took 17.7 s; a trip is 0.09 s, a chunk's gather from a
-    4.1M-entry table running at 60M elements/s.  Volume-sized and paid once
-    a job, 7.3 s and now most of the fill: the harvest's twelve gathers
-    over the padded per-axis lists, the rank, the four compactions, the
-    final resolve.
+    4.1M-entry table running at 60M elements/s.  Paid once a job, 4.4 s
+    of the fill's 7.2 on that volume: the harvest 3.1 s (its three loops
+    1.2 s = 4 + 6 + 5 trips of 16, a trip's six gathers from 66M-entry
+    tables 0.078 s, where eighteen gathers over lists padded to
+    ``face_cap`` took 4.1 s; the four n-sized compaction scatters 1.3 s
+    and their sorts 0.65 s) and the final resolve's volume-sized gather
+    1.2 s.
     Memory: one list of four ``3 * face_cap`` int32 arrays, four
     ``basin_cap`` tables and two volume-sized int32 temporaries (the rank
-    before the rounds, the code table after them); the per-axis arrays of
-    the harvest live beside the list until they are copied into it.
+    before the rounds, the code table after them); of the harvest only
+    one axis's compacted face positions (``face_cap`` slots) live beside
+    the list.
 
     ``values``: >0 seeded label, <= -2 unseeded terminal code
     (``-flat_index - 2``), 0 invalid, and **-1 for masked/padded voxels**
@@ -718,28 +724,6 @@ def fill_unseeded_basins_dense(
     if max_rounds is None:
         max_rounds = _auto_fill_rounds(n)
 
-    # ---- one-time dense basin ids ----
-    # a seedless basin's code is its terminal's own index, so the terminals
-    # are the voxels that carry their own code; their rank in flat order is
-    # the basin's id.  term_pos[id] leads back to the code after the rounds.
-    flat_idx = _match_vma(jnp.arange(n, dtype=jnp.int32), values)
-    is_term = v == -flat_idx - 2
-    (term_pos,), n_basins = _compact(is_term, (flat_idx,), basin_cap, n)
-    trunc = (n_basins > basin_cap).astype(jnp.int32)
-    term_id = jnp.where(is_term, jnp.cumsum(is_term.astype(jnp.int32)) - 1, -1)
-
-    def to_id(x):
-        """Face endpoint: code -> ``-id - 2`` as ``P0`` resolves it (an id
-        past a truncated table reads the table's last entry); seeds, -1 and
-        0 as they are.  Also: whether some code here has no id (its
-        terminal does not carry it)."""
-        coded = x <= -2
-        tid = term_id[jnp.clip(-x - 2, 0, n - 1)]
-        return (
-            jnp.where(coded, jnp.maximum(-tid - 2, -basin_cap - 1), x),
-            jnp.any(coded & (tid < 0)),
-        )
-
     # P[id] = current label of basin id: a seed label, -1, or the -id - 2
     # of the basin it was joined to; ids resolve through it, seeds are
     # terminal by value
@@ -748,45 +732,90 @@ def fill_unseeded_basins_dense(
     def resolve_flat(P, x):
         return jnp.where(x <= -2, P[jnp.clip(-x - 2, 0, basin_cap - 1)], x)
 
-    # ---- one-time face harvest (round-invariant superset) ----
-    # a face is a candidate edge iff the ORIGINAL codes differ, both are
-    # nonzero, and at least one side is an unseeded basin; merging only
-    # shrinks this set, so harvesting once is exact.  eid = axis * n + voxel
-    # index is globally distinct and seen identically from both sides, so
-    # the min-edge graph is a forest plus 2-cycles (the classic
-    # distinct-weight Boruvka argument, as in _fill_core).
-    # The three axes' faces go into ONE list (va, vb, sad, eid) of 48
-    # chunks (>= 3 * face_cap slots) whose first n_live slots are the
-    # faces: each axis's compacted block lands at the running count, over
-    # the padding of the block before it.  No slot past n_live is read.
     chunk = -(-face_cap // 16)
-    lists = tuple(
-        _match_vma(jnp.zeros((48 * chunk,), jnp.int32), values)
-        for _ in range(4)
-    )
-    n_live = _match_vma(jnp.zeros((), jnp.int32), values)
-    for axis in range(3):
-        nb = _shift(values, -1, axis, jnp.int32(0)).ravel()
-        ok0 = (
-            (v != nb) & (v != 0) & (nb != 0)
-            & ((v <= -2) | (nb <= -2))
+    with jax.named_scope("ws.fill.harvest"):
+        # ---- one-time dense basin ids ----
+        # a seedless basin's code is its terminal's own index, so the
+        # terminals are the voxels that carry their own code; their rank in
+        # flat order is the basin's id.  term_pos[id] leads back to the code
+        # after the rounds.
+        flat_idx = _match_vma(jnp.arange(n, dtype=jnp.int32), values)
+        is_term = v == -flat_idx - 2
+        (term_pos,), n_basins = _compact(is_term, (flat_idx,), basin_cap, n)
+        trunc = (n_basins > basin_cap).astype(jnp.int32)
+        term_id = jnp.where(
+            is_term, jnp.cumsum(is_term.astype(jnp.int32)) - 1, -1
         )
-        (idx_c,), n_faces = _compact(ok0, (flat_idx,), face_cap, n)
-        trunc = jnp.maximum(trunc, (n_faces > face_cap).astype(jnp.int32))
-        stride = int(np.prod(shape[axis + 1:], dtype=np.int64))
-        pad = idx_c >= n
-        ia = jnp.clip(idx_c, 0, n - 1)
-        ib = jnp.clip(idx_c + stride, 0, n - 1)
-        va, bad_a = to_id(jnp.where(pad, 0, v[ia]))
-        vb, bad_b = to_id(jnp.where(pad, 0, v[ib]))
-        trunc = jnp.maximum(trunc, (bad_a | bad_b).astype(jnp.int32))
-        sad = jnp.maximum(h[ia], h[ib])
-        eid = jnp.int32(axis) * jnp.int32(n) + idx_c
+
+        def to_id(x):
+            """Face endpoint: code -> ``-id - 2`` as ``P0`` resolves it (an
+            id past a truncated table reads the table's last entry); seeds,
+            -1 and 0 as they are.  Also: whether some code here has no id
+            (its terminal does not carry it)."""
+            coded = x <= -2
+            tid = term_id[jnp.clip(-x - 2, 0, n - 1)]
+            return (
+                jnp.where(coded, jnp.maximum(-tid - 2, -basin_cap - 1), x),
+                jnp.any(coded & (tid < 0)),
+            )
+
+        # ---- one-time face harvest (round-invariant superset) ----
+        # a face is a candidate edge iff the ORIGINAL codes differ, both are
+        # nonzero, and at least one side is an unseeded basin; merging only
+        # shrinks this set, so harvesting once is exact.  eid = axis * n +
+        # voxel index is globally distinct and seen identically from both
+        # sides, so the min-edge graph is a forest plus 2-cycles (the
+        # classic distinct-weight Boruvka argument, as in _fill_core).
+        # The three axes' faces go into ONE list (va, vb, sad, eid) of 48
+        # chunks (>= 3 * face_cap slots) whose first n_live slots are the
+        # faces.  An axis's face positions are compacted (padding: n) and
+        # walked in chunks up to their count, as the rounds walk the list:
+        # a chunk's endpoints, saddles and ids land at the running count
+        # plus the chunk's start, its tail past the count (0, 0, the pad's
+        # saddle and eid) over slots that the next axis overwrites.  No
+        # slot past n_live is read.
         lists = tuple(
-            lax.dynamic_update_slice(buf, x, (n_live,))
-            for buf, x in zip(lists, (va, vb, sad, eid))
+            _match_vma(jnp.zeros((48 * chunk,), jnp.int32), values)
+            for _ in range(4)
         )
-        n_live = n_live + jnp.minimum(n_faces, face_cap)
+        n_live = _match_vma(jnp.zeros((), jnp.int32), values)
+        for axis in range(3):
+            nb = _shift(values, -1, axis, jnp.int32(0)).ravel()
+            ok0 = (
+                (v != nb) & (v != 0) & (nb != 0)
+                & ((v <= -2) | (nb <= -2))
+            )
+            (idx_c,), n_faces = _compact(ok0, (flat_idx,), face_cap, n)
+            trunc = jnp.maximum(trunc, (n_faces > face_cap).astype(jnp.int32))
+            n_kept = jnp.minimum(n_faces, face_cap)
+            # room for 16 whole chunks: the last one's slice never clamps
+            idx_c = jnp.pad(
+                idx_c, (0, 16 * chunk - face_cap), constant_values=n
+            )
+            stride = int(np.prod(shape[axis + 1:], dtype=np.int64))
+
+            def harvest(k, c):
+                lists, trunc = c
+                idx = lax.dynamic_slice(idx_c, (k * chunk,), (chunk,))
+                pad = idx >= n
+                ia = jnp.clip(idx, 0, n - 1)
+                ib = jnp.clip(idx + stride, 0, n - 1)
+                va, bad_a = to_id(jnp.where(pad, 0, v[ia]))
+                vb, bad_b = to_id(jnp.where(pad, 0, v[ib]))
+                sad = jnp.maximum(h[ia], h[ib])
+                eid = jnp.int32(axis) * jnp.int32(n) + idx
+                lists = tuple(
+                    lax.dynamic_update_slice(buf, x, (n_live + k * chunk,))
+                    for buf, x in zip(lists, (va, vb, sad, eid))
+                )
+                return lists, jnp.maximum(
+                    trunc, (bad_a | bad_b).astype(jnp.int32)
+                )
+
+            lists, trunc = lax.fori_loop(
+                0, (n_kept + chunk - 1) // chunk, harvest, (lists, trunc)
+            )
+            n_live = n_live + n_kept
     me_idx = _match_vma(jnp.arange(basin_cap, dtype=jnp.int32), values)
     slot = jnp.arange(chunk, dtype=jnp.int32)
 
@@ -905,13 +934,14 @@ def fill_unseeded_basins_dense(
         )
     # ---- back to the voxels: ids -> codes at the terminals' positions,
     # then one volume-sized gather as the codes name those positions ----
-    root_pos = term_pos[jnp.clip(-P - 2, 0, basin_cap - 1)]
-    code_table = (-flat_idx - 2).at[term_pos].set(
-        jnp.where(P <= -2, -root_pos - 2, P), mode="drop"
-    )
-    resolved = jnp.where(
-        v <= -2, code_table[jnp.clip(-v - 2, 0, n - 1)], v
-    ).reshape(shape)
+    with jax.named_scope("ws.fill.resolve"):
+        root_pos = term_pos[jnp.clip(-P - 2, 0, basin_cap - 1)]
+        code_table = (-flat_idx - 2).at[term_pos].set(
+            jnp.where(P <= -2, -root_pos - 2, P), mode="drop"
+        )
+        resolved = jnp.where(
+            v <= -2, code_table[jnp.clip(-v - 2, 0, n - 1)], v
+        ).reshape(shape)
     return resolved, jnp.maximum(unconverged.astype(jnp.int32), trunc)
 
 

@@ -211,13 +211,15 @@ def test_dense_mode_through_watershed(rng, monkeypatch):
     jax.clear_caches()
 
 
-def _chain_case(L):
+def _chain_case(L, toward=1):
     """A monotone saddle corridor: seed 1 — B1 — ... — B_L — seed 2 with
     strictly increasing heights, so every basin's min edge points toward
     seed 1 and round one hooks a chain of depth L.  Exact answer: ALL
     basins adopt seed 1.  Depth L >> 8 regresses the fixed-jump-count
     compression bug (partially composed tables let later rounds hook from
-    intermediate nodes and split the component across seeds)."""
+    intermediate nodes and split the component across seeds).
+    ``toward=2``: decreasing heights, every min edge points toward seed 2,
+    so the answer hangs on the LAST face of the corridor's list."""
     shape = (3, 3, L + 2)
     vals = np.zeros(shape, np.int32)  # 0 = invalid everywhere off-corridor
     vals[1, 1, 0] = 1
@@ -225,9 +227,8 @@ def _chain_case(L):
     flat = np.arange(np.prod(shape)).reshape(shape)
     for i in range(1, L + 1):
         vals[1, 1, i] = -int(flat[1, 1, i]) - 2  # its own terminal code
-    height = np.broadcast_to(
-        np.linspace(0.1, 0.9, L + 2).astype(np.float32), shape
-    )
+    ramp = np.linspace(0.1, 0.9, L + 2).astype(np.float32)
+    height = np.broadcast_to(ramp if toward == 1 else ramp[::-1], shape)
     return vals, np.ascontiguousarray(height)
 
 
@@ -475,6 +476,33 @@ def _plateau_case():
     return np.asarray(vals), np.asarray(height)
 
 
+def _columns_case():
+    """A partition that is constant along y: no face on axis 1, so the
+    harvest walks that axis in no trip at all and axis 2's faces land right
+    behind axis 0's.  The heights repeat along y too: every saddle ties
+    across the y copies of a face and the edge id decides."""
+    flat_vals, flat_height = _mk_case(np.random.default_rng(31), (12, 1, 14), 0.3)
+    ny = 5
+    z, x = np.divmod(-flat_vals[:, 0, :] - 2, 14)
+    codes = -(z * ny * 14 + x) - 2  # the terminal's copy at y = 0
+    plane = np.where(flat_vals[:, 0, :] <= -2, codes, flat_vals[:, 0, :])
+    vals = np.repeat(plane[:, None, :], ny, axis=1).astype(np.int32)
+    height = np.repeat(flat_height, ny, axis=1)
+    return vals, np.ascontiguousarray(height)
+
+
+def _axis_faces(vals):
+    """Candidate faces of each axis, as the harvest counts them."""
+    out = []
+    for axis in range(3):
+        a = np.moveaxis(vals, axis, 0)
+        v, nb = a[:-1], a[1:]
+        out.append(int(np.sum(
+            (v != nb) & (v != 0) & (nb != 0) & ((v <= -2) | (nb <= -2))
+        )))
+    return out
+
+
 #: name -> (inputs, ``face_cap`` (None: the default), the flag both raise,
 #: the chunks the live faces span at the start of each round (None: not held))
 _EQUALITY_CASES = {
@@ -495,6 +523,33 @@ _EQUALITY_CASES = {
     "plateau_d4": (_plateau_case, None, 1, None),
     "face_cap_truncated": (lambda: _masked_case(12, (14, 15, 16), 0.15), 200, 1, None),
     "face_cap_truncated_one_slot": (_two_cycle_case, 1, 1, None),
+    # the harvest's edges (``_HARVEST``): each axis's face positions are
+    # walked in chunks up to their count
+    "harvest_axis_without_face": (_columns_case, 640, 0, None),
+    "harvest_under_one_chunk": (
+        lambda: _masked_case(11, (14, 15, 16), 0.5), 9600, 0, (3, 1, 0)
+    ),
+    "harvest_exact_chunks": (
+        lambda: _masked_case(11, (14, 15, 16), 0.5), 4624, 0, None
+    ),
+    # 16 does not divide face_cap: a chunk is 3 slots, 16 of them 48, and
+    # the last chunk's slice reaches past face_cap.  The chain leans toward
+    # seed 2, so the last face kept decides every label: all 41 fit ...
+    "harvest_odd_face_cap_full": (lambda: _chain_case(40, toward=2), 41, 0, None),
+    # ... or the list is cut at 37 and the flag goes up
+    "harvest_truncated_odd_face_cap": (
+        lambda: _chain_case(40, toward=2), 37, 1, None
+    ),
+}
+
+#: name -> (faces of each axis, the harvest's trips for each axis)
+_HARVEST = {
+    "harvest_axis_without_face": ((125, 0, 150), (4, 0, 4)),
+    "harvest_under_one_chunk": ((578, 521, 531), (1, 1, 1)),
+    "harvest_exact_chunks": ((2 * 289, 521, 531), (2, 2, 2)),
+    "harvest_odd_face_cap_full": ((0, 0, 41), (0, 0, 14)),
+    "harvest_truncated_odd_face_cap": ((0, 0, 41), (0, 0, 13)),
+    "deep_chain_chunks_14_0": ((0, 0, 41), (0, 0, 14)),
 }
 
 
@@ -515,6 +570,17 @@ def test_compact_table_equals_n_table(case):
         chunk = -(-(face_cap or 1 << 16) // 16)
         spans = [-(-int(x) // chunk) for x in np.asarray(live)]
         assert tuple(spans[: len(chunks)]) == chunks
+    if case in _HARVEST:
+        faces, trips = _HARVEST[case]
+        chunk = -(-face_cap // 16)
+        assert tuple(_axis_faces(vals)) == faces
+        assert tuple(-(-min(f, face_cap) // chunk) for f in faces) == trips
+    if case == "harvest_odd_face_cap_full":
+        assert (np.asarray(got)[1, 1] == [1] + [2] * 41).all()
+    if case == "harvest_truncated_odd_face_cap":
+        # faces 0..36 are kept: B1..B37 end at seed 1, B38..B40 are cut off
+        assert (np.asarray(got)[1, 1, :38] == 1).all()
+        assert (np.asarray(got)[1, 1, 38:41] <= -2).all()
     if case == "two_cycle":
         assert (np.asarray(got)[1, 1] == [1, 1, 1, 1, 1, 1, 2, 2]).all()
         c_code = -(0 * 24 + 0 * 8 + 3) - 2
@@ -628,3 +694,30 @@ def test_compact_table_under_shard_map(check_vma, face_cap):
     got, flag = step(jnp.asarray(vals[:1]), jnp.asarray(height[:1]))
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0][0]))
     assert int(flag) == 0
+
+
+def test_harvest_gathers_are_chunk_sized():
+    """No gather of ``ws.fill.harvest`` takes ``face_cap`` or more indices:
+    the harvest reads its endpoints, saddles and ids a chunk at a time, in
+    a loop whose trips follow the count (six gathers for each axis)."""
+    shape, face_cap = (14, 15, 16), 1600
+    assert int(np.prod(shape)) > face_cap
+    closed = jax.make_jaxpr(
+        partial(fill_unseeded_basins_dense, face_cap=face_cap)
+    )(jnp.zeros(shape, jnp.int32), jnp.zeros(shape, jnp.float32))
+    found = []
+
+    def walk(jaxpr, stack):
+        for eqn in jaxpr.eqns:
+            path = f"{stack}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "gather":
+                found.append((path, eqn.invars[1].aval.shape[0]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, path)
+
+    walk(closed.jaxpr, "")
+    harvest = [slots for path, slots in found if "ws.fill.harvest" in path]
+    assert len(harvest) == 18
+    assert set(harvest) == {-(-face_cap // 16)}
+    # the walk does see a gather as large as that where there is one
+    assert any(slots >= face_cap for _, slots in found)

@@ -280,10 +280,9 @@ ROUND_OPS = (
 )
 
 
-@pytest.fixture
-def traced_rounds(traced):
-    """``traced`` with the fill's single fusion replaced by a round loop."""
-    ops = [op for op in DEVICE_OPS if op[0] != "fusion.6"] + list(ROUND_OPS)
+def _with_fill_ops(traced, fill_ops):
+    """``traced`` with the fill's single fusion replaced by ``fill_ops``."""
+    ops = [op for op in DEVICE_OPS if op[0] != "fusion.6"] + list(fill_ops)
     texts = {}
     for n, oc, _, _, tf in ops:
         texts.setdefault(_op_text(n, oc), tf)
@@ -295,6 +294,12 @@ def traced_rounds(traced):
     program_trace._read.cache_clear()
     traced["trace"].ops = [reduce_trace.Op(n, oc, _op_text(n, oc), s, d) for n, oc, s, d, _ in ops]
     return traced
+
+
+@pytest.fixture
+def traced_rounds(traced):
+    """``traced`` with the fill's single fusion replaced by a round loop."""
+    return _with_fill_ops(traced, ROUND_OPS)
 
 
 def test_rounds_reader_sums_the_loop_and_lists_its_rounds(traced_rounds, capfd):
@@ -322,6 +327,76 @@ def test_rounds_metric_is_declared_for_both_cells():
     (mine,) = [m for m in bench["per_layer"] if m["name"] == "ws_fill_rounds_device_s"]
     assert mine["workloads"][:2] == ["fused384.volumes", "fused4x384.volumes.sp4"]
     assert (mine["layer"], mine["moves"]) == ("kernels", "voxels_per_s")
+
+
+# -- PR 36's reader: the fill's harvest, walked to each axis's count -----------
+
+HARVEST = FILL + "ws.fill.harvest/"
+#: ids and a compaction outside the loops, then one loop an axis: 2 trips, 3
+#: trips, and an axis without a face (its loop runs no trip: no leaf inside);
+#: the rounds and the final resolve lie outside the scope
+HARVEST_OPS = (
+    ("fusion.30", "fusion", 16.0, 0.5, HARVEST + "cumsum:"),
+    ("fusion.31", "fusion", 16.5, 0.25, HARVEST + "scatter:"),
+    ("while.32", "while", 16.75, 1.0, ""),
+    ("fusion.33", "fusion", 16.75, 0.25, HARVEST + "while/body/gather:"),
+    ("fusion.34", "fusion", 17.0, 0.25, HARVEST + "while/body/dynamic_update_slice:"),
+    ("fusion.33", "fusion", 17.25, 0.25, HARVEST + "while/body/gather:"),
+    ("fusion.34", "fusion", 17.5, 0.25, HARVEST + "while/body/dynamic_update_slice:"),
+    ("fusion.31", "fusion", 17.75, 0.25, HARVEST + "scatter:"),
+    ("while.35", "while", 18.0, 0.75, ""),
+    ("fusion.36", "fusion", 18.0, 0.25, HARVEST + "while/body/gather:"),
+    ("fusion.36", "fusion", 18.25, 0.25, HARVEST + "while/body/gather:"),
+    ("fusion.36", "fusion", 18.5, 0.25, HARVEST + "while/body/gather:"),
+    ("while.37", "while", 18.75, 0.0, ""),
+    ("while.38", "while", 19.0, 1.0, ""),
+    ("fusion.39", "fusion", 19.0, 1.0, ROUNDS + "while/body/scatter-min:"),
+    ("fusion.40", "fusion", 20.0, 1.5, FILL + "ws.fill.resolve/gather:"),
+)
+
+
+@pytest.fixture
+def traced_harvest(traced):
+    """``traced`` with the fill's single fusion replaced by harvest, one
+    round and the final resolve."""
+    return _with_fill_ops(traced, HARVEST_OPS)
+
+
+def test_harvest_reader_sums_the_scope_and_lists_each_axis(traced_harvest, capfd):
+    assert _read_metric("ws_fill_harvest_device_s", traced_harvest) == pytest.approx(2.75)
+    listed = [line for line in capfd.readouterr().err.splitlines()
+              if line.startswith("[ws_fill_harvest]")]
+    assert len(listed) == 3
+    assert "axis loop 1: while.32 x2 of 16  1.000s" in listed[0]
+    assert "axis loop 2: while.35 x3 of 16  0.750s" in listed[1]
+    assert "loops 1.750s, outside them 1.000s" in listed[2]
+    # its neighbours keep their own: the rounds 1.0, the whole fill with the resolve
+    assert _read_metric("ws_fill_rounds_device_s", traced_harvest) == pytest.approx(1.0)
+    assert _read_metric("ws_fill_device_s", traced_harvest) == pytest.approx(5.25)
+
+
+@pytest.mark.parametrize("which", ["the_parents_program", "selfcheck"])
+def test_harvest_reader_returns_nothing_without_the_scope(traced_rounds, which):
+    """The parent commit's program has ``ws.fill.rounds`` and no
+    ``ws.fill.harvest``; ``selfcheck``'s trace has no stage at all."""
+    assert _read_metric("ws_fill_harvest_device_s", _selfcheck_traced()
+                        if which == "selfcheck" else traced_rounds) is None
+
+
+def test_harvest_metric_is_declared_for_every_cell_with_the_fused_step():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (mine,) = [m for m in bench["per_layer"] if m["name"] == "ws_fill_harvest_device_s"]
+    with open(os.path.join(ROOT, "benchmark", "metrics",
+                           "ws_fill_harvest_device_s.json")) as f:
+        meta = json.load(f)
+    for key in ("name", "unit", "better", "source", "layer", "moves"):
+        assert mine[key] == meta[key]
+    assert mine["workloads"] == ["fused384.volumes", "fused4x384.volumes.sp4",
+                                 "multicut384.volumes"]
+    assert (mine["unit"], mine["better"], mine["source"]) == ("s", "lower", "device_trace")
+    assert (mine["layer"], mine["moves"]) == ("kernels", "voxels_per_s")
+    assert meta["stages"] == ["ws.fill.harvest"]
 
 
 # -- PR 34's reader: the compiled step read back from the step store -----------
