@@ -127,6 +127,44 @@ def tile_local_labels_xla(
     )
 
 
+@jax.custom_batching.custom_vmap
+def scatter_set(buf: jnp.ndarray, idx: jnp.ndarray, vals: jnp.ndarray):
+    """``buf.at[idx].set(vals, mode="drop")`` on 1-D arrays, with the batched
+    form written out.
+
+    Alone it is that scatter and compiles to the same program.  Under
+    ``vmap`` (the blockwise executor's lanes) XLA's TPU pipeline rewrites a
+    scatter with a batch dimension into one flat scatter over ``lanes *
+    len(buf)`` slots, and the instructions it makes carry no ``op_name``:
+    on the chip the compactions of a blockwise program then show under no
+    stage scope (a sixth of its device time, PERF.md section 6, PR 37).
+    The rule below is that rewrite done here, under the caller's
+    ``jax.named_scope``, so the scatter keeps its stage."""
+    return buf.at[idx].set(vals, mode="drop")
+
+
+@scatter_set.def_vmap
+def _scatter_set_lanes(axis_size, in_batched, buf, idx, vals):
+    lanes, m = axis_size, buf.shape[-1]
+    if lanes * m >= 2 ** 31 - 1:  # flat positions would leave int32
+        plain = jax.vmap(
+            lambda b, i, v: b.at[i].set(v, mode="drop"),
+            in_axes=[0 if b else None for b in in_batched],
+        )
+        return plain(buf, idx, vals), True
+    buf, idx, vals = (
+        x if b else jnp.broadcast_to(x, (lanes,) + x.shape)
+        for x, b in zip((buf, idx, vals), in_batched)
+    )
+    idx = jnp.where(idx < 0, idx + m, idx)  # as indexing wraps a negative
+    lane0 = jnp.arange(lanes, dtype=idx.dtype)[:, None] * m
+    flat = jnp.where((idx >= 0) & (idx < m), idx + lane0, lanes * m)
+    out = buf.reshape(-1).at[flat.reshape(-1)].set(
+        vals.reshape(-1), mode="drop"
+    )
+    return out.reshape(lanes, m), True
+
+
 def _compact(
     flags: jnp.ndarray, values: Tuple[jnp.ndarray, ...], cap: int, fill: int
 ):
@@ -144,7 +182,7 @@ def _compact(
     out = []
     for v in values:
         buf = jnp.full((cap + 1,), fill, dtype=v.dtype)
-        buf = buf.at[dest].set(v.ravel(), mode="drop")
+        buf = scatter_set(buf, dest, v.ravel())
         out.append(buf[:cap])
     n_kept = jnp.where(flat.size > 0, pos[-1] + 1, 0).astype(jnp.int32)
     return tuple(out), n_kept
@@ -279,8 +317,8 @@ def _merge_core(a, b, edge_cap, max_rounds, vma_like):
     svals, sslots = lax.sort((vals, slots), num_keys=1)
     is_new = svals != _shift1(svals, 0, -1)
     rank = jnp.cumsum(is_new.astype(jnp.int32)) - 1
-    uniq = jnp.full((m2,), jnp.int32(BIG)).at[rank].set(svals)
-    dense = jnp.zeros((m2,), jnp.int32).at[sslots].set(rank)
+    uniq = scatter_set(jnp.full((m2,), jnp.int32(BIG)), rank, svals)
+    dense = scatter_set(jnp.zeros((m2,), jnp.int32), sslots, rank)
     da, db = dense[:edge_cap], dense[edge_cap:]
 
     parent = _match_vma(jnp.arange(m2, dtype=jnp.int32), vma_like)
@@ -376,8 +414,8 @@ def _remap_tables_core(tile_ids, old_vals, new_vals, n_tiles, table_cap):
                      n_tiles * table_cap)
     old_tbl = jnp.full((n_tiles * table_cap + 1,), jnp.int32(-1))
     new_tbl = jnp.full((n_tiles * table_cap + 1,), jnp.int32(-1))
-    old_tbl = old_tbl.at[dest].set(v, mode="drop")
-    new_tbl = new_tbl.at[dest].set(r, mode="drop")
+    old_tbl = scatter_set(old_tbl, dest, v)
+    new_tbl = scatter_set(new_tbl, dest, r)
     return (
         old_tbl[:-1].reshape(n_tiles, table_cap),
         new_tbl[:-1].reshape(n_tiles, table_cap),
@@ -395,8 +433,8 @@ def resolve_labels_gather(
     """Fallback resolve: scatter roots into a parent table, one full gather."""
     n = int(np.prod(labels.shape))
     P = _match_vma(jnp.arange(n + 1, dtype=jnp.int32), labels)
-    P = P.at[jnp.minimum(ea, n)].set(jnp.minimum(root_a, n), mode="drop")
-    P = P.at[jnp.minimum(eb, n)].set(jnp.minimum(root_b, n), mode="drop")
+    P = scatter_set(P, jnp.minimum(ea, n), jnp.minimum(root_a, n))
+    P = scatter_set(P, jnp.minimum(eb, n), jnp.minimum(root_b, n))
     flat = labels.ravel()
     out = P[jnp.minimum(flat, n)]
     return jnp.where(flat >= BIG, jnp.int32(BIG), out).reshape(labels.shape)

@@ -61,6 +61,7 @@ from .tile_ccl import (
     DEFAULT_TABLE_CAP,
     _auto_cap,
     _compact,
+    scatter_set,
     _round_up,
     _shift1,
     _tile_for,
@@ -424,7 +425,7 @@ def value_join(
             )
             (ctv, ctf), _ = _compact(tv < BIG, (tv, tf), small_t, BIG)
             res = _value_join_core(cq, ctv, ctf)
-            return qv.at[slots].set(res, mode="drop")
+            return scatter_set(qv, slots, res)
 
         def _big(args):
             return _value_join_core(*args)
@@ -456,7 +457,7 @@ def _value_join_core(query_vals, table_vals, table_finals):
     tbl_fin = payload[jnp.clip(last_tbl, 0, nt + nq - 1)]
     res = jnp.where((last_tbl >= 0) & (tbl_key == keys), tbl_fin, keys)
     out = jnp.zeros((nq,), jnp.int32)
-    out = out.at[jnp.where(is_query == 1, slot, nq)].set(res, mode="drop")
+    out = scatter_set(out, jnp.where(is_query == 1, slot, nq), res)
     return out
 
 
@@ -515,7 +516,7 @@ def chase_exits(values: jnp.ndarray, codes: jnp.ndarray, max_hops: int = 256):
         )
         fin_s, moved = _core(pc)
         # non-active codes map to themselves; padded slots (BIG) drop
-        out = c.at[slots].set(fin_s, mode="drop")
+        out = scatter_set(c, slots, fin_s)
         return out, moved
 
     n_active = (codes <= -2).sum()
@@ -527,7 +528,7 @@ def _resolve_codes_gather(values: jnp.ndarray, codes, finals) -> jnp.ndarray:
     n = values.size
     table = _match_vma(-jnp.arange(n, dtype=jnp.int32) - 2, values)
     pos = jnp.where(codes <= -2, -codes - 2, n)
-    table = table.at[pos].set(finals, mode="drop")
+    table = scatter_set(table, pos, finals)
     flat = values.ravel()
     looked = table[jnp.clip(-flat - 2, 0, n - 1)]
     return jnp.where(flat <= -2, looked, flat).reshape(values.shape)
@@ -936,8 +937,8 @@ def fill_unseeded_basins_dense(
     # then one volume-sized gather as the codes name those positions ----
     with jax.named_scope("ws.fill.resolve"):
         root_pos = term_pos[jnp.clip(-P - 2, 0, basin_cap - 1)]
-        code_table = (-flat_idx - 2).at[term_pos].set(
-            jnp.where(P <= -2, -root_pos - 2, P), mode="drop"
+        code_table = scatter_set(
+            -flat_idx - 2, term_pos, jnp.where(P <= -2, -root_pos - 2, P)
         )
         resolved = jnp.where(
             v <= -2, code_table[jnp.clip(-v - 2, 0, n - 1)], v
@@ -968,8 +969,8 @@ def _fill_core(a, b, hk, adj_cap, max_rounds, vma_like):
     sv, ss = lax.sort((vals, slots), num_keys=1)
     is_new = sv != _shift1(sv, 0, -BIG)
     rank = jnp.cumsum(is_new.astype(jnp.int32)) - 1
-    uniq = jnp.full((m2,), jnp.int32(BIG)).at[rank].set(sv)
-    dense = jnp.zeros((m2,), jnp.int32).at[ss].set(rank)
+    uniq = scatter_set(jnp.full((m2,), jnp.int32(BIG)), rank, sv)
+    dense = scatter_set(jnp.zeros((m2,), jnp.int32), ss, rank)
     da, db = dense[: a.shape[0]], dense[a.shape[0]:]
     edge_pad = a >= BIG
 
@@ -1619,9 +1620,12 @@ def _dt_watershed_seeded_tiled_jit(
         pair_cap=pair_cap, edge_cap=edge_cap, table_cap=table_cap,
         interpret=interpret,
     )
-    ext = ext_seeds.astype(jnp.int32)
-    # external seeds dominate; internal ids live in 1..N, external in N+1..
-    seeds = jnp.where(ext > 0, ext + jnp.int32(n), internal)
+    # the merge is part of seeding (``ws.seeds`` is the stage the readers
+    # know); ``ws.ext_seeds`` names it inside
+    with jax.named_scope("ws.seeds"), jax.named_scope("ws.ext_seeds"):
+        ext = ext_seeds.astype(jnp.int32)
+        # external seeds dominate; internal ids live in 1..N, external in N+1..
+        seeds = jnp.where(ext > 0, ext + jnp.int32(n), internal)
     labels, ws_overflow = _seeded_watershed_tiled_jit(
         boundaries, seeds, mask=valid, impl=impl, tile=tile,
         exit_cap=exit_cap, fill_cap=fill_cap, table_cap=table_cap,

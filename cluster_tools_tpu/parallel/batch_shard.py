@@ -138,6 +138,12 @@ def batched_shard_map(
     def _sharded_batch_body(*args):
         return jax.vmap(kernel)(*args)
 
+    # the compiled program carries its kernel's name (``jit_sharded_<name>``
+    # on a trace's "XLA Modules" line), so that two sweeps of one job can be
+    # told apart there
+    _sharded_batch_body.__name__ = "sharded_" + getattr(
+        kernel, "__name__", "kernel"
+    )
     spec = P(axis_name)
     return jax.jit(
         shard_map(

@@ -35,7 +35,9 @@ from ..runtime.executor import (
     validate_labels,
 )
 from ..parallel.mesh import device_peak_bytes
+from ..runtime import trace as trace_mod
 from ..runtime.task import BaseTask, WorkflowBase, get_task_cls
+from ..utils import function_utils as fu
 from ..utils.volume_utils import (
     Blocking,
     blocks_in_volume,
@@ -61,6 +63,50 @@ def _tiled_cap_knobs(cfg):
 
 def _outer_shape(block_shape, halo):
     return tuple(b + 2 * h for b, h in zip(block_shape, halo))
+
+
+def _refuse_checkerboard_hybrids(cfg):
+    """What the two-pass checkerboard cannot combine, refused in one place:
+    the workflow asks before pass one burns hours on even blocks, the two
+    tasks ask again because each can be run on its own."""
+    if cfg.get("two_d"):
+        # pass one would be segmented per slice and pass two in 3-D
+        raise NotImplementedError(
+            "two_d=True is not supported with the two-pass watershed; use "
+            "the single-pass watershed for per-slice segmentation"
+        )
+    if cfg.get("agglomerate_threshold") is not None:
+        # pass-two labels carry immutable external seed ids from pass one;
+        # merging either pass blockwise would desynchronize the shared
+        # label space: agglomerate on the single-pass task instead
+        raise NotImplementedError(
+            "agglomerate_threshold is not supported with the two-pass "
+            "watershed (pass_parity / two_pass=True)"
+        )
+
+
+def _pass_counters(summary, blocks, outer, use_tiled, overflow_blocks):
+    """What one sweep of the watershed computed, for the task's manifest and
+    its ``ws.pass`` span (docs/OBSERVABILITY.md "Two-pass watershed"): the
+    blocks it ran, its dispatches, the lanes that carried no block, and the
+    voxels computed (every lane at the kernel's tile-padded outer shape)
+    beside those of the outer blocks and of the inner blocks it stored."""
+    padded = outer
+    if use_tiled:
+        from ..ops.tile_ws import _ws_static_plan
+
+        padded = _ws_static_plan(outer, None, None, None)[1]
+    n_blocks = len(blocks)
+    lanes_padded = int(summary.get("n_lanes_padded", 0))
+    return {
+        "n_blocks": n_blocks,
+        "dispatches": int(summary.get("n_dispatches", 0)),
+        "lanes_padded": lanes_padded,
+        "padded_voxels": (n_blocks + lanes_padded) * int(np.prod(padded)),
+        "outer_voxels": n_blocks * int(np.prod(outer)),
+        "inner_voxels": sum(int(np.prod(b.shape)) for b in blocks),
+        "overflow_blocks": sorted(overflow_blocks),
+    }
 
 
 class _WsTaskBase(BaseTask):
@@ -208,6 +254,64 @@ class _WsTaskBase(BaseTask):
             f"kernels={resolved_modes(impl if use_tiled else 'legacy')}"
         )
 
+    def _sweep(self, executor, cfg, kernel, blocks, load, store, block_done,
+               done, out, parity, outer, use_tiled, overflow_blocks):
+        """One sweep of the block grid through the executor, as both passes
+        run it; returns the pass's counters (:func:`_pass_counters`).  The
+        ``ws.pass`` span is the pass on the timeline: which parity, how many
+        blocks, and (once the sweep is through) the lanes its dispatches
+        carried and the voxels they computed."""
+        todo = [b for b in blocks if b.block_id not in done]
+        with trace_mod.span(
+            "ws.pass", task=self.uid, parity=parity, n_blocks=len(todo)
+        ) as pass_span:
+            summary = executor.map_blocks(
+                kernel,
+                blocks,
+                load,
+                store,
+                on_block_done=block_done,
+                done_block_ids=done,
+                validate_fn=validate_labels,
+                failures_path=self.failures_path,
+                task_name=self.uid,
+                block_deadline_s=cfg.get("block_deadline_s"),
+                watchdog_period_s=cfg.get("watchdog_period_s"),
+                store_verify_fn=region_verifier(out),
+                schedule=str(cfg.get("block_schedule") or "morton"),
+                # one sharded program per Morton batch when the mesh/sweep
+                # is big enough (docs/PERFORMANCE.md "Sharded sweeps");
+                # bit-identical to per-block dispatch, which stays the
+                # degrade fallback
+                sweep_mode=str(cfg.get("sweep_mode") or "auto"),
+                sharded_batch=cfg.get("sharded_batch"),
+                # HBM-resident page pool for ragged sweeps: pages upload
+                # once, re-address per batch (docs/PERFORMANCE.md
+                # "Device-resident data plane")
+                device_pool=str(cfg.get("device_pool") or "auto"),
+                device_pool_bytes=cfg.get("device_pool_bytes"),
+                # degrade policy: OOM/ENOSPC blocks wait for headroom and
+                # re-execute instead of burning same-size retries.  NEVER
+                # splittable: the label encoding (block_id * (n_outer+1) +
+                # flat index in the STATIC outer block) depends on the outer
+                # shape, so sub-block re-execution could not reproduce the
+                # unsplit labels bit-identically.
+                splittable=False,
+                degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
+                inflight_byte_budget=cfg.get("inflight_byte_budget"),
+            )
+            counters = _pass_counters(
+                summary, todo, outer, use_tiled, overflow_blocks
+            )
+            pass_span.note(
+                lanes=len(todo) + counters["lanes_padded"],
+                **{k: counters[k] for k in (
+                    "dispatches", "padded_voxels", "outer_voxels",
+                    "inner_voxels",
+                )},
+            )
+        return counters
+
     def _store_labels(self, out, block, raw, n_outer, size_dtype=np.uint64):
         """Crop inner region from the padded-outer labels and globalize."""
         inner = raw[block.inner_in_outer_bb]
@@ -260,14 +364,9 @@ class WatershedBase(_WsTaskBase):
         two_d = bool(cfg.get("two_d", False))
         size_filter = int(cfg.get("size_filter") or 0)
         agg_thr = cfg.get("agglomerate_threshold")
-        if agg_thr is not None and cfg.get("pass_parity") is not None:
-            # pass one of the checkerboard: its labels seed pass two, which
-            # cannot agglomerate (see TwoPassWatershedBase) — mixing would
-            # desynchronize the shared label space
-            raise NotImplementedError(
-                "agglomerate_threshold is not supported with pass_parity "
-                "(two-pass checkerboard)"
-            )
+        if parity is not None:
+            # pass one of the checkerboard: its labels seed pass two
+            _refuse_checkerboard_hybrids(cfg)
         # boundary blocks stashed between load and store for the host-side
         # agglomeration (unique keys; dict ops are GIL-atomic across the IO
         # threads)
@@ -294,7 +393,7 @@ class WatershedBase(_WsTaskBase):
             and len(outer) == 3
         )
 
-        def kernel(b, m):
+        def ws_block(b, m):
             if use_tiled:
                 from ..ops.tile_ws import dt_watershed_tiled
 
@@ -379,6 +478,7 @@ class WatershedBase(_WsTaskBase):
             with ThreadPoolExecutor(max(1, self.max_jobs)) as pool:
                 # list() propagates the first worker exception
                 list(pool.map(_host_block, todo))
+            counters = _pass_counters({}, todo, outer, False, overflow_blocks)
         else:
             executor = BlockwiseExecutor(
                 target=self.target,
@@ -388,46 +488,15 @@ class WatershedBase(_WsTaskBase):
                 backoff_base=float(cfg.get("io_backoff_s", 0.05)),
             )
             self._log_execution(executor, impl, use_tiled)
-            executor.map_blocks(
-                kernel,
-                blocks_all,
-                load,
-                store,
-                on_block_done=block_done,
-                done_block_ids=done,
-                validate_fn=validate_labels,
-                failures_path=self.failures_path,
-                task_name=self.uid,
-                block_deadline_s=cfg.get("block_deadline_s"),
-                watchdog_period_s=cfg.get("watchdog_period_s"),
-                store_verify_fn=region_verifier(out),
-                schedule=str(cfg.get("block_schedule") or "morton"),
-                # one sharded program per Morton batch when the mesh/sweep
-                # is big enough (docs/PERFORMANCE.md "Sharded sweeps");
-                # bit-identical to per-block dispatch, which stays the
-                # degrade fallback
-                sweep_mode=str(cfg.get("sweep_mode") or "auto"),
-                sharded_batch=cfg.get("sharded_batch"),
-                # HBM-resident page pool for ragged sweeps: pages upload
-                # once, re-address per batch (docs/PERFORMANCE.md
-                # "Device-resident data plane")
-                device_pool=str(cfg.get("device_pool") or "auto"),
-                device_pool_bytes=cfg.get("device_pool_bytes"),
-                # degrade policy: OOM/ENOSPC blocks wait for headroom and
-                # re-execute instead of burning same-size retries.  NEVER
-                # splittable: the label encoding (block_id * (n_outer+1) +
-                # flat index in the STATIC outer block) depends on the outer
-                # shape, so sub-block re-execution could not reproduce the
-                # unsplit labels bit-identically.
-                splittable=False,
-                degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
-                inflight_byte_budget=cfg.get("inflight_byte_budget"),
+            counters = self._sweep(
+                executor, cfg, ws_block, blocks_all, load, store, block_done,
+                done, out, parity, outer, use_tiled, overflow_blocks,
             )
             device_memory = device_peak_bytes(executor.devices)
         return {
+            **counters,
             "n_blocks": len(block_ids),
             "n_outer": n_outer,
-            "overflow_blocks": sorted(overflow_blocks),
             "device_memory": device_memory,
         }
 
@@ -465,21 +534,7 @@ class TwoPassWatershedBase(_WsTaskBase):
         ) = self._setup()
         if all(h == 0 for h in halo):
             raise ValueError("two-pass watershed requires a nonzero halo")
-        if cfg.get("agglomerate_threshold") is not None:
-            # pass-two labels carry immutable external seed ids from pass
-            # one; merging them blockwise would desynchronize the shared
-            # label space — agglomerate on the single-pass task instead
-            raise NotImplementedError(
-                "agglomerate_threshold is not supported with the two-pass "
-                "watershed"
-            )
-        if cfg.get("two_d"):
-            # pass-one blocks would be segmented per-slice and pass-two in
-            # 3-D: refuse the inconsistent hybrid instead of producing it
-            raise NotImplementedError(
-                "two_d=True is not supported for the two-pass watershed; "
-                "use the single-pass watershed for per-slice segmentation"
-            )
+        _refuse_checkerboard_hybrids(cfg)
         block_ids = [
             b
             for b in block_ids
@@ -492,32 +547,42 @@ class TwoPassWatershedBase(_WsTaskBase):
         kp = self._kernel_params(cfg)
         size_filter = int(cfg.get("size_filter") or 0)
 
-        # per-block external-seed tables, keyed by block id (host side)
+        # per-block external-seed tables, keyed by block id (host side),
+        # and how many external labels each block saw (kept past block_done)
         tables = {}
+        n_ext = {}
 
         def load(block):
             data = pad_block_to(
                 inp[block.outer_bb].astype(np.float32), outer, constant_values=1.0
             )
             prev = pad_block_to(out[block.outer_bb], outer)
-            # keep only voxels owned by even-parity (pass-one) blocks: pass
-            # one is a completed barrier, so those chunks are immutable here —
-            # reading odd-parity neighbors' chunks would race with concurrent
-            # pass-two stores, and diagonal odd blocks must not seed us anyway
-            grids = np.ix_(
-                *(
-                    np.arange(b, b + o) // bs
-                    for b, o, bs in zip(block.outer_begin, prev.shape, block_shape)
+            with trace_mod.span(
+                "ws2.ext_seeds", task=self.uid, block_id=int(block.block_id),
+                nbytes=int(prev.nbytes),
+            ) as ext_span:
+                # keep only voxels owned by even-parity (pass-one) blocks:
+                # pass one is a completed barrier, so those chunks are
+                # immutable here; reading odd-parity neighbors' chunks would
+                # race with concurrent pass-two stores, and diagonal odd
+                # blocks must not seed us anyway
+                grids = np.ix_(
+                    *(
+                        np.arange(b, b + o) // bs
+                        for b, o, bs in zip(
+                            block.outer_begin, prev.shape, block_shape
+                        )
+                    )
                 )
-            )
-            parity = sum(grids) % 2
-            prev = np.where(parity == 0, prev, np.uint64(0))
-            ext_labels = np.unique(prev[prev > 0])
-            dense = np.zeros(outer, np.int32)
-            if len(ext_labels):
-                dense = np.searchsorted(ext_labels, prev).astype(np.int32) + 1
-                dense[prev == 0] = 0
+                prev = np.where(sum(grids) % 2 == 0, prev, np.uint64(0))
+                ext_labels = np.unique(prev[prev > 0])
+                dense = np.zeros(outer, np.int32)
+                if len(ext_labels):
+                    dense = np.searchsorted(ext_labels, prev).astype(np.int32) + 1
+                    dense[prev == 0] = 0
+                ext_span.note(n_ext=len(ext_labels))
             tables[block.block_id] = ext_labels
+            n_ext[block.block_id] = len(ext_labels)
             if mask_ds is not None:
                 m = pad_block_to(mask_ds[block.outer_bb] > 0, outer)
             else:
@@ -527,15 +592,15 @@ class TwoPassWatershedBase(_WsTaskBase):
         impl = str(cfg.get("impl", "auto"))
         if impl == "host":
             # pass one would run scipy while this pass runs the seeded
-            # device kernel — two different flood semantics stitched into
-            # one label space.  Refuse the hybrid (same policy as two_d).
+            # device kernel: two flood semantics stitched into one label
+            # space.  Refuse the hybrid (same policy as two_d).
             raise NotImplementedError(
-                "impl='host' is not supported for two-pass watershed — the "
+                "impl='host' is not supported for two-pass watershed: the "
                 "seeded continuation only exists as a device kernel"
             )
         use_tiled = impl != "legacy" and int(kp.get("connectivity", 1)) == 1
 
-        def kernel(b, ext, m):
+        def ws_block_seeded(b, ext, m):
             if use_tiled:
                 from ..ops.tile_ws import dt_watershed_seeded_tiled
 
@@ -575,16 +640,20 @@ class TwoPassWatershedBase(_WsTaskBase):
             raw = np.asarray(raw)[block.inner_in_outer_bb]
             # peek, don't pop: a store retry must find the table intact
             ext_labels = tables[block.block_id]
-            is_ext = raw > n_outer
-            glob = np.zeros(raw.shape, np.uint64)
-            if is_ext.any():
-                glob[is_ext] = ext_labels[
-                    np.clip(raw[is_ext] - n_outer - 1, 0, len(ext_labels) - 1)
-                ]
-            new = (raw > 0) & ~is_ext
-            glob[new] = np.uint64(block.block_id) * np.uint64(n_outer + 1) + raw[
-                new
-            ].astype(np.uint64)
+            with trace_mod.span(
+                "ws2.relabel", task=self.uid, block_id=int(block.block_id),
+                nbytes=int(raw.nbytes),
+            ):
+                is_ext = raw > n_outer
+                glob = np.zeros(raw.shape, np.uint64)
+                if is_ext.any():
+                    glob[is_ext] = ext_labels[
+                        np.clip(raw[is_ext] - n_outer - 1, 0, len(ext_labels) - 1)
+                    ]
+                new = (raw > 0) & ~is_ext
+                glob[new] = np.uint64(block.block_id) * np.uint64(
+                    n_outer + 1
+                ) + raw[new].astype(np.uint64)
             out[block.bb] = glob
 
         def block_done(block):
@@ -601,34 +670,15 @@ class TwoPassWatershedBase(_WsTaskBase):
             backoff_base=float(cfg.get("io_backoff_s", 0.05)),
         )
         self._log_execution(executor, impl, use_tiled)
-        executor.map_blocks(
-            kernel,
-            blocks_all,
-            load,
-            store,
-            on_block_done=block_done,
-            done_block_ids=done,
-            validate_fn=validate_labels,
-            failures_path=self.failures_path,
-            task_name=self.uid,
-            block_deadline_s=cfg.get("block_deadline_s"),
-            watchdog_period_s=cfg.get("watchdog_period_s"),
-            store_verify_fn=region_verifier(out),
-            schedule=str(cfg.get("block_schedule") or "morton"),
-            sweep_mode=str(cfg.get("sweep_mode") or "auto"),
-            sharded_batch=cfg.get("sharded_batch"),
-            device_pool=str(cfg.get("device_pool") or "auto"),
-            device_pool_bytes=cfg.get("device_pool_bytes"),
-            # same degrade policy as the single-pass task; never splittable
-            # (outer-shape-dependent label encoding, see WatershedBase)
-            splittable=False,
-            degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
-            inflight_byte_budget=cfg.get("inflight_byte_budget"),
+        counters = self._sweep(
+            executor, cfg, ws_block_seeded, blocks_all, load, store,
+            block_done, done, out, 1, outer, use_tiled, overflow_blocks,
         )
         return {
+            **counters,
             "n_blocks": len(block_ids),
             "n_outer": n_outer,
-            "overflow_blocks": sorted(overflow_blocks),
+            "n_ext_labels": sum(n_ext.values()),
             "device_memory": device_peak_bytes(executor.devices),
         }
 
@@ -653,19 +703,9 @@ class WatershedWorkflow(WorkflowBase):
 
         p = dict(self.params)
         two_pass = bool(p.pop("two_pass", False))
-        if two_pass and p.get("two_d"):
-            # reject before pass one burns hours on even blocks — the
-            # two-pass task would refuse anyway (see TwoPassWatershedBase)
-            raise NotImplementedError(
-                "two_d=True is not supported with two_pass=True"
-            )
-        if two_pass and p.get("agglomerate_threshold") is not None:
-            # same altitude as the two_d guard: refuse before pass one runs
-            # (and checkpoints) agglomerated even blocks that pass two would
-            # then mix with un-agglomerated labels
-            raise NotImplementedError(
-                "agglomerate_threshold is not supported with two_pass=True"
-            )
+        if two_pass:
+            # before pass one burns hours on even blocks
+            _refuse_checkerboard_hybrids(p)
         common = dict(
             tmp_folder=self.tmp_folder,
             config_dir=self.config_dir,
@@ -685,5 +725,33 @@ class WatershedWorkflow(WorkflowBase):
         )
         return [t2]
 
+    #: what a pass's manifest says of its sweep (:func:`_pass_counters`)
+    _PASS_KEYS = (
+        "n_blocks", "dispatches", "lanes_padded", "padded_voxels",
+        "outer_voxels", "inner_voxels", "n_ext_labels", "overflow_blocks",
+    )
+
     def run_impl(self):
-        return {}
+        """The passes' counters, gathered from their manifests into the
+        workflow's own and into ``io_metrics.json`` (docs/OBSERVABILITY.md
+        "Two-pass watershed"), keyed by task, first pass first."""
+        task, chain = self.requires()[0], []
+        while task is not None:
+            chain.insert(0, task)
+            task = next(
+                (d for d in task.dependencies if isinstance(d, _WsTaskBase)),
+                None,
+            )
+        passes = {}
+        for task in chain:
+            try:
+                doc = task.output().read()
+            except OSError:
+                doc = {}
+            passes[task.task_name] = {
+                k: doc[k] for k in self._PASS_KEYS if k in doc
+            }
+        fu.record_io_metrics(
+            fu.io_metrics_path(self.tmp_folder), self.uid, {"passes": passes}
+        )
+        return {"passes": passes}

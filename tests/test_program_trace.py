@@ -392,8 +392,10 @@ def test_harvest_metric_is_declared_for_every_cell_with_the_fused_step():
         meta = json.load(f)
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert mine[key] == meta[key]
+    # the three cells of the fused step, then (PR 37) the two-pass cell,
+    # whose blockwise programs run the same harvest under a vmap
     assert mine["workloads"] == ["fused384.volumes", "fused4x384.volumes.sp4",
-                                 "multicut384.volumes"]
+                                 "multicut384.volumes", "twopass125.volumes"]
     assert (mine["unit"], mine["better"], mine["source"]) == ("s", "lower", "device_trace")
     assert (mine["layer"], mine["moves"]) == ("kernels", "voxels_per_s")
     assert meta["stages"] == ["ws.fill.harvest"]
