@@ -205,9 +205,10 @@ def run_capacity_tiered(arrays, n_total, big_cap, core, n_padded,
     and :func:`~cluster_tools_tpu.ops.tile_ws.collect_negative_values`.
     Inline variants of the same 1/16 tier (they need slot-aligned
     scatter-back or shape-independent outputs rather than tail-padding)
-    live in :func:`build_remap_tables` (this module),
-    ``tile_ws.chase_exits``, and ``tile_ws.value_join`` — retune the
-    ratio in ALL of these together.
+    live in :func:`build_remap_tables` (this module) and
+    ``tile_ws.value_join`` — retune the ratio in ALL of these together.
+    (``tile_ws.chase_exits`` has no tier: it walks its live codes in
+    chunks up to their count.)
     """
     small_n = min(big_cap, max(3 * 16384, arrays[0].shape[0] // 16))
 

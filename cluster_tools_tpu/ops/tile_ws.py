@@ -17,9 +17,11 @@ restructures the resolution:
    label, the code of its unseeded in-tile terminal, or an *exit code*
    naming the voxel its path leaves the tile through.
 3. **Exit chase** (XLA, small): unique exit codes are collected from tile
-   boundary strips (capacity-compacted), then chased across tiles by
-   pointer-jumping on arrays of edge size — basins are object-scale, so
-   chains are a few hops.
+   boundary strips (capacity-compacted), then chased across tiles, each
+   code's chain alone, over a compacted list of the chains still running
+   (:func:`chase_exits`): a hop costs what its live chains cost.  Basins
+   are object-scale, so most chains are a few hops and the long tail is a
+   handful of chains.
 4. **Apply**: per-tile value-remap tables (the ops/tile_ccl machinery) or a
    gather fallback.
 5. **Unseeded-basin fill**: instead of ring-growing, basins without seeds
@@ -405,10 +407,10 @@ def value_join(
     that measured ~50x slower than a sort at these sizes on TPU.
 
     Both operands are static-capacity buffers (``BIG``-padded), so the
-    usual 1/16 tier applies, slot-aligned like ``chase_exits``: when the
-    live counts fit, both sides compact, the join runs small, and results
-    scatter back to their query slots (absent/padded queries keep their
-    identity mapping either way).
+    usual 1/16 tier applies, slot-aligned: when the live counts fit, both
+    sides compact, the join runs small, and results scatter back to their
+    query slots (absent/padded queries keep their identity mapping either
+    way).
     """
     nq = query_vals.shape[0]
     nt = table_vals.shape[0]
@@ -467,60 +469,76 @@ def chase_exits(values: jnp.ndarray, codes: jnp.ndarray, max_hops: int = 256):
     ``codes``: negative codes (``BIG``-padded).  Returns ``(finals,
     unconverged)``: the final value each code's chain reaches (a seed label
     (>0), 0, or the unseeded terminal code of its basin), and a flag that is
-    True when a chain exceeded ``max_hops`` (finals then hold intermediate
-    codes — callers must fold this into their overflow report).
+    True when a chain needed more than ``max_hops`` gathers.  Callers must
+    fold the flag into their overflow report: the slots of the chains still
+    running then keep their own codes (every finished chain has its final).
 
-    Per hop the chase gathers ``codes``-many volume entries, and ``codes``
-    is a STATIC capacity buffer — so like the merge cores this tiers: when
-    the runtime active-code count fits 1/16 of the buffer, the chain loop
-    runs on the compacted codes and the finals scatter back to their
-    original slots (identical results — each chain is chased
-    independently).
+    ``codes`` is a STATIC capacity buffer, and a hop costs what its live
+    chains cost, not what the buffer holds.  The live codes (``<= -2``) are
+    compacted once into a list ``(slot, g)`` whose first ``n_live`` entries
+    are chains: the code's slot and the voxel it names.  A hop walks that
+    prefix in chunks of ``cap / 16`` slots under a loop of ``ceil(n_live /
+    chunk)`` trips — one compiled body, the trip count read from the data.
+    A trip gathers ``values[g]`` for its chunk; a chain is finished where the
+    value is no code, or names ``g`` itself (a seedless terminal), and its
+    value goes to its ``slot`` of the output, which starts as ``codes`` so
+    that padding and non-active slots keep their value.  The survivors
+    ``(slot, -value - 2)`` are compacted in place at the running count, which
+    never passes the chunk's own start.  Each chain is chased alone, so the
+    finals are those of a chase of every slot on every hop.  Under ``vmap`` a
+    lane takes the hops and the trips of the lane with most of each.
+
+    Cost on the chip (TPU v5e; PERF.md section 5 has the traced runs): about
+    half the buffer is live on the 384³ step's shard, four fifths of the
+    chains end within four hops, and the tail that sets the hop count is a
+    few thousand chains, then a few dozen: one trip a hop.
     """
     n = values.size
     flat = values.ravel()
-
-    def _core(c):
-        active0 = c <= -2
-        g = jnp.where(active0, -c - 2, 0)
-        val = jnp.where(active0, flat[jnp.clip(g, 0, n - 1)], c)
-
-        def cond(s):
-            _, _, moved, hops = s
-            return moved & (hops < max_hops)
-
-        def body(s):
-            g, val, _, hops = s
-            active = (val <= -2) & (val != -g - 2)
-            g2 = jnp.where(active, -val - 2, g)
-            val2 = jnp.where(active, flat[jnp.clip(g2, 0, n - 1)], val)
-            return g2, val2, jnp.any(active), hops + 1
-
-        g, val, moved, _ = lax.while_loop(
-            cond, body, (g, val, _true_like(g), jnp.int32(0))
-        )
-        return jnp.where(active0, val, c), moved
-
-    # tier selection mirrors tile_ccl.run_capacity_tiered (same 1/16
-    # ratio — retune together) but needs a slot-aligned scatter-back
-    # instead of the helper's tail-padding, and a 1x floor (the input is
-    # one buffer, not a 3-axis concat)
     cap = codes.shape[0]
-    small_n = max(16384, cap // 16)
-    if small_n >= cap:
-        return _core(codes)
+    chunk = -(-cap // 16)
+    (slots, gs), n_live = _compact(
+        codes <= -2, (jnp.arange(cap, dtype=jnp.int32), -codes - 2), cap, 0
+    )
+    # room for 16 whole chunks: the last one's slice never clamps
+    slots, gs = (jnp.pad(x, (0, 16 * chunk - cap)) for x in (slots, gs))
+    offs = jnp.arange(chunk, dtype=jnp.int32)
 
-    def _small(c):
-        (pc, slots), _ = _compact(
-            c <= -2, (c, jnp.arange(cap, dtype=jnp.int32)), small_n, BIG
+    def cond(s):
+        _, n_live, _, hops = s
+        return (n_live > 0) & (hops < max_hops)
+
+    def hop(s):
+        lists, n_live, out, hops = s
+
+        def trip(k, c):
+            lists, n_kept, out = c
+            slot, g = (
+                lax.dynamic_slice(x, (k * chunk,), (chunk,)) for x in lists
+            )
+            live = k * chunk + offs < n_live
+            val = flat[jnp.clip(g, 0, n - 1)]
+            done = live & ((val > -2) | (val == -g - 2))
+            out = scatter_set(out, jnp.where(done, slot, cap), val)
+            packed, n_keep = _compact(
+                live & ~done, (slot, -val - 2), chunk, 0
+            )
+            lists = tuple(
+                lax.dynamic_update_slice(buf, x, (n_kept,))
+                for buf, x in zip(lists, packed)
+            )
+            return lists, n_kept + n_keep, out
+
+        lists, n_kept, out = lax.fori_loop(
+            0, (n_live + chunk - 1) // chunk, trip,
+            (lists, jnp.zeros_like(n_live), out),
         )
-        fin_s, moved = _core(pc)
-        # non-active codes map to themselves; padded slots (BIG) drop
-        out = scatter_set(c, slots, fin_s)
-        return out, moved
+        return lists, n_kept, out, hops + 1
 
-    n_active = (codes <= -2).sum()
-    return lax.cond(n_active <= small_n, _small, _core, codes)
+    _, n_live, finals, _ = lax.while_loop(
+        cond, hop, ((slots, gs), n_live, codes, jnp.int32(0))
+    )
+    return finals, n_live > 0
 
 
 def _resolve_codes_gather(values: jnp.ndarray, codes, finals) -> jnp.ndarray:

@@ -401,6 +401,98 @@ def test_harvest_metric_is_declared_for_every_cell_with_the_fused_step():
     assert meta["stages"] == ["ws.fill.harvest"]
 
 
+# -- PR 38's reader: the exit chase, hop by hop ---------------------------------
+
+CHASE = STEP + "ws.flow/ws.flow.chase/"
+#: the parent's program: a first gather of every slot, then one loop whose
+#: every hop gathers every slot again (inside the tier's conditional)
+CHASE_FULL_WIDTH_OPS = (
+    ("conditional.50", "conditional", 16.0, 3.0, ""),
+    ("fusion.51", "fusion", 16.0, 0.5, CHASE + "cond/branch_1_fun/gather:"),
+    ("while.52", "while", 16.5, 2.5, ""),
+) + tuple(("fusion.53", "fusion", 16.5 + 0.5 * i, 0.5,
+           CHASE + "cond/branch_1_fun/while/body/gather:") for i in range(5))
+#: the change's: the live codes compacted once, then three hops of 3, 2 and 1
+#: trips; the last trip holds a loop over lanes, as a program under vmap does
+CHASE_HOP_OPS = (
+    ("fusion.60", "fusion", 16.0, 0.25, CHASE + "scatter:"),
+    ("while.61", "while", 16.25, 3.0, ""),
+    ("while.62", "while", 16.25, 1.5, ""),
+) + tuple(x for i in range(3) for x in (
+    ("fusion.63", "fusion", 16.25 + 0.5 * i, 0.25, CHASE + "while/body/while/body/gather:"),
+    ("fusion.64", "fusion", 16.5 + 0.5 * i, 0.25, CHASE + "while/body/while/body/scatter:"),
+)) + (
+    ("while.62", "while", 17.75, 1.0, ""),
+) + tuple(x for i in range(2) for x in (
+    ("fusion.63", "fusion", 17.75 + 0.5 * i, 0.25, CHASE + "while/body/while/body/gather:"),
+    ("fusion.64", "fusion", 18.0 + 0.5 * i, 0.25, CHASE + "while/body/while/body/scatter:"),
+)) + (
+    ("while.62", "while", 18.75, 0.5, ""),
+    ("fusion.63", "fusion", 18.75, 0.125, CHASE + "while/body/while/body/gather:"),
+    ("while.65", "while", 18.875, 0.25, ""),
+) + tuple(("fusion.66", "fusion", 18.875 + 0.0625 * i, 0.0625,
+           CHASE + "while/body/while/body/dynamic_slice:") for i in range(4)) + (
+    ("fusion.64", "fusion", 19.125, 0.125, CHASE + "while/body/while/body/scatter:"),
+)
+
+
+def _chase_lines(capfd):
+    return [line for line in capfd.readouterr().err.splitlines()
+            if line.startswith("[ws_flow_chase]")]
+
+
+def test_chase_reader_lists_the_parents_loop_at_the_full_width(traced, capfd):
+    traced = _with_fill_ops(traced, CHASE_FULL_WIDTH_OPS)
+    assert _read_metric("ws_flow_chase_device_s", traced) == pytest.approx(3.0)
+    listed = _chase_lines(capfd)
+    assert len(listed) == 2
+    assert "loop 1: while.52 5 hops at the full width  2.500s  (0.5000s a hop)" in listed[0]
+    assert "loops 2.500s, outside them 0.500s" in listed[1]
+    # the stage keeps the whole flow: propagate 3.0, the exits' sort 1.0, the chase
+    assert _read_metric("ws_flow_device_s", traced) == pytest.approx(7.0)
+
+
+def test_chase_reader_lists_each_hop_with_its_trips(traced, capfd):
+    traced = _with_fill_ops(traced, CHASE_HOP_OPS)
+    assert _read_metric("ws_flow_chase_device_s", traced) == pytest.approx(3.25)
+    listed = _chase_lines(capfd)
+    assert len(listed) == 5
+    assert "loop 1: while.61 3 hops  3.000s  6 trips" in listed[0]
+    assert "hop 1: while.62 x3 of 16  1.500s" in listed[1]
+    assert "hop 2: while.62 x2 of 16  1.000s" in listed[2]
+    assert "hop 3: while.62 x1 of 16  0.500s" in listed[3]
+    assert "loops 3.000s, outside them 0.250s" in listed[4]
+    assert _read_metric("ws_flow_device_s", traced) == pytest.approx(7.25)
+
+
+@pytest.mark.parametrize("which", ["a_program_without_the_scope", "selfcheck"])
+def test_chase_reader_returns_nothing_without_the_scope(traced, which):
+    """A program from before the stage names' inner scopes has ``ws.flow``
+    and no ``ws.flow.chase``; ``selfcheck``'s trace has no stage at all."""
+    assert _read_metric("ws_flow_chase_device_s", _selfcheck_traced()
+                        if which == "selfcheck" else traced) is None
+
+
+def test_chase_metric_is_declared_for_every_cell():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (mine,) = [m for m in bench["per_layer"] if m["name"] == "ws_flow_chase_device_s"]
+    assert bench["per_layer"][-1] is mine
+    with open(os.path.join(ROOT, "benchmark", "metrics",
+                           "ws_flow_chase_device_s.json")) as f:
+        meta = json.load(f)
+    for key in ("name", "unit", "better", "source", "layer", "moves"):
+        assert mine[key] == meta[key]
+    # every watershed runs the chase: the fused step's three cells and the
+    # two-pass cell's blockwise programs, there under a vmap over lanes
+    assert mine["workloads"] == [w["name"] for w in bench["workloads"]][:4] == [
+        "fused384.volumes", "fused4x384.volumes.sp4", "multicut384.volumes",
+        "twopass125.volumes"]
+    assert (mine["unit"], mine["better"], mine["source"]) == ("s", "lower", "device_trace")
+    assert (mine["layer"], mine["moves"]) == ("kernels", "voxels_per_s")
+    assert meta["stages"] == ["ws.flow.chase"]
+
+
 # -- PR 34's reader: the compiled step read back from the step store -----------
 
 

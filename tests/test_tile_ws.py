@@ -143,52 +143,136 @@ def test_overflow_flag(rng, monkeypatch):
     jax.clear_caches()
 
 
+# The chase walks a compacted, shrinking list of live chains in chunks of
+# cap / 16 slots (one path for every buffer size), so the cases below pick
+# the live count against the chunk (cap 1000 -> chunk 63, 16 chunks = 1008
+# slots: the lists' padding is exercised too) and the chains against the hops.
+
+_CHASE_CAP = 1000
+_CHASE_LONGEST = 42  # gathers the tail chain of _chase_volume("tail") needs
+
+
+def _chase_volume(chains):
+    """A 4096-voxel volume of acyclic chains, flat.  The top 512 voxels are
+    finals: labels, 0, and seedless terminals that name themselves.
+    ``flat``: every other voxel holds a label (all chains end at the first
+    gather).  ``tail``: voxels point 512 ahead (at most 8 gathers) but for
+    one chain of 41 links from voxel 0 on."""
+    n = 4096
+    values = np.zeros(n, np.int32)
+    for g in range(3584, n):
+        values[g] = (0, (g % 97) + 1, -g - 2)[g % 3]
+    for g in range(3584):
+        values[g] = (g % 89) + 1 if chains == "flat" else -(g + 512) - 2
+    if chains == "tail":
+        for g in range(40):
+            values[g] = -(g + 1) - 2
+        values[40] = -4000 - 2  # voxel 4000 holds a label
+    return values
+
+
+def _chase_codes(values, n_live, seed, first_voxel=None):
+    """``cap`` slots: ``n_live`` codes at random slots, the rest ``BIG``
+    padding and some -1 (neither is a chain)."""
+    from cluster_tools_tpu.ops.tile_ws import BIG
+
+    rng_ = np.random.default_rng(seed)
+    codes = np.full(_CHASE_CAP, BIG, np.int32)
+    codes[rng_.choice(_CHASE_CAP, 50, replace=False)] = -1
+    live = rng_.choice(_CHASE_CAP, n_live, replace=False)
+    codes[live] = -(rng_.integers(64, values.size, size=n_live) + 2)
+    if first_voxel is not None and n_live:
+        codes[live[0]] = -first_voxel - 2
+    return codes
+
+
+def _chase_oracle(values, codes, max_hops=None):
+    """(finals, gathers of the longest chain): each live code followed alone
+    in numpy; a chain past ``max_hops`` gathers keeps its code."""
+    finals, longest = codes.copy(), 0
+    for i, code in enumerate(codes):
+        if code > -2:
+            continue
+        g, hops = -code - 2, 1
+        while values[g] <= -2 and values[g] != -g - 2:
+            g, hops = -values[g] - 2, hops + 1
+        longest = max(longest, hops)
+        if max_hops is None or hops <= max_hops:
+            finals[i] = values[g]
+    return finals, longest
+
+
+@pytest.mark.parametrize("chains", ["flat", "tail"])
+@pytest.mark.parametrize("n_live", [0, 1, 40, 200, _CHASE_CAP])
+def test_chase_exits_matches_oracle(n_live, chains):
+    """Finals slot for slot against a numpy chain-following oracle: no live
+    code, one, under a chunk, several chunks with a ragged last one, every
+    slot live; all chains done at the first gather, and one chain far longer
+    than the rest (hops of one trip after the others' few)."""
+    from cluster_tools_tpu.ops.tile_ws import chase_exits
+
+    values = _chase_volume(chains)
+    codes = _chase_codes(
+        values, n_live, seed=n_live, first_voxel=0 if chains == "tail" else None
+    )
+    want, longest = _chase_oracle(values, codes)
+    assert longest == (0 if n_live == 0 else
+                       1 if chains == "flat" else _CHASE_LONGEST)
+    finals, unconverged = chase_exits(
+        jnp.asarray(values.reshape(16, 16, 16)), jnp.asarray(codes)
+    )
+    assert not bool(unconverged)
+    # the live slots' finals, and padding / non-active slots untouched
+    np.testing.assert_array_equal(np.asarray(finals), want)
+
+
+@pytest.mark.parametrize("max_hops,flag", [(_CHASE_LONGEST - 1, True),
+                                           (_CHASE_LONGEST, False)])
+def test_chase_exits_unconverged_flag(max_hops, flag):
+    """A chain that needs more than ``max_hops`` gathers raises the flag and
+    keeps its code; every chain that ended has its final."""
+    from cluster_tools_tpu.ops.tile_ws import chase_exits
+
+    values = _chase_volume("tail")
+    codes = _chase_codes(values, 200, seed=7, first_voxel=0)
+    want, longest = _chase_oracle(values, codes, max_hops=max_hops)
+    assert longest == _CHASE_LONGEST
+    finals, unconverged = chase_exits(
+        jnp.asarray(values.reshape(16, 16, 16)), jnp.asarray(codes),
+        max_hops=max_hops,
+    )
+    assert bool(unconverged) == flag
+    np.testing.assert_array_equal(np.asarray(finals), want)
+
+
+def test_chase_exits_lanes_match_alone():
+    """Under ``vmap`` (the blockwise executor's lanes) every lane takes the
+    hops and trips of the lane with most: lanes with different live counts
+    and chain lengths, one of them empty, equal the lanes run alone."""
+    from cluster_tools_tpu.ops.tile_ws import chase_exits
+
+    lanes = [("tail", 200, 0), ("flat", _CHASE_CAP, None), ("flat", 0, None),
+             ("tail", 40, None)]
+    values = np.stack([_chase_volume(c) for c, _, _ in lanes])
+    codes = np.stack([
+        _chase_codes(v, n_live, seed=i, first_voxel=first)
+        for i, (v, (_, n_live, first)) in enumerate(zip(values, lanes))
+    ])
+    finals, unconverged = jax.vmap(chase_exits)(
+        jnp.asarray(values.reshape(-1, 16, 16, 16)), jnp.asarray(codes)
+    )
+    assert not np.asarray(unconverged).any()
+    for lane, (v, c) in enumerate(zip(values, codes)):
+        alone, _ = chase_exits(jnp.asarray(v.reshape(16, 16, 16)), jnp.asarray(c))
+        np.testing.assert_array_equal(np.asarray(finals)[lane], np.asarray(alone))
+        np.testing.assert_array_equal(np.asarray(alone), _chase_oracle(v, c)[0])
+
+
 # The capacity tiers choose at run time between one machine at two sizes, so
 # a caller can never see which ran (tests/test_tile_ccl.py has the merge's and
 # the remap tables').  Each site below is driven with buffers large enough
 # that it really tiers, once with a live count that fits the small tier and
 # once with one that does not.
-
-
-@pytest.mark.parametrize("n_active", [512, 20000])
-def test_chase_exits_small_tier_matches_oracle(rng, n_active):
-    """The chase's small tier (compact -> chase -> scatter-back) only
-    engages for capacity buffers > 16*16384, which no workflow test
-    reaches — drive it directly against a numpy chain-following oracle."""
-    from cluster_tools_tpu.ops.tile_ws import BIG, chase_exits
-
-    n = 4096
-    values = np.zeros(n, np.int32)
-    # deterministic ACYCLIC chains: indices below 3584 point 512 ahead
-    # (<= 8 hops to a terminal), the top 512 hold labels (>0) or 0
-    for g in range(3584):
-        values[g] = -(g + 512 + 2)
-    for g in range(3584, n):
-        values[g] = 0 if g % 3 == 0 else (g % 97) + 1
-    cap = 16 * 16384 + 1024  # force small_n < cap -> tiered path
-    # small_n = cap // 16 = 16448: 512 codes take the small tier, 20000 the big
-    rng_ = np.random.default_rng(0)
-    codes = np.full(cap, BIG, np.int32)
-    codes[:n_active] = -(rng_.integers(0, n, size=n_active) + 2)
-
-    import jax.numpy as jnp
-
-    finals, unconverged = chase_exits(
-        jnp.asarray(values.reshape(16, 16, 16)), jnp.asarray(codes)
-    )
-    finals = np.asarray(finals)
-    assert not bool(unconverged)
-
-    def oracle(code):
-        val = values[-code - 2]
-        while val <= -2:
-            val = values[-val - 2]
-        return val
-
-    for i in range(n_active):
-        assert finals[i] == oracle(codes[i]), i
-    # padding and non-active slots unchanged
-    np.testing.assert_array_equal(finals[n_active:], codes[n_active:])
 
 
 @pytest.mark.parametrize("n_t,n_q", [(300, 500), (300, 20000), (20000, 500)])
