@@ -2558,7 +2558,7 @@ def main():
             # pre_impl is never "legacy" here (the legacy rung skips the
             # pre-pass), so this is always the tiled path
             fg3 = (vol < threshold)[0]
-            cc1 = jax.jit(lambda m: label_components_tiled(m, impl=pre_impl))
+            cc1 = jax.jit(lambda m: label_components_tiled(m, impl=pre_impl)[:2])
             t_cc, (_, cc_ovf) = _timeit(
                 "config 1: tiled CCL on binary mask", cc1, fg3
             )
@@ -2579,7 +2579,7 @@ def main():
                 lambda b: dt_watershed_tiled(
                     b, threshold=threshold, dt_max_distance=float(halo),
                     min_seed_distance=min_seed_distance, impl=pre_impl,
-                )
+                )[:2]
             )
             t_ws, (ws_lab1, ws_ovf) = _timeit(
                 "config 2: fused DT watershed", ws1, vol[0]
@@ -2732,7 +2732,7 @@ def main():
                 out0 = step(vol)
                 _sync(out0)
         t_fused, out = _timeit("fused ws+ccl step", step, vol)
-        ws_lab, cc_lab, n_fg, overflow = out
+        ws_lab, cc_lab, n_fg, overflow, _ = out
         n_fg = int(n_fg)
         overflow = bool(overflow)
         vps = vol.size / t_fused
@@ -2773,7 +2773,7 @@ def main():
                 cc1 = jax.jit(lambda m: (label_components(m), False))
             else:
                 cc1 = jax.jit(
-                    lambda m: label_components_tiled(m, impl=sub_impl)
+                    lambda m: label_components_tiled(m, impl=sub_impl)[:2]
                 )
             t_cc, (_, cc_ovf) = _timeit(
                 "config 1: tiled CCL on binary mask", cc1, fg3
@@ -2811,7 +2811,7 @@ def main():
                     lambda b: dt_watershed_tiled(
                         b, threshold=threshold, dt_max_distance=float(halo),
                         min_seed_distance=min_seed_distance, impl=sub_impl,
-                    )
+                    )[:2]
                 )
             t_ws, (_, ws_ovf) = _timeit(
                 "config 2: fused DT watershed", ws1, vol[0]

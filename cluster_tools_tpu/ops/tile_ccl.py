@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import work
 from .ccl import _match_vma, _shift, _true_like, label_components
 
 BIG = 2**30  # background sentinel during the padded/tiled phase
@@ -270,20 +271,22 @@ def merge_face_pairs(
     """Union-find closure over tile-face equivalences.
 
     ``labels``: per-tile global-flat-index labels (``BIG`` background).
-    Returns ``(ea, eb, root_a, root_b, n_edges, overflow)`` where ``ea/eb``
-    are the deduped edge endpoints (label values, ``BIG``-padded) and
-    ``root_a/root_b`` their final merged roots.  ``overflow`` is True when a
-    capacity was exceeded or the union-find hit ``max_rounds`` unconverged
-    (labels would be under-merged — callers re-run with bigger caps or fall
-    back).
+    Returns ``(ea, eb, root_a, root_b, n_edges, overflow, counts)`` where
+    ``ea/eb`` are the deduped edge endpoints (label values, ``BIG``-padded)
+    and ``root_a/root_b`` their final merged roots.  ``overflow`` is True
+    when a capacity was exceeded or the union-find hit ``max_rounds``
+    unconverged (labels would be under-merged — callers re-run with bigger
+    caps or fall back); ``counts`` is the merge's part of the work record
+    (:mod:`.work`): the fullest axis's pairs, the edges, and the flag split
+    by what tripped.
     """
     pair_lists = []
-    overflow = _match_vma(jnp.zeros((), jnp.int32), labels)
+    most_pairs = _match_vma(jnp.zeros((), jnp.int32), labels)
     n_total = _match_vma(jnp.zeros((), jnp.int32), labels)
     for axis in range(3):
         (pa, pb), kept = _face_pairs_axis(labels, tile, axis, pair_cap)
         pair_lists.append((pa, pb))
-        overflow = jnp.maximum(overflow, (kept > pair_cap).astype(jnp.int32))
+        most_pairs = jnp.maximum(most_pairs, kept)
         n_total = n_total + jnp.minimum(kept, pair_cap)
     # the concat inherits the labels' varying-manual-axes type even when every
     # axis had a single tile (all-constant empty pair lists) — required for
@@ -291,23 +294,29 @@ def merge_face_pairs(
     a = _match_vma(jnp.concatenate([p[0] for p in pair_lists]), labels)
     b = _match_vma(jnp.concatenate([p[1] for p in pair_lists]), labels)
 
-    ea, eb, root_a, root_b, n_edges, core_ovf = run_capacity_tiered(
-        (a, b), n_total, edge_cap, _merge_core, 4, max_rounds, labels
+    ea, eb, root_a, root_b, n_edges, edge_over, unconverged = (
+        run_capacity_tiered(
+            (a, b), n_total, edge_cap, _merge_core, 4, max_rounds, labels
+        )
     )
-    overflow = jnp.maximum(overflow, core_ovf)
-    return ea, eb, root_a, root_b, n_edges, overflow > 0
+    counts = {
+        work.CCL_PAIRS: most_pairs, work.CCL_EDGES: n_edges,
+        work.OVER_EDGE: (most_pairs > pair_cap) | edge_over,
+        work.OVER_ROUNDS: unconverged,
+        work.CAP_PAIR: pair_cap, work.CAP_EDGE: edge_cap,
+    }
+    return ea, eb, root_a, root_b, n_edges, work.any_over(counts), counts
 
 
 def _merge_core(a, b, edge_cap, max_rounds, vma_like):
     """Dedup + dense-id union-find over one capacity tier; outputs sized
-    ``edge_cap`` (``BIG``-padded), overflow as int32."""
-    overflow = _match_vma(jnp.zeros((), jnp.int32), vma_like)
+    ``edge_cap`` (``BIG``-padded), then the edge count, whether it passed
+    the tier's capacity and whether the union-find ran out of rounds."""
     # value-dedup: one small sort, duplicates & padding end up adjacent/last
     a, b = lax.sort((a, b), num_keys=2)
     dup = (a == _shift1(a, 0, -1)) & (b == _shift1(b, 0, -1))
     keep = (~dup) & (a < BIG)
     (ea, eb), n_edges = _compact(keep, (a, b), edge_cap, BIG)
-    overflow = jnp.maximum(overflow, (n_edges > edge_cap).astype(jnp.int32))
 
     # compact endpoint labels to dense ids so the union-find's parent table
     # is edge-sized, not volume-sized: full pointer-doubling per round then
@@ -346,13 +355,13 @@ def _merge_core(a, b, edge_cap, max_rounds, vma_like):
         cond, body, (parent, _true_like(da), jnp.int32(0))
     )
     # a max_rounds exit leaves edges with differing roots: report, never hide
-    overflow = jnp.maximum(overflow, unconverged.astype(jnp.int32))
+    # (the caller folds both flags into its overflow)
     # map dense roots back to label values
     root_a = uniq[parent[da]]
     root_b = uniq[parent[db]]
     root_a = jnp.where(ea < BIG, root_a, jnp.int32(BIG))
     root_b = jnp.where(eb < BIG, root_b, jnp.int32(BIG))
-    return ea, eb, root_a, root_b, n_edges, overflow
+    return ea, eb, root_a, root_b, n_edges, n_edges > edge_cap, unconverged
 
 
 def _tile_id_of(v: jnp.ndarray, shape, tile) -> jnp.ndarray:
@@ -376,8 +385,10 @@ def build_remap_tables(
 
     ``tile_ids``: which tile each entry belongs to (``BIG`` = drop the
     entry); duplicates of (tile, old) collapse to one slot.  Returns
-    ``(old_tbl, new_tbl, overflow)`` with tables shaped
-    ``(n_tiles, table_cap)``; unused slots hold -1.
+    ``(old_tbl, new_tbl, overflow, tile_max)`` with tables shaped
+    ``(n_tiles, table_cap)``, unused slots holding -1; ``tile_max`` is the
+    number of entries of the fullest tile, and ``overflow`` says that it
+    passed ``table_cap`` (the caller then resolves by a gather instead).
 
     The sort runs at the static input size; table shapes don't depend on
     it, so the usual 1/16 capacity tier applies with no scatter-back —
@@ -410,7 +421,7 @@ def _remap_tables_core(tile_ids, old_vals, new_vals, n_tiles, table_cap):
     is_first = (tid != _shift1(tid, 0, -1)) & (tid < BIG)
     base = lax.cummax(jnp.where(is_first, cnt - valid.astype(jnp.int32), -1))
     slot = jnp.where(valid, cnt - 1 - base, table_cap)
-    overflow = jnp.any(valid & (slot >= table_cap))
+    tile_max = jnp.max(jnp.where(valid, slot + 1, 0))
     dest = jnp.where(valid & (slot < table_cap), tid * table_cap + slot,
                      n_tiles * table_cap)
     old_tbl = jnp.full((n_tiles * table_cap + 1,), jnp.int32(-1))
@@ -420,7 +431,8 @@ def _remap_tables_core(tile_ids, old_vals, new_vals, n_tiles, table_cap):
     return (
         old_tbl[:-1].reshape(n_tiles, table_cap),
         new_tbl[:-1].reshape(n_tiles, table_cap),
-        overflow,
+        tile_max > table_cap,
+        tile_max,
     )
 
 
@@ -457,8 +469,8 @@ def label_components_tiled(
     edge_cap: Optional[int] = None,
     table_cap: int = DEFAULT_TABLE_CAP,
     interpret: bool = False,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Two-level CCL of a 3-D bool mask.
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Two-level CCL of a 3-D bool mask: ``(labels, overflow, work)``.
 
     Same output contract as :func:`~cluster_tools_tpu.ops.ccl.label_components`
     — int32, foreground = flat index (in ``mask``'s own shape) of a canonical
@@ -474,6 +486,10 @@ def label_components_tiled(
     neighborhood use the legacy kernel.  Capacities default to volume-scaled
     values (static, shape-derived); pass explicit caps for workloads with
     unusually many fragments per tile face.
+
+    ``work`` is the program's work record (:mod:`.work`, names ``ccl.*``):
+    the merge's counts and, where the Mosaic remap kernel can run, the
+    fullest tile's table entries and whether the gather ran in its place.
     """
     if mask.ndim != 3:
         raise ValueError("label_components_tiled expects a 3-D mask")
@@ -511,9 +527,10 @@ def label_components_tiled(
 
     # second level: equivalences across tile faces, solved and applied
     with jax.named_scope("ccl.merge"):
-        ea, eb, root_a, root_b, n_edges, overflow = merge_face_pairs(
+        ea, eb, root_a, root_b, n_edges, overflow, counts = merge_face_pairs(
             labels, tile, pair_cap=pair_cap, edge_cap=edge_cap
         )
+        counts[work.CAP_TABLE] = table_cap
 
         if impl == "pallas":
             n_tiles = (zp // tz) * (yp // ty) * (xp // tx)
@@ -523,9 +540,11 @@ def label_components_tiled(
             tids = jnp.where(
                 changed, _tile_id_of(v, (zp, yp, xp), tile), jnp.int32(BIG)
             )
-            old_tbl, new_tbl, tbl_overflow = build_remap_tables(
+            old_tbl, new_tbl, tbl_overflow, tile_max = build_remap_tables(
                 tids, v, r, n_tiles, table_cap=table_cap
             )
+            counts[work.CCL_REMAP_TILE_MAX] = tile_max
+            counts[work.CCL_REMAP_FALLBACK] = tbl_overflow
 
             def fast(args):
                 labels, old_tbl, new_tbl = args
@@ -553,4 +572,4 @@ def label_components_tiled(
             out = jnp.where(resolved >= BIG, jnp.int32(n_orig), orig)
         else:
             out = jnp.where(resolved >= BIG, jnp.int32(n_orig), resolved)
-    return out, overflow
+    return out, overflow, work.pack(counts)

@@ -48,7 +48,7 @@ differs.
 from __future__ import annotations
 
 import os
-from functools import partial
+from functools import partial, reduce
 from typing import Optional, Tuple
 
 import jax
@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import work
 from .ccl import _match_vma, _shift, _true_like
 from .pallas_kernels import WS_OFFS
 from .tile_ccl import (
@@ -350,11 +351,13 @@ def collect_negative_values(
 
     Every cross-tile fragment touches a boundary strip of each tile it
     occupies, so this covers all (tile, value) incidences needed for exits
-    and fill remaps.  Returns ``(vals, tids, overflow)``.
+    and fill remaps.  Returns ``(vals, tids, overflow, n_kept, family_max)``:
+    the deduped pairs, whether they or one strip family passed ``cap``, how
+    many pairs there are and how many entries the fullest family held.
     """
     vs, ts = [], []
     overflow = _match_vma(jnp.zeros((), jnp.int32), values)
-    n_total = overflow
+    n_total = family_max = overflow
     for axis in range(3):
         for side in (0, 1):
             sl, tid = _strip_entries(values, tile, axis, side)
@@ -374,6 +377,7 @@ def collect_negative_values(
                 overflow, (kept > fam_cap).astype(jnp.int32)
             )
             n_total = n_total + jnp.minimum(kept, fam_cap)
+            family_max = jnp.maximum(family_max, kept)
             vs.append(v)
             ts.append(t_)
     v = jnp.concatenate(vs)
@@ -386,7 +390,7 @@ def collect_negative_values(
         (v, t_), n_total, cap, _collect_core, 2, 0, values
     )
     overflow = jnp.maximum(overflow, (n_kept > cap).astype(jnp.int32))
-    return cv, ct, overflow > 0
+    return cv, ct, overflow > 0, n_kept, family_max
 
 
 def _collect_core(v, t_, cap, _max_rounds, _vma_like):
@@ -467,9 +471,11 @@ def chase_exits(values: jnp.ndarray, codes: jnp.ndarray, max_hops: int = 256):
     """Resolve exit codes by following values across tiles.
 
     ``codes``: negative codes (``BIG``-padded).  Returns ``(finals,
-    unconverged)``: the final value each code's chain reaches (a seed label
-    (>0), 0, or the unseeded terminal code of its basin), and a flag that is
-    True when a chain needed more than ``max_hops`` gathers.  Callers must
+    unconverged, counts)``: the final value each code's chain reaches (a seed
+    label (>0), 0, or the unseeded terminal code of its basin), a flag that
+    is True when a chain needed more than ``max_hops`` gathers, and the
+    chase's part of the work record (:mod:`.work`): hops, the hops' trips
+    and live chains summed, the hops of one trip.  Callers must
     fold the flag into their overflow report: the slots of the chains still
     running then keep their own codes (every finished chain has its final).
 
@@ -486,7 +492,8 @@ def chase_exits(values: jnp.ndarray, codes: jnp.ndarray, max_hops: int = 256):
     ``(slot, -value - 2)`` are compacted in place at the running count, which
     never passes the chunk's own start.  Each chain is chased alone, so the
     finals are those of a chase of every slot on every hop.  Under ``vmap`` a
-    lane takes the hops and the trips of the lane with most of each.
+    lane takes the hops and the trips of the lane with most of each, and its
+    counts are those of the lane run alone.
 
     Cost on the chip (TPU v5e; PERF.md section 5 has the traced runs): about
     half the buffer is live on the 384³ step's shard, four fifths of the
@@ -505,11 +512,12 @@ def chase_exits(values: jnp.ndarray, codes: jnp.ndarray, max_hops: int = 256):
     offs = jnp.arange(chunk, dtype=jnp.int32)
 
     def cond(s):
-        _, n_live, _, hops = s
+        _, n_live, _, (hops, _, _, _) = s
         return (n_live > 0) & (hops < max_hops)
 
     def hop(s):
-        lists, n_live, out, hops = s
+        lists, n_live, out, (hops, trips, live, tail_hops) = s
+        n_trips = (n_live + chunk - 1) // chunk
 
         def trip(k, c):
             lists, n_kept, out = c
@@ -530,15 +538,22 @@ def chase_exits(values: jnp.ndarray, codes: jnp.ndarray, max_hops: int = 256):
             return lists, n_kept + n_keep, out
 
         lists, n_kept, out = lax.fori_loop(
-            0, (n_live + chunk - 1) // chunk, trip,
-            (lists, jnp.zeros_like(n_live), out),
+            0, n_trips, trip, (lists, jnp.zeros_like(n_live), out),
         )
-        return lists, n_kept, out, hops + 1
+        return lists, n_kept, out, (
+            hops + 1, trips + n_trips,
+            live + work.scaled(n_live, work.FLOW_CHASE_LIVE),
+            tail_hops + (n_trips == 1),
+        )
 
-    _, n_live, finals, _ = lax.while_loop(
-        cond, hop, ((slots, gs), n_live, codes, jnp.int32(0))
+    zero = jnp.zeros_like(n_live)
+    _, n_left, finals, (hops, trips, live, tail_hops) = lax.while_loop(
+        cond, hop, ((slots, gs), n_live, codes, (zero, zero, zero, zero))
     )
-    return finals, n_live > 0
+    return finals, n_left > 0, {
+        work.FLOW_CHASE_HOPS: hops, work.FLOW_CHASE_TRIPS: trips,
+        work.FLOW_CHASE_LIVE: live, work.FLOW_CHASE_TAIL_HOPS: tail_hops,
+    }
 
 
 def _resolve_codes_gather(values: jnp.ndarray, codes, finals) -> jnp.ndarray:
@@ -562,9 +577,11 @@ def fill_unseeded_basins(
     """Merge unseeded basins across their lowest saddles (Boruvka rounds).
 
     ``labels``: >0 seeded basin label, <= -2 unseeded basin code, 0 invalid.
-    Returns ``(edge_vals, edge_finals, overflow)`` — the remap (old basin
-    code -> final label, 0 if unreachable) for every unseeded basin seen on
-    a boundary, for the caller to apply.
+    Returns ``(edge_vals, edge_finals, overflow, counts)`` — the remap (old
+    basin code -> final label, 0 if unreachable) for every unseeded basin
+    seen on a boundary, for the caller to apply, and the fill's part of the
+    work record (:mod:`.work`): faces per axis, adjacencies, the flag split
+    by what tripped.
 
     Cost structure (r4, full story in docs/PERFORMANCE.md): face-voxel
     collection keeps the generous ``fill_cap`` (noise robustness); the
@@ -581,7 +598,7 @@ def fill_unseeded_basins(
         max_rounds = _auto_fill_rounds(labels.size)
     h = height.astype(jnp.float32)
     evs_a, evs_b, evs_h = [], [], []
-    overflow = _match_vma(jnp.zeros((), jnp.int32), labels)
+    counts = {}
     n_total = _match_vma(jnp.zeros((), jnp.int32), labels)
     for axis in range(3):
         na = labels.shape[axis]
@@ -596,7 +613,7 @@ def fill_unseeded_basins(
             (a != _shift1(a, dedup_axis, 0)) | (b != _shift1(b, dedup_axis, 0))
         )
         (pa, pb, ph), kept = _compact(keep, (a, b, saddle), fill_cap, BIG)
-        overflow = jnp.maximum(overflow, (kept > fill_cap).astype(jnp.int32))
+        counts[work.FILL_FACES[axis]] = kept
         n_total = n_total + jnp.minimum(kept, fill_cap)
         evs_a.append(pa)
         evs_b.append(pb)
@@ -626,11 +643,17 @@ def fill_unseeded_basins(
     # common case runs the whole dedup+Boruvka machine at 1/16 size
     # (rationale + the shared threshold live in
     # tile_ccl.run_capacity_tiered).
-    edge_vals, edge_finals, core_overflow = run_capacity_tiered(
+    edge_vals, edge_finals, n_adj, adj_over, unconverged = run_capacity_tiered(
         (a, b, hk), n_total, adj_cap, _fill_core, 2, max_rounds, labels
     )
-    overflow = jnp.maximum(overflow, core_overflow)
-    return edge_vals, edge_finals, overflow > 0
+    counts.update({
+        work.FILL_ADJACENCIES: n_adj,
+        work.OVER_FACE: reduce(
+            jnp.logical_or, [counts[f] > fill_cap for f in work.FILL_FACES]),
+        work.OVER_ADJ: adj_over, work.OVER_ROUNDS: unconverged,
+        work.CAP_FACE: fill_cap, work.CAP_ADJ: adj_cap,
+    })
+    return edge_vals, edge_finals, work.any_over(counts), counts
 
 
 def fill_unseeded_basins_dense(
@@ -722,11 +745,13 @@ def fill_unseeded_basins_dense(
     touches one adopts -1, which the caller's final ``values > 0`` squash
     maps to background 0 — the same adopt-to-0 semantics as the capacity
     path.  Callers must NOT assume invalid voxels sit out of saddle
-    competition.  Returns ``(resolved_values, overflow_int32)`` —
+    competition.  Returns ``(resolved_values, overflow_int32, counts)`` —
     per-voxel labels with every reachable unseeded basin resolved to its
     adopted seed label (unreachable basins keep their codes; callers zero
     them), overflow set when ``max_rounds`` rounds did not converge OR a
-    face list or the basin table truncated.
+    face list or the basin table truncated, and the fill's part of the work
+    record (:mod:`.work`, names ``fill.*``): what the harvest found, what
+    the rounds walked, the flag split by what tripped.
 
     Selected by ``fill_mode="dense"`` (``CT_FILL_MODE``), or by the
     substrate-aware ``auto`` default on the cpu backend — resolution
@@ -761,7 +786,9 @@ def fill_unseeded_basins_dense(
         flat_idx = _match_vma(jnp.arange(n, dtype=jnp.int32), values)
         is_term = v == -flat_idx - 2
         (term_pos,), n_basins = _compact(is_term, (flat_idx,), basin_cap, n)
-        trunc = (n_basins > basin_cap).astype(jnp.int32)
+        # a code without an id (its terminal does not carry it) counts with
+        # the basins that found no room in the table
+        no_id = n_basins > basin_cap
         term_id = jnp.where(
             is_term, jnp.cumsum(is_term.astype(jnp.int32)) - 1, -1
         )
@@ -797,7 +824,8 @@ def fill_unseeded_basins_dense(
             _match_vma(jnp.zeros((48 * chunk,), jnp.int32), values)
             for _ in range(4)
         )
-        n_live = _match_vma(jnp.zeros((), jnp.int32), values)
+        n_live = harvest_trips = _match_vma(jnp.zeros((), jnp.int32), values)
+        faces = []
         for axis in range(3):
             nb = _shift(values, -1, axis, jnp.int32(0)).ravel()
             ok0 = (
@@ -805,8 +833,9 @@ def fill_unseeded_basins_dense(
                 & ((v <= -2) | (nb <= -2))
             )
             (idx_c,), n_faces = _compact(ok0, (flat_idx,), face_cap, n)
-            trunc = jnp.maximum(trunc, (n_faces > face_cap).astype(jnp.int32))
+            faces.append(n_faces)
             n_kept = jnp.minimum(n_faces, face_cap)
+            n_trips = (n_kept + chunk - 1) // chunk
             # room for 16 whole chunks: the last one's slice never clamps
             idx_c = jnp.pad(
                 idx_c, (0, 16 * chunk - face_cap), constant_values=n
@@ -814,7 +843,7 @@ def fill_unseeded_basins_dense(
             stride = int(np.prod(shape[axis + 1:], dtype=np.int64))
 
             def harvest(k, c):
-                lists, trunc = c
+                lists, no_id = c
                 idx = lax.dynamic_slice(idx_c, (k * chunk,), (chunk,))
                 pad = idx >= n
                 ia = jnp.clip(idx, 0, n - 1)
@@ -827,14 +856,14 @@ def fill_unseeded_basins_dense(
                     lax.dynamic_update_slice(buf, x, (n_live + k * chunk,))
                     for buf, x in zip(lists, (va, vb, sad, eid))
                 )
-                return lists, jnp.maximum(
-                    trunc, (bad_a | bad_b).astype(jnp.int32)
-                )
+                return lists, no_id | bad_a | bad_b
 
-            lists, trunc = lax.fori_loop(
-                0, (n_kept + chunk - 1) // chunk, harvest, (lists, trunc)
+            lists, no_id = lax.fori_loop(
+                0, n_trips, harvest, (lists, no_id)
             )
             n_live = n_live + n_kept
+            harvest_trips = harvest_trips + n_trips
+        faces_over = reduce(jnp.logical_or, [f > face_cap for f in faces])
     me_idx = _match_vma(jnp.arange(basin_cap, dtype=jnp.int32), values)
     slot = jnp.arange(chunk, dtype=jnp.int32)
 
@@ -846,7 +875,7 @@ def fill_unseeded_basins_dense(
         return a, b, sad, eid, k * chunk + slot < n_live
 
     def round_cond(s):
-        _, changed, it, _, _ = s
+        _, changed, it, _, _, _ = s
         return changed & (it < max_rounds)
 
     def round_body(s):
@@ -857,7 +886,7 @@ def fill_unseeded_basins_dense(
         # before the next starts (a basin's best_h must be final before its
         # ties are taken), and neither min nor the one winner's set depends
         # on the order of the chunks.
-        P, _, it, lists, n_live = s
+        P, _, it, lists, n_live, (round_trips, live_faces, closure_trips) = s
         trips = (n_live + chunk - 1) // chunk
 
         def sides(k):
@@ -910,15 +939,17 @@ def fill_unseeded_basins_dense(
         # overwrite (sever) an already-contracted MSF union — the exact-
         # semantics claim depends on every round starting from true roots
         def comp_cond(t):
-            _, ch = t
+            _, ch, _ = t
             return ch
 
         def comp_body(t):
-            p, _ = t
+            p, _, jumps = t
             p2 = resolve_flat(p, p)
-            return p2, jnp.any(p2 != p)
+            return p2, jnp.any(p2 != p), jumps + 1
 
-        P2, _ = lax.while_loop(comp_cond, comp_body, (P2, _true_like(P2)))
+        P2, _, closure_trips = lax.while_loop(
+            comp_cond, comp_body, (P2, _true_like(P2), closure_trips)
+        )
         changed = jnp.any(P2 != P)
 
         # drop the dead, rewrite the living: a face whose resolved sides
@@ -944,13 +975,20 @@ def fill_unseeded_basins_dense(
         kept, n_kept = lax.fori_loop(
             0, trips, drop_dead, (lists, jnp.zeros_like(n_live))
         )
-        return P2, changed, it + 1, kept, n_kept
-
-    with jax.named_scope("ws.fill.rounds"):
-        P, unconverged, _, _, _ = lax.while_loop(
-            round_cond, round_body,
-            (P0, _true_like(v), jnp.int32(0), lists, n_live),
+        return P2, changed, it + 1, kept, n_kept, (
+            round_trips + trips,
+            live_faces + work.scaled(n_live, work.FILL_LIVE_FACES),
+            closure_trips,
         )
+
+    zero = jnp.zeros_like(n_live)
+    with jax.named_scope("ws.fill.rounds"):
+        P, unconverged, rounds, _, _, walked = lax.while_loop(
+            round_cond, round_body,
+            (P0, _true_like(v), jnp.int32(0), lists, n_live,
+             (zero, zero, zero)),
+        )
+        round_trips, live_faces, closure_trips = walked
     # ---- back to the voxels: ids -> codes at the terminals' positions,
     # then one volume-sized gather as the codes name those positions ----
     with jax.named_scope("ws.fill.resolve"):
@@ -961,24 +999,34 @@ def fill_unseeded_basins_dense(
         resolved = jnp.where(
             v <= -2, code_table[jnp.clip(-v - 2, 0, n - 1)], v
         ).reshape(shape)
-    return resolved, jnp.maximum(unconverged.astype(jnp.int32), trunc)
+    trunc = faces_over | no_id
+    counts = {
+        **dict(zip(work.FILL_FACES, faces)),
+        work.FILL_HARVEST_TRIPS: harvest_trips,
+        work.FILL_BASINS: n_basins, work.FILL_ROUNDS: rounds,
+        work.FILL_ROUND_TRIPS: round_trips, work.FILL_LIVE_FACES: live_faces,
+        work.FILL_CLOSURE_TRIPS: closure_trips,
+        work.OVER_FACE: faces_over, work.OVER_BASIN: no_id,
+        work.OVER_ROUNDS: unconverged,
+        work.CAP_FACE: face_cap, work.CAP_BASIN: basin_cap,
+    }
+    return resolved, (unconverged | trunc).astype(jnp.int32), counts
 
 
 def _fill_core(a, b, hk, adj_cap, max_rounds, vma_like):
     """Dedup + dense ids + Boruvka rounds over one capacity tier.
 
-    Returns ``(edge_vals, edge_finals, overflow_int32)`` with outputs
-    sized ``2 * adj_cap``; ``vma_like`` carries the shard_map varying-axes
-    signature for freshly created arrays.
+    Returns ``(edge_vals, edge_finals, n_adj, adj_over, unconverged)`` with
+    the first two sized ``2 * adj_cap``, then the adjacency count, whether it
+    passed the tier's capacity and whether the rounds ran out; ``vma_like``
+    carries the shard_map varying-axes signature for freshly created arrays.
     """
-    overflow = _match_vma(jnp.zeros((), jnp.int32), vma_like)
     # dedup to unique (a, b) adjacencies with their min saddle: ascending
     # sort puts each pair's lowest saddle first and the BIG padding last
     sa, sb, sh = lax.sort((a, b, hk), num_keys=3)
     first = (sa != _shift1(sa, 0, BIG)) | (sb != _shift1(sb, 0, BIG))
     keep_adj = first & (sa < BIG)
     (a, b, hk), n_adj = _compact(keep_adj, (sa, sb, sh), adj_cap, BIG)
-    overflow = jnp.maximum(overflow, (n_adj > adj_cap).astype(jnp.int32))
 
     # dense ids over all endpoint values
     m2 = a.shape[0] * 2
@@ -1070,15 +1118,14 @@ def _fill_core(a, b, hk, adj_cap, max_rounds, vma_like):
     parent, unconverged, _ = lax.while_loop(
         round_cond, round_body, (parent, _true_like(da), jnp.int32(0))
     )
-    # a max_rounds exit leaves basins mid-chain: report, never hide
-    overflow = jnp.maximum(overflow, unconverged.astype(jnp.int32))
-
+    # a max_rounds exit leaves basins mid-chain: report, never hide (the
+    # caller folds both flags into its overflow)
     root_val = uniq[parent]
     final_of = jnp.where(root_val > 0, root_val, 0)
     # remap for every unseeded endpoint value
     edge_vals = uniq
     edge_finals = jnp.where(uniq <= -2, final_of, uniq)
-    return edge_vals, edge_finals, overflow
+    return edge_vals, edge_finals, n_adj, n_adj > adj_cap, unconverged
 
 
 def seeded_watershed_tiled(
@@ -1094,8 +1141,9 @@ def seeded_watershed_tiled(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: Optional[str] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Seeded watershed with the two-level tile machinery.
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Seeded watershed with the two-level tile machinery: ``(labels,
+    overflow, work)``, the last the program's work record (:mod:`.work`).
 
     Contract matches :func:`~cluster_tools_tpu.ops.watershed.seeded_watershed`
     (labels int32, 0 outside mask / unreachable) up to unseeded-basin fill
@@ -1143,20 +1191,23 @@ def _seeded_watershed_tiled_jit(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: str = "capacity",
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``(labels, overflow, work)``: the program packs its parts' counts
+    into the one work record (:mod:`.work`)."""
     # The body is flow-phase + fill-phase cores so the split execution mode
     # (parallel/split_pipeline.py) can jit each phase as its OWN program —
     # composing them here compiles the identical fused program.
-    values, h, flow_overflow = _ws_flow_core(
+    values, h, flow_overflow, flow_counts = _ws_flow_core(
         height, seeds, mask, impl=impl, tile=tile, exit_cap=exit_cap,
         table_cap=table_cap, interpret=interpret,
     )
-    out, fill_overflow = _ws_fill_core(
+    out, fill_overflow, fill_counts = _ws_fill_core(
         values, h, height.shape, impl=impl, tile=tile, exit_cap=exit_cap,
         fill_cap=fill_cap, table_cap=table_cap, interpret=interpret,
         adj_cap=adj_cap, fill_rounds=fill_rounds, fill_mode=fill_mode,
     )
-    return out, flow_overflow | fill_overflow
+    record = work.pack(work.join(flow_counts, fill_counts))
+    return out, flow_overflow | fill_overflow, record
 
 
 def resolved_modes(impl: str = "auto") -> dict:
@@ -1225,11 +1276,12 @@ def _ws_flow_core(
     exit_cap: Optional[int],
     table_cap: int,
     interpret: bool,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, dict]:
     """Flow phase: tile-pad, descent directions, in-tile flow, exit chase +
-    remap.  Returns ``(values, h, overflow)`` at TILE-PADDED shape: >0
-    seeded label, <= -2 unseeded terminal code, -1 masked/padded, plus the
-    padded float32 heights the fill phase needs."""
+    remap.  Returns ``(values, h, overflow, counts)`` at TILE-PADDED shape:
+    >0 seeded label, <= -2 unseeded terminal code, -1 masked/padded, plus the
+    padded float32 heights the fill phase needs and the flow's part of the
+    work record (:mod:`.work`, names ``flow.*``)."""
     if height.ndim != 3:
         raise ValueError("seeded_watershed_tiled expects a 3-D volume")
     impl = resolve_impl(impl)
@@ -1264,22 +1316,29 @@ def _ws_flow_core(
 
     # cross-tile exits: collect, chase, remap
     with jax.named_scope("ws.flow.exits"):
-        codes, code_tiles, overflow = collect_negative_values(
-            values, tile, exit_cap
+        codes, code_tiles, exit_over, n_exits, family_max = (
+            collect_negative_values(values, tile, exit_cap)
         )
     with jax.named_scope("ws.flow.chase"):
-        finals, chase_unconverged = chase_exits(values, codes)
-    overflow = overflow | chase_unconverged
-    values = _remap_exits(
+        finals, chase_unconverged, counts = chase_exits(values, codes)
+    values, remap_counts = _remap_exits(
         values, codes, code_tiles, finals, impl, tile, table_cap, interpret
     )
-    return values, h, overflow
+    counts.update(remap_counts)
+    counts.update({
+        work.FLOW_EXITS: n_exits, work.FLOW_EXIT_FAMILY_MAX: family_max,
+        work.OVER_EXIT: exit_over, work.OVER_HOPS: chase_unconverged,
+        work.CAP_EXIT: exit_cap, work.CAP_TABLE: table_cap,
+    })
+    return values, h, exit_over | chase_unconverged, counts
 
 
 @jax.named_scope("ws.flow.exits")
 def _remap_exits(values, codes, code_tiles, finals, impl, tile, table_cap,
                  interpret):
-    """Write every chased exit code's final value back into ``values``."""
+    """Write every chased exit code's final value back into ``values``;
+    also, where the Mosaic kernel can run: the fullest tile's table entries
+    and whether the gather ran in its place."""
     zp, yp, xp = values.shape
     tz, ty, tx = tile
     n_tiles = (zp // tz) * (yp // ty) * (xp // tx)
@@ -1289,7 +1348,7 @@ def _remap_exits(values, codes, code_tiles, finals, impl, tile, table_cap,
 
         changed = (codes <= -2) & (finals != codes)
         tids = jnp.where(changed, code_tiles, jnp.int32(BIG))
-        old_tbl, new_tbl, tbl_overflow = build_remap_tables(
+        old_tbl, new_tbl, tbl_overflow, tile_max = build_remap_tables(
             tids, codes, finals, n_tiles, table_cap=table_cap
         )
 
@@ -1303,8 +1362,11 @@ def _remap_exits(values, codes, code_tiles, finals, impl, tile, table_cap,
             v, _, _ = args
             return _resolve_codes_gather(v, codes, finals)
 
-        return lax.cond(tbl_overflow, slow, fast, (values, old_tbl, new_tbl))
-    return _resolve_codes_gather(values, codes, finals)
+        return lax.cond(tbl_overflow, slow, fast, (values, old_tbl, new_tbl)), {
+            work.FLOW_REMAP_TILE_MAX: tile_max,
+            work.FLOW_REMAP_FALLBACK: tbl_overflow,
+        }
+    return _resolve_codes_gather(values, codes, finals), {}
 
 
 @jax.named_scope("ws.fill")
@@ -1322,10 +1384,12 @@ def _ws_fill_core(
     adj_cap: Optional[int],
     fill_rounds: Optional[int],
     fill_mode: str,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
     """Fill phase: unseeded-basin fill across lowest saddles (fill_mode
     selects the machinery — see :func:`_resolve_fill_mode`), remap, squash
-    leftovers to 0, crop the tile padding back to ``orig_shape``."""
+    leftovers to 0, crop the tile padding back to ``orig_shape``.  Returns
+    ``(labels, overflow, counts)``, the last the fill's part of the work
+    record (:mod:`.work`, names ``fill.*``)."""
     impl = resolve_impl(impl)
     z, y, x = orig_shape
     tile, (zp, yp, xp), exit_cap, fill_cap = _ws_static_plan(
@@ -1342,16 +1406,16 @@ def _ws_fill_core(
         fill_rounds = _auto_fill_rounds(zp * yp * xp)
     if fill_mode == "dense":
         with jax.named_scope("ws.fill.dense"):
-            values, fill_unconv = fill_unseeded_basins_dense(
+            values, fill_unconv, counts = fill_unseeded_basins_dense(
                 values, h, max_rounds=fill_rounds
             )
         overflow = fill_unconv > 0
         out = jnp.where(values > 0, values, 0).astype(jnp.int32)
         if padded:
             out = out[:z, :y, :x]
-        return out, overflow
+        return out, overflow, counts
     with jax.named_scope("ws.fill.capacity"):
-        fill_vals, fill_finals, overflow = fill_unseeded_basins(
+        fill_vals, fill_finals, overflow, counts = fill_unseeded_basins(
             values, h, fill_cap=fill_cap, max_rounds=fill_rounds,
             adj_cap=adj_cap,
         )
@@ -1361,8 +1425,11 @@ def _ws_fill_core(
         from .pallas_kernels import apply_remap_pallas
 
         # tiles needing a basin's entry: strip incidences + the terminal's tile
-        bvals, btiles, b_overflow = collect_negative_values(values, tile, exit_cap)
+        bvals, btiles, b_overflow, _, _ = collect_negative_values(
+            values, tile, exit_cap
+        )
         overflow = overflow | b_overflow
+        counts.update({work.OVER_EXIT: b_overflow, work.CAP_EXIT: exit_cap})
         # map each (value, tile) incidence to its fill final
         bfin = value_join(bvals, fill_vals, fill_finals)
         # terminal-tile incidences for interior basins
@@ -1375,9 +1442,13 @@ def _ws_fill_core(
             [jnp.where((bvals <= -2) & (bfin != bvals), btiles, jnp.int32(BIG)),
              jnp.where((tvals <= -2) & (fill_finals != tvals), ttiles, jnp.int32(BIG))]
         )
-        old2, new2, tbl_overflow2 = build_remap_tables(
+        old2, new2, tbl_overflow2, tile_max2 = build_remap_tables(
             all_tiles, all_vals, all_fin, n_tiles, table_cap=table_cap
         )
+        counts.update({
+            work.FILL_REMAP_TILE_MAX: tile_max2,
+            work.FILL_REMAP_FALLBACK: tbl_overflow2,
+        })
 
         def fast2(args):
             v, o, nw = args
@@ -1397,7 +1468,7 @@ def _ws_fill_core(
     out = jnp.where(values > 0, values, 0).astype(jnp.int32)
     if padded:
         out = out[:z, :y, :x]
-    return out, overflow
+    return out, overflow, counts
 
 
 @jax.named_scope("ws.seeds")
@@ -1417,12 +1488,13 @@ def _dt_seeds_core(
     edge_cap: Optional[int],
     table_cap: int,
     interpret: bool,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Seed phase of the DT watershed: threshold -> (capped) EDT -> optional
     smoothing -> maxima plateaus -> seed CCL.  Returns ``(seeds, valid,
-    overflow)`` at the input shape — the split execution mode
+    overflow, work)`` at the input shape — the split execution mode
     (parallel/split_pipeline.py) jits this as its own program; the fused
-    ``dt_watershed_tiled`` inlines it."""
+    ``dt_watershed_tiled`` inlines it.  ``work`` is the seed CCL's work
+    record (:mod:`.work`) under the names ``seeds.*``."""
     from .edt import distance_transform_squared
     from .filters import gaussian_smooth
     from .watershed import local_maxima
@@ -1450,13 +1522,13 @@ def _dt_seeds_core(
         & fg
         & (dist >= min_seed_distance * min_seed_distance)
     )
-    raw, seed_overflow = label_components_tiled(
+    raw, seed_overflow, record = label_components_tiled(
         maxima, impl=impl, tile=tile, pair_cap=pair_cap, edge_cap=edge_cap,
         table_cap=table_cap, interpret=interpret,
     )
     n = int(np.prod(boundaries.shape))
     seeds = jnp.where(raw == n, 0, raw + 1).astype(jnp.int32)
-    return seeds, valid, seed_overflow
+    return seeds, valid, seed_overflow, work.as_seed_ccl(record)
 
 
 def dt_watershed_tiled(
@@ -1479,7 +1551,7 @@ def dt_watershed_tiled(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: Optional[str] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused distance-transform watershed on the two-level machinery.
 
     The same pipeline as
@@ -1487,8 +1559,10 @@ def dt_watershed_tiled(
     (threshold -> capped EDT -> seeds = CCL of DT maxima plateaus -> seeded
     watershed; reference ``_ws_block``, SURVEY.md §2a "watershed") with the
     seed CCL and the flood running on the tiled kernels.  3-D only,
-    connectivity 1.  Returns ``(labels, overflow)``; labels are
-    ``seed_rep + 1`` flat-index based, 0 outside mask/unreached.
+    connectivity 1.  Returns ``(labels, overflow, work)``; labels are
+    ``seed_rep + 1`` flat-index based, 0 outside mask/unreached; ``work`` is
+    the program's work record (:mod:`.work`: what its loops walked, how full
+    its capacities ran, which fallback branch it took).
 
     ``dist``: optional precomputed *squared* distances (e.g. the mesh-exact
     transform from :mod:`cluster_tools_tpu.parallel.distributed_edt`); when
@@ -1538,21 +1612,21 @@ def _dt_watershed_tiled_jit(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: str = "capacity",
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    seeds, valid, seed_overflow = _dt_seeds_core(
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    seeds, valid, seed_overflow, seed_record = _dt_seeds_core(
         boundaries, mask, dist, threshold=threshold, sigma_seeds=sigma_seeds,
         min_seed_distance=min_seed_distance, sampling=sampling,
         dt_max_distance=dt_max_distance, impl=impl, tile=tile,
         pair_cap=pair_cap, edge_cap=edge_cap, table_cap=table_cap,
         interpret=interpret,
     )
-    labels, ws_overflow = _seeded_watershed_tiled_jit(
+    labels, ws_overflow, record = _seeded_watershed_tiled_jit(
         boundaries, seeds, mask=valid, impl=impl, tile=tile,
         exit_cap=exit_cap, fill_cap=fill_cap, table_cap=table_cap,
         interpret=interpret, adj_cap=adj_cap, fill_rounds=fill_rounds,
         fill_mode=fill_mode,
     )
-    return labels, seed_overflow | ws_overflow
+    return labels, seed_overflow | ws_overflow, work.merge(record, seed_record)
 
 
 def dt_watershed_seeded_tiled(
@@ -1575,7 +1649,7 @@ def dt_watershed_seeded_tiled(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: Optional[str] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Two-pass-mode DT watershed on the tiled machinery.
 
     Same contract as
@@ -1584,7 +1658,8 @@ def dt_watershed_seeded_tiled(
     1..K, 0 = none) are neighbor labels from pass one; internal DT seeds are
     planted where no external seed sits.  Output values > N are external
     (+N offset, N = voxel count); 1..N are new internal fragments.  Returns
-    ``(labels, overflow)``.
+    ``(labels, overflow, work)``, the last the program's work record
+    (:mod:`.work`).
 
     ``fill_mode`` as in :func:`dt_watershed_tiled` — resolved pre-jit so
     the env value joins the compile key.
@@ -1629,9 +1704,9 @@ def _dt_watershed_seeded_tiled_jit(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: str = "capacity",
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     n = int(np.prod(boundaries.shape))
-    internal, valid, seed_overflow = _dt_seeds_core(
+    internal, valid, seed_overflow, seed_record = _dt_seeds_core(
         boundaries, mask, None, threshold=threshold, sigma_seeds=sigma_seeds,
         min_seed_distance=min_seed_distance, sampling=sampling,
         dt_max_distance=dt_max_distance, impl=impl, tile=tile,
@@ -1644,10 +1719,11 @@ def _dt_watershed_seeded_tiled_jit(
         ext = ext_seeds.astype(jnp.int32)
         # external seeds dominate; internal ids live in 1..N, external in N+1..
         seeds = jnp.where(ext > 0, ext + jnp.int32(n), internal)
-    labels, ws_overflow = _seeded_watershed_tiled_jit(
+    labels, ws_overflow, record = _seeded_watershed_tiled_jit(
         boundaries, seeds, mask=valid, impl=impl, tile=tile,
         exit_cap=exit_cap, fill_cap=fill_cap, table_cap=table_cap,
         interpret=interpret, adj_cap=adj_cap, fill_rounds=fill_rounds,
         fill_mode=fill_mode,
     )
-    return labels, seed_overflow | ws_overflow
+    return labels, seed_overflow | ws_overflow, work.merge(record, seed_record)
+

@@ -47,6 +47,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compat import shard_map
+from ..ops import work
 from ..ops.ccl import _match_vma, label_components, relabel_consecutive
 from ..ops.tile_ccl import _compact, _shift1
 from ..ops.unionfind import union_find
@@ -194,8 +195,11 @@ def sharded_label_components(
     Returns int32 labels that are **globally consistent across all shards**;
     background is 0.  With ``max_labels_per_shard`` set, per-shard labels are
     compacted before globalization (see module docstring); with
-    ``return_overflow`` also returns a replicated bool that is True when any
-    shard exceeded the compaction capacity (labels are then unreliable).
+    ``return_overflow`` returns ``(labels, overflow, work)``: a replicated
+    bool that is True when any shard exceeded the compaction capacity (labels
+    are then unreliable), and THIS shard's work record (``ops/work.py``: the
+    tiled CCL's counts and, where labels are compacted, the components
+    against the capacity), reduced over no mesh axis.
 
     Cross-shard stitching matches the in-shard neighborhood at any
     ``connectivity`` (scipy semantics): faces at 1, plus diagonal adjacency
@@ -216,17 +220,20 @@ def sharded_label_components(
     if use_tiled:
         from ..ops.tile_ccl import label_components_tiled
 
-        raw, tiled_overflow = label_components_tiled(
+        raw, tiled_overflow, record = label_components_tiled(
             mask, connectivity=connectivity, impl=impl
         )
     else:
         with jax.named_scope("ccl.tile"):
             raw = label_components(mask, connectivity=connectivity)
-        tiled_overflow = None
-    return _globalize_and_merge(
+        tiled_overflow, record = None, work.pack({})
+    labels, overflow, counts = _globalize_and_merge(
         raw, tiled_overflow, axes, connectivity, max_labels_per_shard,
         return_overflow,
     )
+    if return_overflow:
+        return labels, overflow, work.merge(record, work.pack(counts))
+    return labels
 
 
 @jax.named_scope("ccl.merge")
@@ -236,7 +243,10 @@ def _globalize_and_merge(
 ):
     """Steps 2-4 of :func:`sharded_label_components`: make the per-shard
     labels unique over the mesh, exchange the cross-shard equivalences,
-    solve them replicated and relabel the local shard."""
+    solve them replicated and relabel the local shard.  Returns ``(labels,
+    overflow or None, counts)``, the last this shard's label count against
+    the compaction capacity where there is one."""
+    counts = {}
     n_slab = int(np.prod(raw.shape))
     n_shards = int(np.prod([s for _, _, s in axes]))
     rank = linearized_shard_rank(axes)
@@ -266,6 +276,8 @@ def _globalize_and_merge(
             local, max_labels=cap, value_bound=n_slab
         )
         overflow = overflow | (n_fg > cap)
+        counts = {work.STEP_COMPONENTS: n_fg, work.OVER_LABELS: n_fg > cap,
+                  work.CAP_LABELS: cap}
         glob = jnp.where(dense > 0, dense + rank * jnp.int32(cap + 1), 0)
 
     if n_shards == 1:
@@ -278,8 +290,8 @@ def _globalize_and_merge(
             ov = overflow.astype(jnp.int32)
             for _, name, _ in axes:
                 ov = lax.pmax(ov, name)
-            return glob, ov > 0
-        return glob
+            return glob, ov > 0, counts
+        return glob, None, counts
 
     # 2. cross-shard equivalences (faces; diagonals too at connectivity>1)
     pairs = _boundary_pairs(glob, axes, connectivity)
@@ -293,9 +305,7 @@ def _globalize_and_merge(
     span = (n_slab if max_labels_per_shard is None
             else int(max_labels_per_shard) + 1)
     labels = merge_labels_by_pairs(glob, pairs, axes, rank, span)
-    if return_overflow:
-        return labels, overflow
-    return labels
+    return labels, overflow if return_overflow else None, counts
 
 
 def default_pair_cap(n_rows: int) -> int:
@@ -413,15 +423,17 @@ def distributed_connected_components(
     """
     names = [sp_axis] if isinstance(sp_axis, str) else list(sp_axis)
     shard_axes = sp_axes_for_mesh(mesh, sp_axis)
+    body = partial(
+        sharded_label_components,
+        shard_axes=shard_axes,
+        connectivity=connectivity,
+        max_labels_per_shard=max_labels_per_shard,
+        return_overflow=return_overflow,
+        impl=impl,
+    )
     fn = shard_map(
-        partial(
-            sharded_label_components,
-            shard_axes=shard_axes,
-            connectivity=connectivity,
-            max_labels_per_shard=max_labels_per_shard,
-            return_overflow=return_overflow,
-            impl=impl,
-        ),
+        # the shards' work records stay behind: the fused step hands them out
+        (lambda m: body(m)[:2]) if return_overflow else body,
         mesh=mesh,
         in_specs=P(*names),
         out_specs=(P(*names), P()) if return_overflow else P(*names),

@@ -33,6 +33,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compat import shard_map
+from ..ops import work
 from ..ops.ccl import _match_vma, relabel_consecutive
 from ..ops.watershed import distance_transform_watershed
 from .distributed_ccl import (
@@ -105,14 +106,15 @@ def globalize_fragments(
     rank: jnp.ndarray,
     n_pad: int,
     max_labels_per_shard: Optional[int],
-) -> Tuple[jnp.ndarray, int, Optional[jnp.ndarray]]:
+) -> Tuple[jnp.ndarray, int, Optional[jnp.ndarray], dict]:
     """Crop the halo and make watershed fragment ids unique over the mesh
-    by shard rank: ``(ws, span, overflow)``, shared by the fused step and
-    the split chain's fill stage.  With a compaction cap, fragment ids are
-    densified first so the label space is ``n_shards * cap`` instead of
+    by shard rank: ``(ws, span, overflow, counts)``, shared by the fused step
+    and the split chain's fill stage.  With a compaction cap, fragment ids
+    are densified first so the label space is ``n_shards * cap`` instead of
     ``n_shards * padded_voxels`` (the int32 ceiling that blocked teravoxel
     volumes); ``overflow`` is then the int32 flag of a shard with more
-    fragments than the cap, else None."""
+    fragments than the cap, else None, and ``counts`` the shard's fragments
+    against the cap for the work record (``ops/work.py``), else empty."""
     n_shards = int(np.prod([s for _, _, s in sp_axes]))
     for a, _, _ in sp_axes:
         ws = crop_halo(ws, halo, a)
@@ -122,7 +124,8 @@ def globalize_fragments(
                 f"{n_shards} shards of {n_pad} padded voxels overflow "
                 "int32 labels; pass max_labels_per_shard"
             )
-        return jnp.where(ws > 0, ws + rank * jnp.int32(n_pad), 0), n_pad, None
+        return (jnp.where(ws > 0, ws + rank * jnp.int32(n_pad), 0), n_pad,
+                None, {})
     cap = int(max_labels_per_shard)
     if n_shards * (cap + 1) >= 2**31:
         raise ValueError(
@@ -135,7 +138,22 @@ def globalize_fragments(
         ws, max_labels=cap, value_bound=n_pad + 1
     )
     ws = jnp.where(ws > 0, ws + rank * jnp.int32(cap + 1), 0)
-    return ws, cap + 1, (n_frag > cap).astype(jnp.int32)
+    return ws, cap + 1, (n_frag > cap).astype(jnp.int32), {
+        work.STEP_FRAGMENTS: n_frag, work.OVER_LABELS: n_frag > cap,
+        work.CAP_LABELS: cap,
+    }
+
+
+def shard_records(
+    rows: Sequence[jnp.ndarray], sp_axes: Sequence[ShardAxis]
+) -> jnp.ndarray:
+    """A shard's work records (``ops/work.py``), one row a local volume, as
+    its block of the step's ``(B,) + spatial mesh sizes + (K,)`` output: a
+    size-1 axis per sharded mesh axis, so that the labels' ``out_specs`` fit
+    and no row meets another shard's."""
+    return jnp.stack(rows).reshape(
+        (len(rows),) + (1,) * len(sp_axes) + (len(work.NAMES),)
+    )
 
 
 @jax.named_scope("step.count")
@@ -166,11 +184,14 @@ def _ws_ccl_shard(
     impl: str,
     exact_edt: bool,
     stitch_ws_threshold: Optional[float],
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Per-device body: local shard is ``(local_batch,) + local_volume``.
 
     ``sp_axes`` holds ``(volume_axis, mesh_axis_name, mesh_axis_size)`` per
-    sharded spatial axis (volume axes count WITHOUT the batch axis).
+    sharded spatial axis (volume axes count WITHOUT the batch axis).  The
+    last output is the shard's work record (``ops/work.py``), one row a
+    local volume, a size-1 axis per sharded mesh axis in between: it is
+    this shard's own and meets no collective.
     """
     local_b = boundaries.shape[0]
     n_shards = int(np.prod([s for _, _, s in sp_axes]))
@@ -191,6 +212,7 @@ def _ws_ccl_shard(
 
     ws_out = []
     cc_out = []
+    records = []
     # per-shard ws-compaction overflow (varies over the mesh); cc overflow
     # arrives already sp-reduced from sharded_label_components
     ws_overflow = _match_vma(jnp.zeros((), jnp.int32), boundaries)
@@ -225,7 +247,7 @@ def _ws_ccl_shard(
                     impl="xla" if impl in ("xla", "tiled") else "auto",
                 )
                 dist_pad = exchange_all(dist_sq, halo, sp_axes, fill=0.0)
-            ws, ws_over = dt_watershed_tiled(
+            ws, ws_over, record = dt_watershed_tiled(
                 padded,
                 threshold=threshold,
                 dist=dist_pad,
@@ -242,7 +264,8 @@ def _ws_ccl_shard(
                 connectivity=connectivity,
                 dt_max_distance=dt_max_distance,
             )
-        ws, ws_span, frag_over = globalize_fragments(
+            record = work.pack({})  # the dense fixpoint counts nothing
+        ws, ws_span, frag_over, frag_counts = globalize_fragments(
             ws, halo, sp_axes, rank, int(np.prod(padded.shape)),
             max_labels_per_shard,
         )
@@ -259,7 +282,7 @@ def _ws_ccl_shard(
 
         # globally merged connected components of the foreground mask — the
         # two-pass union-find merge as ICI collectives
-        cc, cc_over = sharded_label_components(
+        cc, cc_over, cc_record = sharded_label_components(
             vol < threshold,
             shard_axes=sp_axes,
             connectivity=connectivity,
@@ -267,6 +290,7 @@ def _ws_ccl_shard(
             return_overflow=True,
             impl=impl,
         )
+        records.append(work.merge(record, work.pack(frag_counts), cc_record))
         cc_over = cc_over.astype(jnp.int32)
         cc_overflow = (
             cc_over if cc_overflow is None else jnp.maximum(cc_overflow, cc_over)
@@ -283,7 +307,7 @@ def _ws_ccl_shard(
             ws_overflow = lax.pmax(ws_overflow, name)
         overflow = jnp.maximum(ws_overflow, cc_overflow)
         overflow = lax.pmax(overflow, dp_axis) > 0
-    return ws_lab, cc_lab, n_fg, overflow
+    return ws_lab, cc_lab, n_fg, overflow, shard_records(records, sp_axes)
 
 
 def make_ws_ccl_step(
@@ -308,12 +332,16 @@ def make_ws_ccl_step(
     volume's z axis sharded in slabs) or a sequence of names (the leading
     volume axes sharded over the respective mesh axes — a full 2-D/3-D
     spatial decomposition; each sharded extent must divide).  Output:
-    ``(ws_labels, cc_labels, n_foreground, overflow)`` with labels sharded
-    like the input and the scalars replicated; ``n_foreground`` is float32
-    (exact below 2**24 per shard; an int32 count would wrap past 2**31
-    global foreground voxels); ``overflow`` is True when any shard exceeded
-    ``max_labels_per_shard``, a tiled-kernel capacity, or a compaction cap
-    (labels unreliable — raise the cap or add shards).
+    ``(ws_labels, cc_labels, n_foreground, overflow, work)`` with labels
+    sharded like the input and the scalars replicated; ``n_foreground`` is
+    float32 (exact below 2**24 per shard; an int32 count would wrap past
+    2**31 global foreground voxels); ``overflow`` is True when any shard
+    exceeded ``max_labels_per_shard``, a tiled-kernel capacity, or a
+    compaction cap (labels unreliable — raise the cap or add shards);
+    ``work`` is the shards' work records, int32 ``(B,) + spatial mesh sizes
+    + (len(work.NAMES),)``, each shard's row its own (``ops/work.py``:
+    ``work.unpack`` gives one dict a shard, ``work.tripped`` names what
+    raised ``overflow``).
 
     ``impl`` selects the per-shard kernels: "auto" (two-level VMEM tile
     machinery, Mosaic on TPU / portable XLA elsewhere — the fast path),
@@ -369,7 +397,7 @@ def make_ws_ccl_step(
         body,
         mesh=mesh,
         in_specs=spec,
-        out_specs=(spec, spec, P(), P()),
+        out_specs=(spec, spec, P(), P(), spec),
         check_vma=False,
     )
     # the name the compiled module, its cache entry and every trace carry:

@@ -49,6 +49,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compat import shard_map
+from ..ops import work
 from ..ops.ccl import _match_vma
 from ..ops.tile_ccl import DEFAULT_TABLE_CAP
 from ..ops.tile_ws import (
@@ -67,6 +68,7 @@ from .pipeline import (
     count_foreground,
     exchange_all,
     globalize_fragments,
+    shard_records,
 )
 
 
@@ -74,8 +76,10 @@ class SplitWsCclStep:
     """Callable chain of per-stage programs; see the module docstring.
 
     ``step(boundaries)`` returns ``(ws_labels, cc_labels, n_foreground,
-    overflow)`` — the same contract as the fused step from
-    ``make_ws_ccl_step``.  ``stages`` maps stage name to its jitted
+    overflow, work)`` — the same contract as the fused step from
+    ``make_ws_ccl_step``; the shards' work records (``ops/work.py``) pass
+    from stage to stage beside the overflow flag, each stage adding what it
+    counted.  ``stages`` maps stage name to its jitted
     function for individual compile-probing / cache warming; ``run_staged``
     exposes per-stage sync points for stage-resolved timing.
     """
@@ -150,7 +154,7 @@ def make_ws_ccl_split(
         if boundaries.ndim - 1 != 3:
             raise ValueError("split mode expects 3-D volumes")
         local_b = boundaries.shape[0]
-        pad_out, seed_out = [], []
+        pad_out, seed_out, rows = [], [], []
         ovf = _match_vma(jnp.zeros((), jnp.int32), boundaries)
         for b in range(local_b):
             vol = boundaries[b]
@@ -168,7 +172,7 @@ def make_ws_ccl_split(
                     impl="xla" if impl in ("xla", "tiled") else "auto",
                 )
                 dist_pad = exchange_all(dist_sq, halo, sp_axes, fill=0.0)
-            seeds, _, s_ovf = _dt_seeds_core(
+            seeds, _, s_ovf, record = _dt_seeds_core(
                 padded, None, dist_pad, threshold=threshold,
                 sigma_seeds=0.0, min_seed_distance=min_seed_distance,
                 sampling=None, dt_max_distance=dt_max_distance,
@@ -178,27 +182,31 @@ def make_ws_ccl_split(
             ovf = jnp.maximum(ovf, s_ovf.astype(jnp.int32))
             pad_out.append(padded)
             seed_out.append(seeds)
-        return jnp.stack(pad_out), jnp.stack(seed_out), _reduce_all(ovf)
+            rows.append(record)
+        return (jnp.stack(pad_out), jnp.stack(seed_out), _reduce_all(ovf),
+                shard_records(rows, sp_axes))
 
     # ---- stage 2: descent + in-tile flow + exit chase/remap ----
-    def flow_body(padded, seeds, ovf_in):
+    def flow_body(padded, seeds, ovf_in, rec_in):
         local_b = padded.shape[0]
-        val_out, h_out = [], []
+        val_out, h_out, rows = [], [], []
         ovf = ovf_in
         for b in range(local_b):
-            values, h, o = _ws_flow_core(
+            values, h, o, counts = _ws_flow_core(
                 padded[b], seeds[b], None, impl=impl, tile=None,
                 exit_cap=None, table_cap=DEFAULT_TABLE_CAP, interpret=False,
             )
             ovf = jnp.maximum(ovf, o.astype(jnp.int32))
             val_out.append(values)
             h_out.append(h)
+            rows.append(work.pack(counts))
         # pmax so the replicated out_spec is honest (check_vma is off —
         # an unreduced per-shard flag would silently take one shard's copy)
-        return jnp.stack(val_out), jnp.stack(h_out), _reduce_all(ovf)
+        return (jnp.stack(val_out), jnp.stack(h_out), _reduce_all(ovf),
+                work.merge(rec_in, shard_records(rows, sp_axes)))
 
     # ---- stage 3: fill + halo crop + globalize + stitch ----
-    def fill_body(values, h, boundaries, ovf_in):
+    def fill_body(values, h, boundaries, ovf_in, rec_in):
         local_b = values.shape[0]
         rank = linearized_shard_rank(sp_axes)
         pad_shape = tuple(
@@ -207,19 +215,20 @@ def make_ws_ccl_split(
             for i in range(3)
         )
         n_pad = int(np.prod(pad_shape))
-        ws_out = []
+        ws_out, rows = [], []
         ovf = ovf_in
         for b in range(local_b):
-            ws, o = _ws_fill_core(
+            ws, o, counts = _ws_fill_core(
                 values[b], h[b], pad_shape, impl=impl, tile=None,
                 exit_cap=None, fill_cap=None, table_cap=DEFAULT_TABLE_CAP,
                 interpret=False, adj_cap=None, fill_rounds=None,
                 fill_mode=fill_mode,
             )
             ovf = jnp.maximum(ovf, o.astype(jnp.int32))
-            ws, ws_span, frag_over = globalize_fragments(
+            ws, ws_span, frag_over, frag_counts = globalize_fragments(
                 ws, halo, sp_axes, rank, n_pad, max_labels_per_shard
             )
+            rows.append(work.pack(work.join(counts, frag_counts)))
             if frag_over is not None:
                 ovf = jnp.maximum(ovf, frag_over)
             if stitch_ws_threshold is not None and n_shards > 1:
@@ -228,16 +237,17 @@ def make_ws_ccl_split(
                     float(stitch_ws_threshold),
                 )
             ws_out.append(ws)
-        return jnp.stack(ws_out), _reduce_all(ovf)
+        return (jnp.stack(ws_out), _reduce_all(ovf),
+                work.merge(rec_in, shard_records(rows, sp_axes)))
 
     # ---- stage 4: distributed CC of the foreground + global stats ----
-    def cc_body(boundaries, ovf_in):
+    def cc_body(boundaries, ovf_in, rec_in):
         local_b = boundaries.shape[0]
-        cc_out = []
+        cc_out, rows = [], []
         ovf = ovf_in
         for b in range(local_b):
             vol = boundaries[b]
-            cc, cc_over = sharded_label_components(
+            cc, cc_over, record = sharded_label_components(
                 vol < threshold,
                 shard_axes=sp_axes,
                 connectivity=connectivity,
@@ -247,37 +257,40 @@ def make_ws_ccl_split(
             )
             ovf = jnp.maximum(ovf, cc_over.astype(jnp.int32))
             cc_out.append(cc)
+            rows.append(record)
         cc_lab = jnp.stack(cc_out)
         n_fg = count_foreground(cc_lab, sp_axes, dp_axis)
         overflow = _reduce_all(ovf) > 0
-        return cc_lab, n_fg, overflow
+        return cc_lab, n_fg, overflow, work.merge(rec_in, shard_records(rows, sp_axes))
 
     stages = {
-        "seeds": _smap("seeds", seeds_body, (spec,), (spec, spec, rep)),
+        "seeds": _smap("seeds", seeds_body, (spec,), (spec, spec, rep, spec)),
         # donate the padded volume (consumed by flow) and values/h
         # (consumed by fill) so peak HBM stays in the fused step's class
         "flow": _smap(
-            "flow", flow_body, (spec, spec, rep), (spec, spec, rep), donate=(0, 1)
+            "flow", flow_body, (spec, spec, rep, spec),
+            (spec, spec, rep, spec), donate=(0, 1),
         ),
         "fill": _smap(
-            "fill", fill_body, (spec, spec, spec, rep), (spec, rep), donate=(0, 1)
+            "fill", fill_body, (spec, spec, spec, rep, spec),
+            (spec, rep, spec), donate=(0, 1),
         ),
-        "cc": _smap("cc", cc_body, (spec, rep), (spec, rep, rep)),
+        "cc": _smap("cc", cc_body, (spec, rep, spec), (spec, rep, rep, spec)),
     }
 
     def runner(boundaries, sync=None):
-        padded, seeds, ovf = stages["seeds"](boundaries)
+        padded, seeds, ovf, rec = stages["seeds"](boundaries)
         if sync is not None:
             sync("seeds", seeds)
-        values, h, ovf = stages["flow"](padded, seeds, ovf)
+        values, h, ovf, rec = stages["flow"](padded, seeds, ovf, rec)
         if sync is not None:
             sync("flow", values)
-        ws_lab, ovf = stages["fill"](values, h, boundaries, ovf)
+        ws_lab, ovf, rec = stages["fill"](values, h, boundaries, ovf, rec)
         if sync is not None:
             sync("fill", ws_lab)
-        cc_lab, n_fg, overflow = stages["cc"](boundaries, ovf)
+        cc_lab, n_fg, overflow, rec = stages["cc"](boundaries, ovf, rec)
         if sync is not None:
             sync("cc", cc_lab)
-        return ws_lab, cc_lab, n_fg, overflow
+        return ws_lab, cc_lab, n_fg, overflow, rec
 
     return SplitWsCclStep(stages, runner)

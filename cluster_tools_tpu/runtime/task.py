@@ -112,6 +112,9 @@ class BaseTask:
 
     task_name: str = "base"
     target: str = "local"  # backend: 'local' (CPU devices) or 'tpu'
+    #: keys of ``run_impl()``'s result that go into ``io_metrics.json`` too,
+    #: with the counters the base class gathers
+    io_metrics_keys: tuple = ()
 
     def __init__(
         self,
@@ -368,6 +371,9 @@ class BaseTask:
             step_metrics = step_cache_mod.delta(step_snap)
             if step_metrics:
                 io_metrics["step_cache"] = step_metrics
+            io_metrics.update(
+                {k: result[k] for k in self.io_metrics_keys if k in result}
+            )
             if any(io_metrics.values()):
                 result["io_metrics"] = io_metrics
                 try:

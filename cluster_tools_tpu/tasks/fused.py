@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..ops import work
 from ..runtime.task import BaseTask, WorkflowBase, get_task_cls
 from ..utils.volume_utils import file_reader
 
@@ -72,6 +73,8 @@ class FusedSegmentationBase(BaseTask):
     """
 
     task_name = "fused_segmentation"
+    #: the shards' work records (docs/OBSERVABILITY.md "The work record")
+    io_metrics_keys = ("work",)
 
     @staticmethod
     def default_task_config():
@@ -147,12 +150,24 @@ class FusedSegmentationBase(BaseTask):
             out = step(x)
         del x
         self.logger.info(f"step_cache={step_info}")
-        with trace_mod.span("fused.wait"):
-            ws, cc, n_fg, overflow = jax.block_until_ready(out)
+        # the shards' work records come back with the labels, on every job:
+        # a few hundred bytes, one dict a shard (docs/OBSERVABILITY.md "The
+        # work record")
+        with trace_mod.span("fused.wait") as sp:
+            ws, cc, n_fg, overflow, records = jax.block_until_ready(out)
+            records = work.unpack(records)
+            sp.note(work=records)
         if bool(np.asarray(overflow)):
+            tripped = [
+                f"shard {i}: {line}"
+                for i, rec in enumerate(records) for line in work.tripped(rec)
+            ]
             raise RuntimeError(
-                "fused step overflowed a label capacity; raise "
-                "max_labels_per_shard or use the blockwise task chain"
+                "fused step overflowed a capacity (" + "; ".join(tripped)
+                + "). Of these only max_labels_per_shard is an option of "
+                "this task: the others follow the shard's shape, so shard "
+                "the ROI over more devices, or use the blockwise task chain, "
+                "where each is an option"
             )
 
         out_f = file_reader(cfg["output_path"])
@@ -188,6 +203,7 @@ class FusedSegmentationBase(BaseTask):
                 roi_shape, mesh.devices.shape[1:], halo),
             "device_memory": device_peak_bytes(mesh.devices),
             "step_cache": dict(step_info, totals=step_cache.totals()),
+            "work": records,
         }
 
     def _build_step(self, cfg, roi_shape):

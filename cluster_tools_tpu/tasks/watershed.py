@@ -24,6 +24,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from ..ops import work
 from ..ops.watershed import (
     distance_transform_watershed,
     dt_watershed_seeded,
@@ -85,12 +86,32 @@ def _refuse_checkerboard_hybrids(cfg):
         )
 
 
-def _pass_counters(summary, blocks, outer, use_tiled, overflow_blocks):
+def _validate_block_labels(block, out):
+    """:func:`validate_labels` on a kernel's labels and flag; its work
+    record holds -1 for what the program did not count."""
+    return validate_labels(block, out[:2])
+
+
+def _warn_overflow(logger, block, rec):
+    """Capacity-truncated labels are under-merged: never silent, and named
+    (``ops/work.py::tripped``) so that the right knob is raised."""
+    logger.warning(
+        f"block {block.block_id} overflowed a tiled-watershed capacity ("
+        + ("; ".join(work.tripped(rec)) or "none recorded")
+        + "); labels may be under-merged (raise that cap in the task's "
+        "config, or use impl=legacy)"
+    )
+
+
+def _pass_counters(summary, blocks, outer, use_tiled, overflow_blocks,
+                   records):
     """What one sweep of the watershed computed, for the task's manifest and
     its ``ws.pass`` span (docs/OBSERVABILITY.md "Two-pass watershed"): the
     blocks it ran, its dispatches, the lanes that carried no block, and the
     voxels computed (every lane at the kernel's tile-padded outer shape)
-    beside those of the outer blocks and of the inner blocks it stored."""
+    beside those of the outer blocks and of the inner blocks it stored; and
+    ``work``, the real lanes' work records as one (``ops/work.py::total``;
+    ``records``: block id -> the lane's record, which the span keeps)."""
     padded = outer
     if use_tiled:
         from ..ops.tile_ws import _ws_static_plan
@@ -106,6 +127,7 @@ def _pass_counters(summary, blocks, outer, use_tiled, overflow_blocks):
         "outer_voxels": n_blocks * int(np.prod(outer)),
         "inner_voxels": sum(int(np.prod(b.shape)) for b in blocks),
         "overflow_blocks": sorted(overflow_blocks),
+        "work": work.total(records.values()),
     }
 
 
@@ -255,12 +277,14 @@ class _WsTaskBase(BaseTask):
         )
 
     def _sweep(self, executor, cfg, kernel, blocks, load, store, block_done,
-               done, out, parity, outer, use_tiled, overflow_blocks):
+               done, out, parity, outer, use_tiled, overflow_blocks, records):
         """One sweep of the block grid through the executor, as both passes
         run it; returns the pass's counters (:func:`_pass_counters`).  The
         ``ws.pass`` span is the pass on the timeline: which parity, how many
         blocks, and (once the sweep is through) the lanes its dispatches
-        carried and the voxels they computed."""
+        carried, the voxels they computed and the real lanes' work records
+        (``work``: one dict a block, by block id; a padding lane's row never
+        reaches ``store``)."""
         todo = [b for b in blocks if b.block_id not in done]
         with trace_mod.span(
             "ws.pass", task=self.uid, parity=parity, n_blocks=len(todo)
@@ -272,7 +296,7 @@ class _WsTaskBase(BaseTask):
                 store,
                 on_block_done=block_done,
                 done_block_ids=done,
-                validate_fn=validate_labels,
+                validate_fn=_validate_block_labels,
                 failures_path=self.failures_path,
                 task_name=self.uid,
                 block_deadline_s=cfg.get("block_deadline_s"),
@@ -301,10 +325,11 @@ class _WsTaskBase(BaseTask):
                 inflight_byte_budget=cfg.get("inflight_byte_budget"),
             )
             counters = _pass_counters(
-                summary, todo, outer, use_tiled, overflow_blocks
+                summary, todo, outer, use_tiled, overflow_blocks, records
             )
             pass_span.note(
                 lanes=len(todo) + counters["lanes_padded"],
+                work=[dict(records[b], block=b) for b in sorted(records)],
                 **{k: counters[k] for k in (
                     "dispatches", "padded_voxels", "outer_voxels",
                     "inner_voxels",
@@ -399,28 +424,26 @@ class WatershedBase(_WsTaskBase):
 
                 tk = {k: v for k, v in kp.items() if k != "connectivity"}
                 tk.update(_tiled_cap_knobs(cfg))
-                lab, ovf = dt_watershed_tiled(b, mask=m, impl=impl, **tk)
+                lab, ovf, rec = dt_watershed_tiled(b, mask=m, impl=impl, **tk)
             else:
                 lab = distance_transform_watershed(b, mask=m, two_d=two_d, **kp)
                 ovf = jnp.zeros((), bool)
+                rec = work.pack({})  # the dense fixpoint counts nothing
             if size_filter > 0:
                 lab = filter_small_segments(
                     lab, b, jnp.int32(size_filter), connectivity=kp["connectivity"]
                 )
-            return lab, ovf
+            return lab, ovf, rec
 
         overflow_blocks = set()
+        records = {}  # block id -> the lane's work record
 
         def store(block, raw):
-            lab, ovf = raw
+            lab, ovf, rec = raw
+            records[block.block_id] = rec = work.unpack(rec)[0]
             if bool(np.asarray(ovf)):
-                # capacity-truncated labels are under-merged — record loudly
                 overflow_blocks.add(block.block_id)
-                self.logger.warning(
-                    f"block {block.block_id} overflowed a tiled-watershed "
-                    "capacity; labels may be under-merged (raise the caps "
-                    "or use impl=legacy)"
-                )
+                _warn_overflow(self.logger, block, rec)
             lab = np.asarray(lab)
             if agg_thr is not None:
                 # peek, don't pop: a store retry (including a post-store
@@ -462,6 +485,8 @@ class WatershedBase(_WsTaskBase):
 
             from ..ops.host import host_dt_watershed
 
+            nothing_counted = np.full(len(work.NAMES), work.UNSET, np.int32)
+
             def _host_block(block):
                 b, m = load(block)
                 lab = host_dt_watershed(
@@ -472,13 +497,15 @@ class WatershedBase(_WsTaskBase):
                     mask=m,
                     sampling=kp.get("sampling"),
                 )
-                store(block, (lab, False))
+                store(block, (lab, False, nothing_counted))
                 self.log_block_success(block.block_id)
 
             with ThreadPoolExecutor(max(1, self.max_jobs)) as pool:
                 # list() propagates the first worker exception
                 list(pool.map(_host_block, todo))
-            counters = _pass_counters({}, todo, outer, False, overflow_blocks)
+            counters = _pass_counters(
+                {}, todo, outer, False, overflow_blocks, records
+            )
         else:
             executor = BlockwiseExecutor(
                 target=self.target,
@@ -490,7 +517,7 @@ class WatershedBase(_WsTaskBase):
             self._log_execution(executor, impl, use_tiled)
             counters = self._sweep(
                 executor, cfg, ws_block, blocks_all, load, store, block_done,
-                done, out, parity, outer, use_tiled, overflow_blocks,
+                done, out, parity, outer, use_tiled, overflow_blocks, records,
             )
             device_memory = device_peak_bytes(executor.devices)
         return {
@@ -606,12 +633,13 @@ class TwoPassWatershedBase(_WsTaskBase):
 
                 tk = {k: v for k, v in kp.items() if k != "connectivity"}
                 tk.update(_tiled_cap_knobs(cfg))
-                lab, ovf = dt_watershed_seeded_tiled(
+                lab, ovf, rec = dt_watershed_seeded_tiled(
                     b, ext, mask=m, impl=impl, **tk
                 )
             else:
                 lab = dt_watershed_seeded(b, ext, mask=m, **kp)
                 ovf = jnp.zeros((), bool)
+                rec = work.pack({})  # the dense fixpoint counts nothing
             if size_filter > 0:
                 # external ids live in (N, 2N]; widen the size-count domain
                 lab = filter_small_segments(
@@ -621,22 +649,19 @@ class TwoPassWatershedBase(_WsTaskBase):
                     connectivity=kp["connectivity"],
                     max_label=2 * n_outer,
                 )
-            return lab, ovf
+            return lab, ovf, rec
 
         overflow_blocks = set()
+        records = {}  # block id -> the lane's work record
 
         def store(block, raw):
-            raw, ovf = raw
+            raw, ovf, rec = raw
+            records[block.block_id] = rec = work.unpack(rec)[0]
             if bool(np.asarray(ovf)):
-                # same contract as the single-pass store: capacity
-                # truncation means under-merged labels — never silent,
-                # and recorded so the blocks can be rerun programmatically
+                # same contract as the single-pass store: recorded so the
+                # blocks can be rerun programmatically
                 overflow_blocks.add(block.block_id)
-                self.logger.warning(
-                    f"block {block.block_id} overflowed a tiled-watershed "
-                    "capacity; labels may be under-merged (raise the caps "
-                    "or use impl=legacy)"
-                )
+                _warn_overflow(self.logger, block, rec)
             raw = np.asarray(raw)[block.inner_in_outer_bb]
             # peek, don't pop: a store retry must find the table intact
             ext_labels = tables[block.block_id]
@@ -673,6 +698,7 @@ class TwoPassWatershedBase(_WsTaskBase):
         counters = self._sweep(
             executor, cfg, ws_block_seeded, blocks_all, load, store,
             block_done, done, out, 1, outer, use_tiled, overflow_blocks,
+            records,
         )
         return {
             **counters,
@@ -729,6 +755,7 @@ class WatershedWorkflow(WorkflowBase):
     _PASS_KEYS = (
         "n_blocks", "dispatches", "lanes_padded", "padded_voxels",
         "outer_voxels", "inner_voxels", "n_ext_labels", "overflow_blocks",
+        "work",
     )
 
     def run_impl(self):

@@ -78,7 +78,7 @@ def main():
         log(f"stage {name} done")
 
     out = split.run_staged(vol, sync)
-    ws, cc, n_fg, overflow = jax.block_until_ready(out)
+    ws, cc, n_fg, overflow, _ = jax.block_until_ready(out)
     total = time.monotonic() - marks[0][1]
     for (pn, pt), (nn, nt) in zip(marks, marks[1:]):
         log(f"  {nn}: {nt - pt:.1f}s")

@@ -151,7 +151,7 @@ def _mk_case(rng, shape, seed_frac):
 def test_dense_fill_matches_exact_oracle(rng, seed_frac):
     shape = (8, 9, 10)
     vals, height = _mk_case(rng, shape, seed_frac)
-    got, unconv = fill_unseeded_basins_dense(
+    got, unconv, _ = fill_unseeded_basins_dense(
         jnp.asarray(vals), jnp.asarray(height)
     )
     assert int(unconv) == 0
@@ -163,7 +163,7 @@ def test_dense_fill_all_seeded_identity(rng):
     shape = (6, 6, 12)
     vals = rng.integers(1, 5, size=shape).astype(np.int32)
     height = rng.random(shape).astype(np.float32)
-    got, unconv = fill_unseeded_basins_dense(
+    got, unconv, _ = fill_unseeded_basins_dense(
         jnp.asarray(vals), jnp.asarray(height)
     )
     assert int(unconv) == 0
@@ -177,7 +177,7 @@ def test_dense_fill_unreachable_keeps_code(rng):
     vals[0, 0, 0] = -(0) - 2  # its own flat index 0 -> code -2
     vals[4, 4, :] = 7  # a seeded region far away, disconnected by zeros
     height = rng.random(shape).astype(np.float32)
-    got, unconv = fill_unseeded_basins_dense(
+    got, unconv, _ = fill_unseeded_basins_dense(
         jnp.asarray(vals), jnp.asarray(height)
     )
     assert int(unconv) == 0
@@ -199,7 +199,7 @@ def test_dense_mode_through_watershed(rng, monkeypatch):
     seeds[20, 20, 100] = 2
     monkeypatch.setenv("CT_FILL_MODE", "dense")
     jax.clear_caches()
-    got, ovf = seeded_watershed_tiled(
+    got, ovf, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), impl="xla"
     )
     assert not bool(ovf)
@@ -235,7 +235,7 @@ def _chain_case(L, toward=1):
 @pytest.mark.parametrize("L", [20, 40])
 def test_dense_fill_deep_chain(L):
     vals, height = _chain_case(L)
-    got, unconv = fill_unseeded_basins_dense(
+    got, unconv, _ = fill_unseeded_basins_dense(
         jnp.asarray(vals), jnp.asarray(height)
     )
     assert int(unconv) == 0
@@ -252,7 +252,7 @@ def test_capacity_fill_deep_chain(L):
     )
 
     vals, height = _chain_case(L)
-    fv, ff, ovf = fill_unseeded_basins(jnp.asarray(vals), jnp.asarray(height))
+    fv, ff, ovf, _ = fill_unseeded_basins(jnp.asarray(vals), jnp.asarray(height))
     assert not bool(ovf)
     got = np.asarray(
         _resolve_codes_gather(jnp.asarray(vals), fv, ff)
@@ -280,13 +280,13 @@ def test_mode_env_flip_retraces_without_clear_caches(rng, monkeypatch):
     from cluster_tools_tpu.ops.tile_ws import _seeded_watershed_tiled_jit
 
     monkeypatch.setenv("CT_FILL_MODE", "capacity")
-    cap_out, cap_ovf = seeded_watershed_tiled(h, s, impl="xla")
+    cap_out, cap_ovf, _ = seeded_watershed_tiled(h, s, impl="xla")
     assert not bool(cap_ovf)  # the equality premise: both paths exact here
     # NO clear_caches: the env flip alone must select the dense machinery
     # — proven by a fresh jit-cache entry, not just by equal outputs
     before = _seeded_watershed_tiled_jit._cache_size()
     monkeypatch.setenv("CT_FILL_MODE", "dense")
-    dense_out, dense_ovf = seeded_watershed_tiled(h, s, impl="xla")
+    dense_out, dense_ovf, _ = seeded_watershed_tiled(h, s, impl="xla")
     assert not bool(dense_ovf)
     assert _seeded_watershed_tiled_jit._cache_size() == before + 1, (
         "env flip did not retrace: stale mode silently reused"
@@ -294,7 +294,7 @@ def test_mode_env_flip_retraces_without_clear_caches(rng, monkeypatch):
     np.testing.assert_array_equal(np.asarray(dense_out), np.asarray(cap_out))
     # the kwarg spelling is the SAME compile key as the env spelling:
     # cache size must not move (a third entry would mean key drift)
-    kw_out, kw_ovf = seeded_watershed_tiled(h, s, impl="xla", fill_mode="dense")
+    kw_out, kw_ovf, _ = seeded_watershed_tiled(h, s, impl="xla", fill_mode="dense")
     assert not bool(kw_ovf)
     assert _seeded_watershed_tiled_jit._cache_size() == before + 1, (
         "kwarg spelling compiled a separate cache entry: key drift"
@@ -465,12 +465,12 @@ def _plateau_case():
         impl="xla", tile=None, table_cap=tile_ws.DEFAULT_TABLE_CAP,
         interpret=False,
     )
-    seeds, valid, _ = tile_ws._dt_seeds_core(
+    seeds, valid, _, _ = tile_ws._dt_seeds_core(
         b, None, None, threshold=0.5, sigma_seeds=0.0, min_seed_distance=0.0,
         sampling=None, dt_max_distance=None, pair_cap=None, edge_cap=None,
         **kernels,
     )
-    vals, height, _ = tile_ws._ws_flow_core(
+    vals, height, _, _ = tile_ws._ws_flow_core(
         b, seeds, valid, exit_cap=None, **kernels
     )
     return np.asarray(vals), np.asarray(height)
@@ -557,7 +557,7 @@ _HARVEST = {
 def test_compact_table_equals_n_table(case):
     make, face_cap, raised, chunks = _EQUALITY_CASES[case]
     vals, height = make()
-    got, flag = fill_unseeded_basins_dense(
+    got, flag, _ = fill_unseeded_basins_dense(
         jnp.asarray(vals), jnp.asarray(height), face_cap=face_cap
     )
     want, want_flag, live = _fill_n_table_reference(
@@ -591,7 +591,7 @@ def test_compact_table_equals_n_table(case):
         # with room for every face the same input converges: D4's flag is
         # the lists' truncation, not the rounds' tie-break
         n = vals.size
-        _, roomy = fill_unseeded_basins_dense(
+        _, roomy, _ = fill_unseeded_basins_dense(
             jnp.asarray(vals), jnp.asarray(height), face_cap=n
         )
         assert int(roomy) == 0
@@ -612,7 +612,7 @@ def test_basin_table_overflow_raises_flag(extra_basins):
     vals = -np.arange(n, dtype=np.int32) - 2
     vals[: n - k] = 1
     height = np.random.default_rng(3).random(shape).astype(np.float32)
-    got, flag = fill_unseeded_basins_dense(
+    got, flag, _ = fill_unseeded_basins_dense(
         jnp.asarray(vals.reshape(shape)), jnp.asarray(height), face_cap=n
     )
     assert int(flag) == extra_basins
@@ -625,7 +625,7 @@ def test_code_without_terminal_raises_flag():
     fill reports it through the flag and never resolves it silently."""
     vals, height = _two_cycle_case()
     vals[1, 1, 3] = -1  # A's terminal is masked; x = 2 still carries A's code
-    _, flag = fill_unseeded_basins_dense(jnp.asarray(vals), jnp.asarray(height))
+    _, flag, _ = fill_unseeded_basins_dense(jnp.asarray(vals), jnp.asarray(height))
     assert int(flag) == 1
 
 
@@ -650,7 +650,7 @@ def test_compact_table_under_vmap(face_cap):
     the small ``face_cap`` the lanes' loops run different trip counts in
     every round."""
     vals, height, want = _two_blocks(face_cap)
-    got, flag = jax.vmap(partial(fill_unseeded_basins_dense, face_cap=face_cap))(
+    got, flag, _ = jax.vmap(partial(fill_unseeded_basins_dense, face_cap=face_cap))(
         jnp.asarray(vals), jnp.asarray(height)
     )
     for lane in range(2):
@@ -683,7 +683,7 @@ def test_compact_table_under_shard_map(check_vma, face_cap):
     vals, height, want = _two_blocks(face_cap)
 
     def body(v, h):
-        out, flag = fill_unseeded_basins_dense(v[0], h[0], face_cap=face_cap)
+        out, flag, _ = fill_unseeded_basins_dense(v[0], h[0], face_cap=face_cap)
         return out[None], lax.pmax(flag, ("dp", "sp"))
 
     spec = PartitionSpec("dp", "sp")

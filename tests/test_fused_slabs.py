@@ -199,7 +199,7 @@ def test_sharded_components_equal_one_device_components_as_a_partition():
         mesh = make_mesh(axis_names=("dp", "sp"), grid=(1, n), devices=devices[:n])
         step = make_ws_ccl_step(mesh, halo=HALO, threshold=0.5, sp_axis="sp",
                                 dt_max_distance=float(HALO), impl="auto")
-        _, cc, _, overflow = step(vol[None])
+        _, cc, _, overflow, _ = step(vol[None])
         assert not bool(overflow)
         got[n] = np.asarray(cc[0])
     many, one = got[len(devices)], got[1]

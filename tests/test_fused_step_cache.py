@@ -81,7 +81,7 @@ def volume(mesh, seed=7):
 
 
 def labels(step, x):
-    ws, cc, _, overflow = jax.block_until_ready(step(x))
+    ws, cc, _, overflow, _ = jax.block_until_ready(step(x))
     assert not bool(overflow)
     return np.asarray(ws), np.asarray(cc)
 
@@ -99,7 +99,8 @@ def tiny_step(mesh, **args):
     def step(x):
         fg = x < args["threshold"]
         ids = jnp.cumsum(fg.astype(jnp.int32), axis=-1)
-        return jnp.where(fg, ids, 0), fg.astype(jnp.int32), fg.sum(), jnp.zeros((), bool)
+        return (jnp.where(fg, ids, 0), fg.astype(jnp.int32), fg.sum(),
+                jnp.zeros((), bool), jnp.zeros((1, 1, 1), jnp.int32))
 
     return jax.jit(step)
 

@@ -271,12 +271,14 @@ def test_step_hlo_holds_every_stage_scope(monkeypatch, fill_mode, execution):
         split = make_ws_ccl_split(mesh, **kw)
         assert [f.__name__ for f in split.stages.values()] == [
             "ws_ccl_seeds", "ws_ccl_flow", "ws_ccl_fill", "ws_ccl_cc"]
-        padded, seeds, ovf = jax.eval_shape(split.stages["seeds"], x)
-        values, h, _ = jax.eval_shape(split.stages["flow"], padded, seeds, ovf)
+        padded, seeds, ovf, rec = jax.eval_shape(split.stages["seeds"], x)
+        values, h, _, _ = jax.eval_shape(
+            split.stages["flow"], padded, seeds, ovf, rec)
         text = "".join(
             split.stages[name].lower(*args).as_text(debug_info=True)
-            for name, args in (("seeds", (x,)), ("flow", (padded, seeds, ovf)),
-                               ("fill", (values, h, x, ovf)), ("cc", (x, ovf))))
+            for name, args in (("seeds", (x,)), ("flow", (padded, seeds, ovf, rec)),
+                               ("fill", (values, h, x, ovf, rec)),
+                               ("cc", (x, ovf, rec))))
     for scope in STAGE_SCOPES + (f"ws.fill.{fill_mode}",):
         # a location reads loc("ws.flow/ws.flow.chase/reshape"(...))
         assert re.search(r'["/]' + re.escape(scope) + r'["/]', text), scope

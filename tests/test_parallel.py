@@ -227,7 +227,7 @@ def test_sharded_ccl_overflow_flag():
             axis_size=sp,
             max_labels_per_shard=8,
             return_overflow=True,
-        )
+        )[:2]
 
     _, overflow = shard_map(
         body, mesh=mesh, in_specs=P("sp"), out_specs=(P("sp"), P())
@@ -244,7 +244,7 @@ def test_ws_ccl_step_shapes_and_consistency(rng):
     b, z, y, x = dp, sp * 8, 16, 16
     vol = rng.random((b, z, y, x)).astype(np.float32)
     step = make_ws_ccl_step(mesh, halo=2, threshold=0.5)
-    ws, cc, n_fg, overflow = jax.block_until_ready(step(vol))
+    ws, cc, n_fg, overflow, _ = jax.block_until_ready(step(vol))
     ws, cc = np.asarray(ws), np.asarray(cc)
     assert ws.shape == vol.shape and cc.shape == vol.shape
     assert int(n_fg) == int((cc > 0).sum())
@@ -283,7 +283,7 @@ def test_ws_ccl_step_single_device_mesh(rng, impl):
     step = make_ws_ccl_step(
         mesh, halo=2, threshold=0.5, dt_max_distance=2.0, impl=impl
     )
-    ws, cc, n_fg, overflow = jax.block_until_ready(step(vol))
+    ws, cc, n_fg, overflow, _ = jax.block_until_ready(step(vol))
     cc = np.asarray(cc)
     assert int(n_fg) == int((cc > 0).sum())
     assert not bool(overflow)
@@ -377,7 +377,7 @@ def test_ws_ccl_step_exact_edt(rng):
     b, z, y, x = dp, sp * 8, 16, 8 * sp  # x divisible by sp for the reshard
     vol = rng.random((b, z, y, x)).astype(np.float32)
     step = make_ws_ccl_step(mesh, halo=2, threshold=0.5, exact_edt=True)
-    ws, cc, n_fg, overflow = jax.block_until_ready(step(vol))
+    ws, cc, n_fg, overflow, _ = jax.block_until_ready(step(vol))
     ws, cc = np.asarray(ws), np.asarray(cc)
     assert not bool(overflow)
     assert (ws.shape == vol.shape) and int(n_fg) == int((cc > 0).sum())
@@ -427,7 +427,7 @@ def test_ws_ccl_step_stitched_fragments(rng):
     step = make_ws_ccl_step(
         mesh, halo=2, threshold=0.5, stitch_ws_threshold=0.5
     )
-    ws, cc, n_fg, overflow = jax.block_until_ready(step(vol))
+    ws, cc, n_fg, overflow, _ = jax.block_until_ready(step(vol))
     ws = np.asarray(ws)
     assert not bool(overflow)
     slab = z // sp
@@ -462,7 +462,7 @@ def test_ws_ccl_step_stitched_with_compaction(rng):
         mesh, halo=2, threshold=0.5, stitch_ws_threshold=0.5,
         max_labels_per_shard=2048,
     )
-    ws, cc, n_fg, overflow = jax.block_until_ready(step(vol))
+    ws, cc, n_fg, overflow, _ = jax.block_until_ready(step(vol))
     assert not bool(overflow)
     ws = np.asarray(ws)
     slab = z // sp
@@ -490,7 +490,7 @@ def test_ws_ccl_step_two_axis_decomposition(rng):
         mesh, halo=2, threshold=0.5, sp_axis=("spz", "spy"),
         stitch_ws_threshold=0.5, max_labels_per_shard=4096,
     )
-    ws, cc, n_fg, overflow = jax.block_until_ready(step(vol))
+    ws, cc, n_fg, overflow, _ = jax.block_until_ready(step(vol))
     ws, cc = np.asarray(ws), np.asarray(cc)
     assert not bool(overflow)
     assert int(n_fg) == int((cc > 0).sum())
@@ -530,7 +530,7 @@ def test_ws_ccl_step_two_axis_exact_edt(rng):
     step = make_ws_ccl_step(
         mesh, halo=2, threshold=0.5, sp_axis=("spz", "spy"), exact_edt=True,
     )
-    ws, cc, n_fg, overflow = jax.block_until_ready(step(vol))
+    ws, cc, n_fg, overflow, _ = jax.block_until_ready(step(vol))
     assert not bool(overflow)
     assert int(n_fg) == int((np.asarray(cc) > 0).sum())
 
@@ -569,7 +569,7 @@ def test_replicated_outputs_fence(rng):
     step = make_ws_ccl_step(
         mesh, halo=2, threshold=0.5, stitch_ws_threshold=0.5,
     )
-    ws, cc, n_fg, overflow = jax.block_until_ready(step(vol))
+    ws, cc, n_fg, overflow, _ = jax.block_until_ready(step(vol))
     _assert_shards_identical(n_fg, "n_foreground")
     _assert_shards_identical(overflow, "overflow")
 

@@ -476,8 +476,12 @@ def test_chase_reader_returns_nothing_without_the_scope(traced, which):
 def test_chase_metric_is_declared_for_every_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    (mine,) = [m for m in bench["per_layer"] if m["name"] == "ws_flow_chase_device_s"]
-    assert bench["per_layer"][-1] is mine
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    mine = by_name["ws_flow_chase_device_s"]
+    # the work record's three readers (PR 39) read the same four cells
+    for name in ("capacity_fallbacks", "capacity_peak_fill", "live_slot_share"):
+        assert by_name[name]["workloads"] == mine["workloads"]
+        assert by_name[name]["source"] == "program_span"
     with open(os.path.join(ROOT, "benchmark", "metrics",
                            "ws_flow_chase_device_s.json")) as f:
         meta = json.load(f)

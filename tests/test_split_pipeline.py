@@ -34,8 +34,10 @@ def _run_both(mesh, vol, **kw):
 
 
 def _assert_same(f, s):
-    ws_f, cc_f, n_f, ov_f = f
-    ws_s, cc_s, n_s, ov_s = s
+    ws_f, cc_f, n_f, ov_f, rec_f = f
+    ws_s, cc_s, n_s, ov_s, rec_s = s
+    # the stages' records, merged from program to program, are the step's
+    np.testing.assert_array_equal(np.asarray(rec_s), np.asarray(rec_f))
     np.testing.assert_array_equal(np.asarray(ws_s), np.asarray(ws_f))
     np.testing.assert_array_equal(np.asarray(cc_s), np.asarray(cc_f))
     assert int(n_s) == int(n_f)
@@ -107,7 +109,7 @@ def test_split_overflow_flag_propagates(rng):
     split = make_ws_ccl_split(
         mesh, halo=2, threshold=0.5, max_labels_per_shard=4
     )
-    *_, overflow = jax.block_until_ready(split(vol))
+    *_, overflow, _ = jax.block_until_ready(split(vol))
     assert bool(overflow)
 
 

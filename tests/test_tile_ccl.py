@@ -27,7 +27,7 @@ from .helpers import assert_labels_equivalent, random_blobs
 
 
 def _check(mask, **kw):
-    lab, overflow = label_components_tiled(jnp.asarray(mask), **kw)
+    lab, overflow, _ = label_components_tiled(jnp.asarray(mask), **kw)
     assert not bool(overflow)
     lab = np.asarray(lab)
     n = mask.size
@@ -80,10 +80,10 @@ def test_tiled_blobs(rng):
 @pytest.mark.parametrize("shape", [(16, 16, 128), (32, 16, 128), (8, 8, 16)])
 def test_tiled_empty_full(shape):
     empty = np.zeros(shape, bool)
-    lab, ovf = label_components_tiled(jnp.asarray(empty), impl="xla")
+    lab, ovf, _ = label_components_tiled(jnp.asarray(empty), impl="xla")
     assert not bool(ovf) and (np.asarray(lab) == empty.size).all()
     full = np.ones(shape, bool)
-    lab, ovf = label_components_tiled(jnp.asarray(full), impl="xla")
+    lab, ovf, _ = label_components_tiled(jnp.asarray(full), impl="xla")
     assert not bool(ovf)
     lab = np.asarray(lab)
     assert len(np.unique(lab)) == 1  # one component
@@ -170,15 +170,15 @@ def test_resolve_impl(monkeypatch, impl, backend, want):
 
 def test_tiled_alias_runs_the_xla_kernels(rng):
     mask = jnp.asarray(rng.random((20, 24, 130)) < 0.4)
-    a, _ = label_components_tiled(mask, impl="tiled")
-    b, _ = label_components_tiled(mask, impl="xla")
+    a, _, _ = label_components_tiled(mask, impl="tiled")
+    b, _, _ = label_components_tiled(mask, impl="xla")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_tiled_overflow_flag(rng):
     # absurdly small capacities must raise the overflow flag, not mislabel
     mask = rng.random((32, 32, 256)) < 0.5
-    _, overflow = label_components_tiled(
+    _, overflow, _ = label_components_tiled(
         jnp.asarray(mask), impl="xla", pair_cap=16, edge_cap=8
     )
     assert bool(overflow)
@@ -189,7 +189,7 @@ def test_tiled_spanning_component():
     mask = np.zeros((16, 16, 512), bool)
     mask[8, 8, :] = True
     mask[3, 3, 5] = True
-    lab, ovf = label_components_tiled(jnp.asarray(mask), impl="xla")
+    lab, ovf, _ = label_components_tiled(jnp.asarray(mask), impl="xla")
     assert not bool(ovf)
     lab = np.asarray(lab)
     line = lab[8, 8, :]
@@ -245,7 +245,7 @@ def test_pallas_kernels_interpret(rng):
 def test_tiled_full_pallas_interpret(rng):
     # end-to-end tiled CCL with the pallas impl in interpret mode
     mask = rng.random((16, 32, 256)) < 0.4
-    lab, ovf = label_components_tiled(jnp.asarray(mask), impl="pallas", interpret=True)
+    lab, ovf, _ = label_components_tiled(jnp.asarray(mask), impl="pallas", interpret=True)
     assert not bool(ovf)
     ref, _ = ndi.label(mask, structure=ndi.generate_binary_structure(3, 1))
     assert_labels_equivalent(

@@ -27,7 +27,7 @@ def test_all_minima_seeded_matches_legacy(rng):
     seeds = np.zeros(shape, np.int32)
     seeds[minima] = np.arange(1, minima.sum() + 1)
     legacy = np.asarray(seeded_watershed(jnp.asarray(height), jnp.asarray(seeds)))
-    got, ovf = seeded_watershed_tiled(
+    got, ovf, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), impl="xla"
     )
     assert not bool(ovf)
@@ -40,7 +40,7 @@ def test_all_voxels_labeled_sparse_seeds(rng):
     seeds = np.zeros(shape, np.int32)
     seeds[4, 4, 10] = 1
     seeds[20, 20, 100] = 2
-    got, ovf = seeded_watershed_tiled(
+    got, ovf, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), impl="xla"
     )
     assert not bool(ovf)
@@ -57,7 +57,7 @@ def test_regions_connected(rng):
     seeds[2, 2, 10] = 1
     seeds[17, 17, 100] = 2
     seeds[2, 17, 60] = 3
-    got, _ = seeded_watershed_tiled(
+    got, _, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), impl="xla"
     )
     got = np.asarray(got)
@@ -76,7 +76,7 @@ def test_respects_mask(rng):
     seeds = np.zeros(shape, np.int32)
     seeds[8, 8, 10] = 1
     seeds[8, 8, 100] = 2
-    got, _ = seeded_watershed_tiled(
+    got, _, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), jnp.asarray(mask), impl="xla"
     )
     got = np.asarray(got)
@@ -96,7 +96,7 @@ def test_unreachable_basin_stays_zero(rng):
     mask[[4, 8], 4:9, 31:40] = False
     seeds = np.zeros(shape, np.int32)
     seeds[1, 1, 1] = 1
-    got, _ = seeded_watershed_tiled(
+    got, _, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), jnp.asarray(mask), impl="xla"
     )
     got = np.asarray(got)
@@ -116,10 +116,10 @@ def test_pallas_interpret_matches_xla(rng):
     pts = rng.integers(0, [16, 32, 128], size=(5, 3))
     for i, p in enumerate(pts):
         seeds[tuple(p)] = i + 1
-    a, ovf_a = seeded_watershed_tiled(
+    a, ovf_a, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), impl="xla"
     )
-    b, ovf_b = seeded_watershed_tiled(
+    b, ovf_b, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), impl="pallas", interpret=True
     )
     assert not bool(ovf_a) and not bool(ovf_b)
@@ -135,7 +135,7 @@ def test_overflow_flag(rng, monkeypatch):
     height = rng.random((32, 32, 128)).astype(np.float32)
     seeds = np.zeros((32, 32, 128), np.int32)
     seeds[0, 0, 0] = 1
-    _, ovf = seeded_watershed_tiled(
+    _, ovf, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), impl="xla",
         exit_cap=8, fill_cap=8,
     )
@@ -187,19 +187,44 @@ def _chase_codes(values, n_live, seed, first_voxel=None):
 
 
 def _chase_oracle(values, codes, max_hops=None):
-    """(finals, gathers of the longest chain): each live code followed alone
-    in numpy; a chain past ``max_hops`` gathers keeps its code."""
-    finals, longest = codes.copy(), 0
+    """(finals, gathers of the longest chain, the chase's work counts): each
+    live code followed alone in numpy; a chain past ``max_hops`` gathers
+    keeps its code.  The counts are what ``chase_exits`` records (names from
+    ``ops/work.py``): a hop gathers for every chain still running, in trips
+    of a sixteenth of the buffer; the live sum in units of 16, every hop
+    rounded up; a hop of one trip is a hop of the tail."""
+    from cluster_tools_tpu.ops import work
+
+    finals, lengths = codes.copy(), []
     for i, code in enumerate(codes):
         if code > -2:
             continue
         g, hops = -code - 2, 1
         while values[g] <= -2 and values[g] != -g - 2:
             g, hops = -values[g] - 2, hops + 1
-        longest = max(longest, hops)
+        lengths.append(hops)
         if max_hops is None or hops <= max_hops:
             finals[i] = values[g]
-    return finals, longest
+    longest = max(lengths, default=0)
+    chunk = -(-len(codes) // 16)
+    running = [sum(n >= hop for n in lengths)
+               for hop in range(1, min(longest, max_hops or longest) + 1)]
+    trips = [-(-n // chunk) for n in running]
+    unit = work.UNITS[work.FLOW_CHASE_LIVE]
+    counts = {
+        work.FLOW_CHASE_HOPS: len(running),
+        work.FLOW_CHASE_TRIPS: sum(trips),
+        work.FLOW_CHASE_LIVE: sum(-(-n // unit) for n in running),
+        work.FLOW_CHASE_TAIL_HOPS: sum(t == 1 for t in trips),
+    }
+    return finals, longest, counts
+
+
+def _assert_counts(got, want, lane=None):
+    """A part of a work record (traced scalars by name) against ints."""
+    got = {k: int(np.asarray(v) if lane is None else np.asarray(v)[lane])
+           for k, v in got.items()}
+    assert got == want
 
 
 @pytest.mark.parametrize("chains", ["flat", "tail"])
@@ -215,15 +240,17 @@ def test_chase_exits_matches_oracle(n_live, chains):
     codes = _chase_codes(
         values, n_live, seed=n_live, first_voxel=0 if chains == "tail" else None
     )
-    want, longest = _chase_oracle(values, codes)
+    want, longest, want_counts = _chase_oracle(values, codes)
     assert longest == (0 if n_live == 0 else
                        1 if chains == "flat" else _CHASE_LONGEST)
-    finals, unconverged = chase_exits(
+    finals, unconverged, counts = chase_exits(
         jnp.asarray(values.reshape(16, 16, 16)), jnp.asarray(codes)
     )
     assert not bool(unconverged)
     # the live slots' finals, and padding / non-active slots untouched
     np.testing.assert_array_equal(np.asarray(finals), want)
+    # and the work it says it did: hops, trips, live chains, tail hops
+    _assert_counts(counts, want_counts)
 
 
 @pytest.mark.parametrize("max_hops,flag", [(_CHASE_LONGEST - 1, True),
@@ -235,14 +262,15 @@ def test_chase_exits_unconverged_flag(max_hops, flag):
 
     values = _chase_volume("tail")
     codes = _chase_codes(values, 200, seed=7, first_voxel=0)
-    want, longest = _chase_oracle(values, codes, max_hops=max_hops)
+    want, longest, want_counts = _chase_oracle(values, codes, max_hops=max_hops)
     assert longest == _CHASE_LONGEST
-    finals, unconverged = chase_exits(
+    finals, unconverged, counts = chase_exits(
         jnp.asarray(values.reshape(16, 16, 16)), jnp.asarray(codes),
         max_hops=max_hops,
     )
     assert bool(unconverged) == flag
     np.testing.assert_array_equal(np.asarray(finals), want)
+    _assert_counts(counts, want_counts)
 
 
 def test_chase_exits_lanes_match_alone():
@@ -258,14 +286,17 @@ def test_chase_exits_lanes_match_alone():
         _chase_codes(v, n_live, seed=i, first_voxel=first)
         for i, (v, (_, n_live, first)) in enumerate(zip(values, lanes))
     ])
-    finals, unconverged = jax.vmap(chase_exits)(
+    finals, unconverged, counts = jax.vmap(chase_exits)(
         jnp.asarray(values.reshape(-1, 16, 16, 16)), jnp.asarray(codes)
     )
     assert not np.asarray(unconverged).any()
     for lane, (v, c) in enumerate(zip(values, codes)):
-        alone, _ = chase_exits(jnp.asarray(v.reshape(16, 16, 16)), jnp.asarray(c))
+        alone, _, _ = chase_exits(jnp.asarray(v.reshape(16, 16, 16)), jnp.asarray(c))
         np.testing.assert_array_equal(np.asarray(finals)[lane], np.asarray(alone))
-        np.testing.assert_array_equal(np.asarray(alone), _chase_oracle(v, c)[0])
+        want, _, want_counts = _chase_oracle(v, c)
+        np.testing.assert_array_equal(np.asarray(alone), want)
+        # a lane's counts are its own, whatever the longest lane ran
+        _assert_counts(counts, want_counts, lane)
 
 
 # The capacity tiers choose at run time between one machine at two sizes, so
@@ -332,7 +363,7 @@ def test_collect_negative_values_tier_matches_oracle(rng, p, fits):
     small_n = max(3 * 16384, (4 * 32768 + 2 * 4096) // 16)
     assert small_n < cap and (n_total <= small_n) == fits
 
-    cv, ct, overflow = collect_negative_values(jnp.asarray(values), tile, cap)
+    cv, ct, overflow, _, _ = collect_negative_values(jnp.asarray(values), tile, cap)
     cv, ct = np.asarray(cv), np.asarray(ct)
     assert not bool(overflow)
     tid = (idx[0] // 16 * (shape[1] // 16) + idx[1] // 16) * (shape[2] // 128) \
@@ -356,7 +387,7 @@ def test_sparse_seed_noise_fill_knobs(rng, monkeypatch):
     seeds = np.zeros((64, 64, 64), np.int32)
     seeds[8, 8, 8] = 1
     seeds[50, 50, 50] = 2
-    seg, ovf = seeded_watershed_tiled(
+    seg, ovf, _ = seeded_watershed_tiled(
         jnp.asarray(height), jnp.asarray(seeds), impl="xla",
         # measured at this size/seed: ~154k face voxels per axis, ~273k
         # unique adjacencies, ~38k unseeded basins -> 2^19 caps fit
@@ -381,7 +412,7 @@ def test_dt_watershed_seeded_tiled_external_encoding(rng):
     b[:, :, 60:68] = 0.95  # a wall splits the volume in x
     ext = np.zeros(shape, np.int32)
     ext[2:6, 2:6, 2:6] = 3  # pass-one neighbor label (dense id 3)
-    lab, ovf = dt_watershed_seeded_tiled(
+    lab, ovf, _ = dt_watershed_seeded_tiled(
         jnp.asarray(b), jnp.asarray(ext), threshold=0.5, impl="xla"
     )
     assert not bool(ovf)
@@ -407,10 +438,10 @@ def test_dt_watershed_tiled_precomputed_dist_identity(rng):
     vol = rng.random((24, 16, 16)).astype(np.float32)
     fg = jnp.asarray(vol < 0.5)
     dist = distance_transform_squared(fg, max_distance=4.0)
-    internal, ovf1 = dt_watershed_tiled(
+    internal, ovf1, _ = dt_watershed_tiled(
         jnp.asarray(vol), threshold=0.5, dt_max_distance=4.0, impl="xla"
     )
-    supplied, ovf2 = dt_watershed_tiled(
+    supplied, ovf2, _ = dt_watershed_tiled(
         jnp.asarray(vol), threshold=0.5, dist=dist, impl="xla"
     )
     np.testing.assert_array_equal(np.asarray(internal), np.asarray(supplied))

@@ -140,6 +140,32 @@ def test_the_passes_and_pass_twos_host_work_are_spans(job):
     assert all(a["n_ext"] > 0 and a["nbytes"] > 0 for a in by_name["ws2.ext_seeds"])
 
 
+def test_a_pass_carries_one_work_record_a_real_lane(job):
+    """The kernels' work records (``ops/work.py``) ride the executor's
+    plumbing beside the labels: the ``ws.pass`` span keeps one row a block
+    that ran, none for a padding lane, and the pass's manifest their sum."""
+    from cluster_tools_tpu.ops import work
+
+    passes = [ev["args"] for ev in job["spans"]
+              if ev.get("ph") == "X" and ev["name"] == "ws.pass"]
+    with open(os.path.join(job["tmp"], f"{job['uid']}.success.json")) as f:
+        manifest = json.load(f)["passes"]
+    blocks = dict(ref2.blocks_of(SHAPE, BLOCK))
+    for args, doc in zip(passes, manifest.values()):
+        rows = args["work"]
+        of_parity = sorted(n for n, pos in blocks.items()
+                           if ref2.parity_of(pos) == args["parity"])
+        assert [r["block"] for r in rows] == of_parity and len(rows) == 18
+        assert args["lanes"] > len(rows)          # the padding lanes are gone
+        assert all(set(work.NAMES) <= set(r) for r in rows)
+        assert all(r[work.FLOW_CHASE_HOPS] >= 0 and r[work.FILL_ROUNDS] >= 1
+                   and r[work.CAP_EXIT] > 0 for r in rows)
+        assert not any(r[n] > 0 for r in rows for n in work.OVER)
+        assert doc["work"] == work.total(rows)
+        assert doc["work"][work.FLOW_EXITS] == sum(r[work.FLOW_EXITS] for r in rows)
+        assert doc["work"][work.CAP_EXIT] == rows[0][work.CAP_EXIT]
+
+
 def test_the_checkerboard_refuses_two_d_and_agglomeration_in_one_place():
     from cluster_tools_tpu.tasks import watershed as ws_mod
 
@@ -180,7 +206,7 @@ def test_seeded_kernel_pallas_and_xla_twins_are_bit_identical(seed):
               fill_mode="dense")
     got = {}
     for impl, interpret in (("xla", False), ("pallas", True)):
-        lab, ovf = dt_watershed_seeded_tiled(
+        lab, ovf, _ = dt_watershed_seeded_tiled(
             jnp.asarray(vol), jnp.asarray(ext), impl=impl, interpret=interpret, **kw)
         assert not bool(ovf)
         got[impl] = np.asarray(lab)
@@ -195,7 +221,7 @@ def test_seeded_kernel_floods_as_the_reference_does(seed):
     from cluster_tools_tpu.ops.tile_ws import dt_watershed_seeded_tiled
 
     vol, ext = _unit(seed)
-    lab, _ = dt_watershed_seeded_tiled(
+    lab, _, _ = dt_watershed_seeded_tiled(
         jnp.asarray(vol), jnp.asarray(ext), impl="xla", threshold=0.5,
         sampling=(10.0, 1.0, 1.0), dt_max_distance=8.0, fill_mode="dense")
     lab = np.asarray(lab)
