@@ -1,31 +1,47 @@
-"""The compiled mesh step, kept: in the process and beside the compile cache.
+"""Compiled programs, kept: in the process and beside the compile cache.
 
-``tasks/fused.py`` used to get a fresh ``jax.jit`` of the step for every
-job, so every job traced and lowered the whole program to find out which
-persistent-cache entry was its own, then read that entry back.  Here the
-compiled step gets a key made of what it is *built from* (computable in
-milliseconds, nothing traced), and is looked up under it before anything is
-traced:
+Two kinds of program live here: the fused mesh step (``tasks/fused.py``,
+:func:`step_for`) and the executor's sharded sweep programs
+(``runtime/executor.py``, :func:`program_for`; the two-pass watershed's
+``jit_sharded_ws_block`` / ``jit_sharded_ws_block_seeded`` among them).
+Both used to be made afresh for every job, so every job traced and lowered
+each program to find out which persistent-cache entry was its own, then
+read that entry back.  Here a compiled program gets a key made of what it
+is *built from* (computable in milliseconds, nothing traced), and is looked
+up under it before anything is traced:
 
 1. in the process: a two-entry LRU (``runtime/executor.py::ProgramCache``;
-   two, because a loaded program's temporaries stay reserved on the chip);
+   two, because a loaded program's temporaries stay reserved on the chip;
+   the two passes' programs fill it);
 2. in the step store, ``<compile cache dir>/steps/<key>``: the executable as
    ``jax.experimental.serialize_executable`` writes it (compressed, as
    JAX's own entries are), with its trees and the key document, loaded
    straight onto the mesh's devices;
 3. else built as before (``make_ws_ccl_step(...).lower(x).compile()``,
-   through JAX's own persistent cache) and written to the store.
+   ``batched_shard_map(...).lower(*xs).compile()``, through JAX's own
+   persistent cache) and written to the store.
+
+A sweep program comes here only where its kernel's identity freezes
+(``runtime/executor.py::kernel_identity``: code and captured values, all
+plain), and its key holds a digest of that identity: two kernels that read
+the same values share a program, a kernel that reads another threshold or
+capacity does not, and a kernel that captures an array or a dataset stays
+in its executor's own cache.  What the process keeps is the compiled
+program, which holds nothing a task owns.
 
 The store exists where the process has a persistent compile cache directory
-and nowhere else; nothing switches it.  Every way out of it is the build: a
+and nowhere else; nothing switches it.  It keeps :data:`STORE_STEPS` = 4
+entries: one tree's one-chip cells write three (the fused step, shared by
+``fused384.volumes`` and ``multicut384.volumes``, and the two sweep
+programs of ``twopass125.volumes``).  Every way out of it is the build: a
 missing, truncated, foreign or mismatching entry, a ``serialize`` or a load
-that raises all end in a built step and an overwritten entry.  A wrong hit
-would be silently wrong labels, so the key document holds everything that
-reaches the lowering (:func:`key_document`) and is compared field by field
-on a hit, not only by its hash.  The key sees *files*: code patched in
-memory (a test's ``monkeypatch`` of the program) is invisible to it, so
-such a test runs its jobs with no store and calls :func:`forget` around
-them (``tests/helpers.py::fused_step_built_here``).
+that raises all end in a built program and an overwritten entry.  A wrong
+hit would be silently wrong labels, so the key document holds everything
+that reaches the lowering (:func:`key_document`) and is compared field by
+field on a hit, not only by its hash.  The key sees *files* and captured
+values: code patched in memory (a test's ``monkeypatch`` of ``ops/*``) is
+invisible to it, so such a test runs its jobs with no store and calls
+:func:`forget` around them (``tests/helpers.py::programs_built_here``).
 Clearing the store by hand is deleting ``<compile cache dir>/steps/``.
 """
 
@@ -50,7 +66,7 @@ from ..runtime import trace as trace_mod
 
 #: the package whose sources the key digests
 PACKAGE_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-#: ready steps a process keeps: a loaded program's temporaries stay
+#: ready programs a process keeps: a loaded program's temporaries stay
 #: reserved on the chip (4.8 GB for the 384^3 step)
 PROCESS_STEPS = 2
 #: entries the store keeps (80-100 MiB each at 384^3); JAX's own size
@@ -75,22 +91,32 @@ def package_digest(root: str = PACKAGE_ROOT) -> str:
     return h.hexdigest()
 
 
+def _describe(x) -> dict:
+    return {"shape": list(x.shape), "dtype": str(np.dtype(x.dtype)),
+            "spec": list(x.sharding.spec)}
+
+
 def key_document(mesh, x, execution: str, build_args: dict,
                  package_root: str = PACKAGE_ROOT) -> dict:
-    """Everything that can change the step compiled for input ``x`` (an
-    array or a ``jax.ShapeDtypeStruct`` with its ``NamedSharding``), as
-    plain JSON: the package's sources, JAX and the backend's build, the mesh
-    (axis names, shape, device ids in order), the input (shape, dtype,
-    partition spec), ``execution`` and every argument of the step's builder,
-    what the kernel switches resolved to (``ops/tile_ws.py::resolved_modes``,
-    which carries ``CT_FILL_MODE``), and what else reaches the lowering from
-    outside the arguments."""
+    """Everything that can change the program compiled for input ``x`` (an
+    array or a ``jax.ShapeDtypeStruct`` with its ``NamedSharding``; a tuple
+    of them for a program of several inputs), as plain JSON: the package's
+    sources, JAX and the backend's build, the mesh (axis names, shape,
+    device ids in order), the inputs (shape, dtype, partition spec),
+    ``execution`` and every argument of the program's builder, what the
+    kernel switches resolved to (``ops/tile_ws.py::resolved_modes`` of the
+    builder's ``impl``, which carries ``CT_FILL_MODE``; a builder without
+    one is an executor kernel, whose own ``impl`` is in its identity and
+    whose resolution reads what ``"auto"``'s does), and what else reaches
+    the lowering from outside the arguments."""
     import jaxlib
 
     from ..ops.tile_ws import resolved_modes
 
     devices = list(mesh.devices.flat)
     client = devices[0].client
+    inputs = ({"inputs": [_describe(a) for a in x]} if isinstance(x, tuple)
+              else {"input": _describe(x)})
     doc = {
         "sources": package_digest(package_root),
         "jax": jax.__version__,
@@ -101,12 +127,11 @@ def key_document(mesh, x, execution: str, build_args: dict,
         "mesh": {"axis_names": list(mesh.axis_names),
                  "shape": list(mesh.devices.shape),
                  "device_ids": [d.id for d in devices]},
-        "input": {"shape": list(x.shape), "dtype": str(np.dtype(x.dtype)),
-                  "spec": list(x.sharding.spec)},
+        **inputs,
         "execution": execution,
         "build": dict(build_args),
         # "auto" resolves by jax.default_backend(), not by the mesh's devices
-        "modes": resolved_modes(build_args["impl"]),
+        "modes": resolved_modes(build_args.get("impl", "auto")),
         "lowering": {
             "jax_enable_x64": bool(jax.config.jax_enable_x64),
             "jax_default_matmul_precision": jax.config.jax_default_matmul_precision,
@@ -151,9 +176,11 @@ def _codec():
 def load(directory: str, key: str, document: dict, x, note=lambda **kw: None
          ) -> Tuple[object, int]:
     """The entry ``key`` as a loaded ``jax.stages.Compiled`` for ``x``'s
-    devices, and the entry's bytes; ``(None, 0)`` where there is none.
-    Raises :class:`Unusable` for an entry that cannot be trusted.  ``note``
-    takes the seconds of the pieces (read, decompress, deserialize + load)."""
+    devices (``x`` an input or a tuple of them, as for
+    :func:`key_document`), and the entry's bytes; ``(None, 0)`` where there
+    is none.  Raises :class:`Unusable` for an entry that cannot be trusted.
+    ``note`` takes the seconds of the pieces (read, decompress, deserialize
+    + load)."""
     from jax.experimental.serialize_executable import deserialize_and_load
 
     path = os.path.join(directory, key)
@@ -188,14 +215,16 @@ def load(directory: str, key: str, document: dict, x, note=lambda **kw: None
     except Exception as e:
         raise Unusable(f"damaged:{type(e).__name__}")
     t2 = time.monotonic()
-    devices = list(x.sharding.mesh.devices.flat)
+    xs = x if isinstance(x, tuple) else (x,)
+    devices = list(xs[0].sharding.mesh.devices.flat)
     try:
         step = deserialize_and_load(payload, *trees, backend=devices[0].client,
                                     execution_devices=devices)
-        (expects,), _ = step.input_shardings
+        expects, _ = step.input_shardings
     except Exception as e:   # whatever the backend refuses: build instead
         raise Unusable(f"load:{type(e).__name__}")
-    if not expects.is_equivalent_to(x.sharding, x.ndim):
+    if len(expects) != len(xs) or not all(
+            e.is_equivalent_to(a.sharding, a.ndim) for e, a in zip(expects, xs)):
         raise Unusable("load:input_sharding")
     note(read_s=round(t1 - t0, 6), decompress_s=round(t2 - t1, 6),
          deserialize_load_s=round(time.monotonic() - t2, 6),
@@ -260,7 +289,7 @@ def _process_level():
 
 
 def forget() -> None:
-    """Drop every ready step of the process: for tests that patch the
+    """Drop every ready program of the process: for tests that patch the
     program in memory, which the key cannot see (the store they switch off
     by running without a persistent compile cache)."""
     global _process
@@ -278,31 +307,54 @@ def step_for(mesh, x, execution: str, builder: Callable, build_args: dict
     task's manifest carries it; spans ``fused.step_load`` /
     ``fused.step_build`` / ``fused.step_store`` lie around the three pieces
     of work."""
-    document = key_document(mesh, x, execution, build_args)
+    return _ready(key_document(mesh, x, execution, build_args), "fused.step",
+                  lambda: builder(mesh, **build_args),
+                  (x,) if execution == "fused" else None)
+
+
+def program_for(mesh, xs: tuple, kernel_digest: str, batch: int,
+                builder: Callable) -> Tuple[Callable, Dict[str, object]]:
+    """The executor's sharded sweep program ``builder()`` (a jitted
+    function of the stacked batch ``xs``) compiled for ``xs``: from the
+    process, else from the store, else built; ``kernel_digest`` is
+    :func:`~cluster_tools_tpu.runtime.executor.identity_digest` of the
+    kernel it maps.  Returns ``(program, info)`` as :func:`step_for` does;
+    spans ``executor.program_load`` / ``_build`` / ``_store``."""
+    document = key_document(mesh, tuple(xs), "sharded",
+                            {"kernel": kernel_digest, "batch": int(batch)})
+    return _ready(document, "executor.program", builder, tuple(xs))
+
+
+def _ready(document: dict, span: str, build: Callable, compile_for
+           ) -> Tuple[Callable, Dict[str, object]]:
+    """The lookup behind :func:`step_for` and :func:`program_for`: the
+    process level under the document's digest, then (where ``compile_for``,
+    the arguments to compile for, is given) the store, then ``build()``,
+    compiled for ``compile_for`` and written to the store."""
     key = digest(document)
     info = {"from": "process", "key": key, "load_s": 0.0, "store_bytes": 0,
             "fallback": None}
 
     def on_miss():
-        directory = store_dir() if execution == "fused" else None
+        directory = store_dir() if compile_for is not None else None
         step = None
         if directory is not None:
-            step = _read_store(directory, key, document, x, info)
+            step = _read_store(directory, key, document, compile_for, info, span)
         if step is not None:
             info["from"] = "store"
             return step
         info["from"] = "built"
         compiles = trace_mod.compile_snapshot()
-        with trace_mod.span("fused.step_build", key=key):
-            step = builder(mesh, **build_args)
-            if execution == "fused":
-                step = step.lower(x).compile()
+        with trace_mod.span(span + "_build", key=key):
+            step = build()
+            if compile_for is not None:
+                step = step.lower(*compile_for).compile()
         if directory is not None:
             handed_over = trace_mod.compile_delta(compiles)["cache_hits"] > 0
-            _write_store(directory, key, document, step, handed_over, info)
+            _write_store(directory, key, document, step, handed_over, info, span)
         return step
 
-    step = _process_level().get_or_build(None, "fused_step", (key,), on_miss)
+    step = _process_level().get_or_build(None, span, (key,), on_miss)
     counter = {"process": "process_hits", "store": "store_hits",
                "built": "builds"}[info["from"]]
     with _lock:
@@ -311,13 +363,15 @@ def step_for(mesh, x, execution: str, builder: Callable, build_args: dict
     return step, info
 
 
-def _read_store(directory: str, key: str, document: dict, x, info: dict):
-    """The entry as a loaded step, or None with ``info["fallback"]`` naming
-    why an entry that is there cannot be used."""
+def _read_store(directory: str, key: str, document: dict, xs: tuple,
+                info: dict, span: str):
+    """The entry as a loaded program, or None with ``info["fallback"]``
+    naming why an entry that is there cannot be used."""
     step = None
-    with trace_mod.begin("fused.step_load", key=key) as sp:
+    with trace_mod.begin(span + "_load", key=key) as sp:
         try:
-            step, info["store_bytes"] = load(directory, key, document, x, note=sp.note)
+            step, info["store_bytes"] = load(directory, key, document, xs,
+                                             note=sp.note)
             sp.note(nbytes=info["store_bytes"])
         except Unusable as e:
             info["fallback"] = str(e)
@@ -326,7 +380,7 @@ def _read_store(directory: str, key: str, document: dict, x, info: dict):
 
 
 def _write_store(directory: str, key: str, document: dict, step,
-                 handed_over: bool, info: dict) -> None:
+                 handed_over: bool, info: dict, span: str) -> None:
     """Write the built step as entry ``key``; where that cannot be done,
     ``info["fallback"]`` says why (after the reason the store was left
     for, if there was one), and the entry that could not be used goes.
@@ -340,7 +394,7 @@ def _write_store(directory: str, key: str, document: dict, step,
         not_stored = "store:deserialized_executable"
     else:
         not_stored = None
-        with trace_mod.span("fused.step_store", key=key) as sp:
+        with trace_mod.span(span + "_store", key=key) as sp:
             try:
                 info["store_bytes"] = save(directory, key, document, step)
                 sp.note(nbytes=info["store_bytes"])

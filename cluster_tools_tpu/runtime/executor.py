@@ -82,6 +82,7 @@ from __future__ import annotations
 import contextlib
 import errno
 import functools
+import hashlib
 import inspect
 import itertools
 import math
@@ -129,6 +130,7 @@ from ..parallel.batch_shard import (
 )
 from ..parallel import block_pool as block_pool_mod
 from ..parallel import device_pool as device_pool_mod
+from ..parallel import step_cache
 
 
 # -- process-wide dispatch metrics -------------------------------------------
@@ -302,6 +304,50 @@ def kernel_identity(kernel: Callable) -> Optional[tuple]:
         return _freeze(kernel, set())
     except _Unfreezable:
         return None
+
+
+def identity_digest(identity: tuple) -> str:
+    """sha256 of a :func:`kernel_identity`, the same in every process: a
+    frozen set's elements are put in order first (their iteration order
+    follows string hashing, which differs from process to process)."""
+
+    def canonical(v):
+        if isinstance(v, frozenset):
+            return ("set", tuple(sorted((canonical(e) for e in v), key=repr)))
+        if isinstance(v, tuple):
+            return tuple(canonical(e) for e in v)
+        return v
+
+    return hashlib.sha256(repr(canonical(identity)).encode()).hexdigest()
+
+
+class _KeptSweepProgram:
+    """The sharded sweep program of a kernel whose identity freezes, kept
+    across executors, tasks and jobs by ``parallel/step_cache.py``: looked
+    up at its first call for each input signature, from the stacked batch's
+    shapes, dtypes and shardings, in the process, then in the step store,
+    and only then built (``batched_shard_map(...).lower(...).compile()``).
+    What the process keeps is the compiled program alone: the kernel's
+    captured values are plain (that is what freezing them proved), so
+    nothing a task owns outlives it.  ``info`` is the last look-up's
+    ``{from, key, load_s, store_bytes, fallback}``."""
+
+    def __init__(self, kernel: Callable, identity: tuple, mesh: Mesh, batch: int):
+        self._kernel, self._mesh, self._batch = kernel, mesh, int(batch)
+        self._digest = identity_digest(identity)
+        self._ready: Dict[tuple, Callable] = {}
+        self.info: Optional[Dict[str, Any]] = None
+
+    def __call__(self, *args):
+        signature = tuple((a.shape, a.dtype, a.sharding) for a in args)
+        program = self._ready.get(signature)
+        if program is None:
+            program, self.info = step_cache.program_for(
+                self._mesh, args, self._digest, self._batch,
+                lambda: batched_shard_map(self._kernel, self._mesh, self._batch),
+            )
+            self._ready[signature] = program
+        return program(*args)
 
 
 class ProgramCache:
@@ -651,9 +697,14 @@ class BlockwiseExecutor:
         # wrapper strongly references its kernel closure (which can pin a
         # task's captured state, e.g. a model checkpoint), so the cache
         # must die with the executor, not outlive the task process-wide.
-        # Under a resident server, a SHARED identity-keyed cache
-        # (install_shared_program_cache, docs/SERVING.md) takes precedence
-        # for kernels whose identity is resolvable.
+        # Two routes take precedence for kernels whose identity freezes
+        # (captured values plain, nothing a task owns): under a resident
+        # server its SHARED identity-keyed cache
+        # (install_shared_program_cache, docs/SERVING.md); elsewhere, on
+        # the sharded route, the process level and step store of
+        # parallel/step_cache.py (_KeptSweepProgram), which keep only the
+        # compiled program.  The per-block, vmap and ragged programs and
+        # every kernel that captures an array stay here.
         self._program_cache = ProgramCache(_PROGRAM_CACHE_SIZE)
 
     def _program_lookup(self, kernel: Callable) -> Callable:
@@ -929,7 +980,14 @@ class BlockwiseExecutor:
         # the identity freeze walks the kernel's whole closure
         cached_program = self._program_lookup(kernel)
 
-        if use_sharded:
+        kept = None
+        if use_sharded and shared_program_cache() is None:
+            identity = kernel_identity(kernel)
+            if identity is not None:
+                kept = _KeptSweepProgram(kernel, identity, self.mesh, bs)
+        if kept is not None:
+            batched_kernel = kept
+        elif use_sharded:
             batched_kernel = cached_program(
                 ("sharded", bs, dev_key),
                 lambda: batched_shard_map(kernel, self.mesh, bs),
@@ -2367,6 +2425,9 @@ class BlockwiseExecutor:
             summary["n_ragged_batches"] = dispatch_stats["ragged_batches"]
             summary["n_lanes_padded"] = dispatch_stats["lanes_padded"]
             summary["pages_in_use"] = dispatch_stats["pages_in_use"]
+        if kept is not None and kept.info is not None:
+            # which level gave the sweep its program (the last signature's)
+            summary["program"] = kept.info
         if dev_pool is not None:
             summary["device_pool"] = "on"
             summary["device_pool_resident_bytes"] = dev_pool.resident_bytes()

@@ -62,6 +62,18 @@ def _tiled_cap_knobs(cfg):
     }
 
 
+def _tiled_kwargs(kp, cfg):
+    """The tiled kernels' keyword arguments: the kernel parameters but
+    ``connectivity``, and the capacity knobs.  Made outside the kernels, so
+    that they capture plain values and not the task's config (whose output
+    key differs every job): a kernel's identity is then equal from job to
+    job wherever what it reads is, and the executor keeps its sweep
+    program across jobs (``runtime/executor.py::kernel_identity``)."""
+    tk = {k: v for k, v in kp.items() if k != "connectivity"}
+    tk.update(_tiled_cap_knobs(cfg))
+    return tk
+
+
 def _outer_shape(block_shape, halo):
     return tuple(b + 2 * h for b, h in zip(block_shape, halo))
 
@@ -111,7 +123,10 @@ def _pass_counters(summary, blocks, outer, use_tiled, overflow_blocks,
     voxels computed (every lane at the kernel's tile-padded outer shape)
     beside those of the outer blocks and of the inner blocks it stored; and
     ``work``, the real lanes' work records as one (``ops/work.py::total``;
-    ``records``: block id -> the lane's record, which the span keeps)."""
+    ``records``: block id -> the lane's record, which the span keeps); and
+    ``step_cache``, where the sweep's program came from (process, store or
+    built; ``parallel/step_cache.py``), None where the executor kept it in
+    its own cache."""
     padded = outer
     if use_tiled:
         from ..ops.tile_ws import _ws_static_plan
@@ -128,6 +143,7 @@ def _pass_counters(summary, blocks, outer, use_tiled, overflow_blocks,
         "inner_voxels": sum(int(np.prod(b.shape)) for b in blocks),
         "overflow_blocks": sorted(overflow_blocks),
         "work": work.total(records.values()),
+        "step_cache": summary.get("program"),
     }
 
 
@@ -418,12 +434,12 @@ class WatershedBase(_WsTaskBase):
             and len(outer) == 3
         )
 
+        tk = _tiled_kwargs(kp, cfg)
+
         def ws_block(b, m):
             if use_tiled:
                 from ..ops.tile_ws import dt_watershed_tiled
 
-                tk = {k: v for k, v in kp.items() if k != "connectivity"}
-                tk.update(_tiled_cap_knobs(cfg))
                 lab, ovf, rec = dt_watershed_tiled(b, mask=m, impl=impl, **tk)
             else:
                 lab = distance_transform_watershed(b, mask=m, two_d=two_d, **kp)
@@ -627,12 +643,12 @@ class TwoPassWatershedBase(_WsTaskBase):
             )
         use_tiled = impl != "legacy" and int(kp.get("connectivity", 1)) == 1
 
+        tk = _tiled_kwargs(kp, cfg)
+
         def ws_block_seeded(b, ext, m):
             if use_tiled:
                 from ..ops.tile_ws import dt_watershed_seeded_tiled
 
-                tk = {k: v for k, v in kp.items() if k != "connectivity"}
-                tk.update(_tiled_cap_knobs(cfg))
                 lab, ovf, rec = dt_watershed_seeded_tiled(
                     b, ext, mask=m, impl=impl, **tk
                 )
@@ -755,7 +771,7 @@ class WatershedWorkflow(WorkflowBase):
     _PASS_KEYS = (
         "n_blocks", "dispatches", "lanes_padded", "padded_voxels",
         "outer_voxels", "inner_voxels", "n_ext_labels", "overflow_blocks",
-        "work",
+        "work", "step_cache",
     )
 
     def run_impl(self):

@@ -6,12 +6,14 @@ import numpy as np
 
 
 @contextlib.contextmanager
-def fused_step_built_here():
-    """Fused jobs inside build their step from the program as it is in
-    memory now: the process holds no ready step and there is no step store.
-    ``parallel/step_cache.py`` keys the compiled step on source *files*, so
-    a test that patches the program in memory, or wants to see the build,
-    says so; the patched step does not outlive the block either."""
+def programs_built_here():
+    """Jobs inside build their programs from the code as it is in memory
+    now: the process holds no ready program (the fused step, the executor's
+    sweep programs) and there is no step store.  ``parallel/step_cache.py``
+    keys a compiled program on source *files* and on the values its kernel
+    captures, not on the module code the kernel calls, so a test that
+    patches the program (``ops/*``) in memory, or wants to see the build,
+    says so; the patched program does not outlive the block either."""
     import jax
 
     from cluster_tools_tpu.parallel import step_cache

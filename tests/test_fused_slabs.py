@@ -29,7 +29,7 @@ from benchmark import control, data, run
 from benchmark import reference as ref
 from cluster_tools_tpu.tasks.fused import collective_bytes
 
-from .helpers import fused_step_built_here
+from .helpers import programs_built_here
 
 CELL = "fused4x384.volumes.sp4"
 HALO = 16
@@ -179,7 +179,7 @@ def input_rounded_to_bfloat16(monkeypatch):
     input_rounded_to_bfloat16], ids=lambda f: f.__name__)
 def test_fault_is_not_correct(fault, monkeypatch):
     expected = fault(monkeypatch)
-    with fused_step_built_here():   # two of the faults patch the step's program
+    with programs_built_here():   # two of the faults patch the step's program
         result = drive()
     assert not result["correct"]
     found = bad(result)

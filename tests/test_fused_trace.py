@@ -18,7 +18,7 @@ from cluster_tools_tpu.runtime import trace
 from cluster_tools_tpu.runtime.task import build
 from cluster_tools_tpu.utils.volume_utils import file_reader
 
-from .helpers import fused_step_built_here
+from .helpers import programs_built_here
 
 SHAPE = (32, 32, 32)
 
@@ -67,7 +67,7 @@ def _run_fused(root, tag, traced):
 def traced_job(tmp_path_factory):
     # the job that builds its step: an earlier test file of this process may
     # have left the same step ready
-    with fused_step_built_here():
+    with programs_built_here():
         return _run_fused(str(tmp_path_factory.mktemp("fused_traced")), "on", True)
 
 

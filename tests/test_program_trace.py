@@ -497,57 +497,75 @@ def test_chase_metric_is_declared_for_every_cell():
     assert meta["stages"] == ["ws.flow.chase"]
 
 
-# -- PR 34's reader: the compiled step read back from the step store -----------
+# -- the compiled step (PR 34) and the executor's sweep programs (PR 40) read
+# back from the step store -----------------------------------------------------
+
+#: metric, its span, its layer, the cells that report it
+LOADS = [
+    ("step_load_s", "fused.step_load", "entry and workflow",
+     ["fused384.volumes", "fused4x384.volumes.sp4", "multicut384.volumes"]),
+    ("executor_program_load_s", "executor.program_load", "executor",
+     ["twopass125.volumes"]),
+]
 
 
-@pytest.fixture
-def traced_store_hit(traced):
-    """``traced`` as a job whose dispatch read the step from the store:
+@pytest.fixture(params=LOADS, ids=[m for m, *_ in LOADS])
+def load_metric(request):
+    return request.param
+
+
+def _store_hit(traced, name):
+    """``traced`` as a job whose dispatch read its program from the store:
     look-up, load, enqueue; nothing traced, lowered or compiled.  A second
     thread's load overlaps the first's (their union counts once), and a
     later job's lies outside."""
     keep = [e for e in traced["runtime_spans"] if not e["name"].startswith("jax.")]
 
-    def span(name, ts, dur, tid=1, **args):
+    def span(ts, dur, tid=1, **args):
         return {"ph": "X", "name": name, "ts": ts, "dur": dur, "tid": tid, "args": args}
 
     traced["runtime_spans"] = keep + [
-        span("fused.step_load", 101.5, 2.5, key="k", nbytes=1 << 20),
-        span("fused.step_load", 103.5, 1.0, tid=2, key="k2"),
-        span("fused.step_load", 131.0, 2.0),
+        span(101.5, 2.5, key="k", nbytes=1 << 20),
+        span(103.5, 1.0, tid=2, key="k2"),
+        span(131.0, 2.0),
     ]
     return traced
 
 
-def test_step_load_reader_sums_the_store_reads_of_the_job(traced_store_hit):
-    assert _read_metric("step_load_s", traced_store_hit) == pytest.approx(3.0)
+def test_load_reader_sums_the_store_reads_of_the_job(traced, load_metric):
+    metric, name, _, _ = load_metric
+    hit = _store_hit(traced, name)
+    assert _read_metric(metric, hit) == pytest.approx(3.0)
     # the load has left executable_load_s, which reads jax.backend_compile
-    assert _read_metric("executable_load_s", traced_store_hit) is None
-    assert _read_metric("trace_lower_s", traced_store_hit) is None
+    assert _read_metric("executable_load_s", hit) is None
+    assert _read_metric("trace_lower_s", hit) is None
+    # and the other program's reader finds nothing of it
+    (other,) = [m for m, *_ in LOADS if m != metric]
+    assert _read_metric(other, hit) is None
 
 
 @pytest.mark.parametrize("which", ["a_built_job", "a_process_hit", "selfcheck"])
-def test_step_load_reader_returns_nothing_without_the_span(traced, which):
-    """The parent commit's program (and a job that built its step with no
-    store): phase spans and no ``fused.step_load``; a job that found the
-    step in its process; ``selfcheck``'s trace: no span at all."""
+def test_load_reader_returns_nothing_without_the_span(traced, which, load_metric):
+    """The parent commit's program (and a job that built its program with
+    no store): phase spans and no load span; a job that found the program
+    in its process; ``selfcheck``'s trace: no span at all."""
     if which == "a_process_hit":
         traced["runtime_spans"] = [e for e in traced["runtime_spans"]
                                    if not e["name"].startswith("jax.")]
-    assert _read_metric("step_load_s", _selfcheck_traced() if which == "selfcheck"
+    assert _read_metric(load_metric[0], _selfcheck_traced() if which == "selfcheck"
                         else traced) is None
 
 
-def test_step_load_metric_is_declared_for_every_cell_with_the_fused_step():
+def test_load_metric_is_declared_for_every_cell_with_its_program(load_metric):
+    metric, name, layer, cells = load_metric
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    (mine,) = [m for m in bench["per_layer"] if m["name"] == "step_load_s"]
-    with open(os.path.join(ROOT, "benchmark", "metrics", "step_load_s.json")) as f:
+    (mine,) = [m for m in bench["per_layer"] if m["name"] == metric]
+    with open(os.path.join(ROOT, "benchmark", "metrics", metric + ".json")) as f:
         meta = json.load(f)
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert mine[key] == meta[key]
-    assert mine["workloads"] == ["fused384.volumes", "fused4x384.volumes.sp4",
-                                 "multicut384.volumes"]
+    assert mine["workloads"] == cells
     assert (mine["unit"], mine["better"], mine["source"]) == ("s", "lower", "program_span")
-    assert (mine["layer"], mine["moves"]) == ("entry and workflow", "voxels_per_s")
-    assert meta["spans"] == ["fused.step_load"]
+    assert (mine["layer"], mine["moves"]) == (layer, "voxels_per_s")
+    assert meta["spans"] == [name]

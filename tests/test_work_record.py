@@ -21,7 +21,7 @@ from cluster_tools_tpu.ops import tile_ccl, tile_ws, work
 from cluster_tools_tpu.runtime import trace
 from cluster_tools_tpu.utils.volume_utils import file_reader
 
-from .helpers import fused_step_built_here
+from .helpers import programs_built_here
 from .test_dense_fill import (
     _EQUALITY_CASES, _axis_faces, _fill_n_table_reference, _masked_case,
     _two_cycle_case,
@@ -403,7 +403,7 @@ def test_fused_job_carries_one_record_a_shard(tmp_path, two_devices, traced):
     task = _fused_task(str(tmp_path), "on" if traced else "off")
     trace.configure(enabled=traced)
     try:
-        with fused_step_built_here():
+        with programs_built_here():
             assert build([task]), "fused task failed (see logs)"
         events = trace._get().snapshot_events()
     finally:
@@ -442,7 +442,7 @@ def test_fused_job_carries_one_record_a_shard(tmp_path, two_devices, traced):
 
 def test_a_tripped_capacity_is_named_in_the_fused_tasks_error(tmp_path, two_devices):
     task = _fused_task(str(tmp_path), "over", max_labels_per_shard=4)
-    with fused_step_built_here(), pytest.raises(RuntimeError) as err:
+    with programs_built_here(), pytest.raises(RuntimeError) as err:
         task.run()
     text = str(err.value)
     assert "shard 0: over.labels" in text and "shard 1: over.labels" in text
