@@ -183,6 +183,7 @@ def distance_transform_squared(
     sampling: Optional[Sequence[float]] = None,
     max_distance: Optional[float] = None,
     impl: str = "auto",
+    axes: Optional[Sequence[int]] = None,
 ) -> jnp.ndarray:
     """Squared EDT of a boolean mask: distance to the nearest background voxel.
 
@@ -199,6 +200,10 @@ def distance_transform_squared(
 
     ``impl``: "auto" (VMEM cascade kernel on TPU, XLA elsewhere), "pallas",
     or "xla".
+
+    ``axes``: the axes the distance is taken along (default all).  Along
+    any other the window is 0, so the transform is each line's, or each
+    plane's, own: ``axes=(1, 2)`` is the 2-D transform of every z-plane.
     """
     sampling = _norm_sampling(mask.ndim, sampling)
     if max_distance is None:
@@ -207,6 +212,8 @@ def distance_transform_squared(
         radii = tuple(
             int(np.ceil(float(max_distance) / s)) for s in sampling
         )
+    if axes is not None:
+        radii = tuple(r if a in axes else 0 for a, r in enumerate(radii))
     return _dt_squared_impl(mask, sampling, radii, impl=impl)
 
 

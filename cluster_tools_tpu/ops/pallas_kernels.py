@@ -73,7 +73,7 @@ def _out_struct(shape, dtype, *like) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _ccl_kernel(tile_shape, mask_ref, out_ref):
+def _ccl_kernel(tile_shape, axes, mask_ref, out_ref):
     tz, ty, tx = tile_shape
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -89,7 +89,7 @@ def _ccl_kernel(tile_shape, mask_ref, out_ref):
 
     def nmin(l):
         m = l
-        for ax in range(3):
+        for ax in axes:
             m = jnp.minimum(m, _shift(l, 1, ax, jnp.int32(BIG)))
             m = jnp.minimum(m, _shift(l, -1, ax, jnp.int32(BIG)))
         return m
@@ -109,23 +109,26 @@ def _ccl_kernel(tile_shape, mask_ref, out_ref):
     out_ref[:] = lab
 
 
-@partial(jax.jit, static_argnames=("tile", "interpret"))
+@partial(jax.jit, static_argnames=("tile", "interpret", "axes"))
 def tile_ccl_pallas(
     mask: jnp.ndarray,
     tile: Tuple[int, int, int] = (16, 16, 128),
     interpret: bool = False,
+    axes: Tuple[int, ...] = (0, 1, 2),
 ) -> jnp.ndarray:
     """Exact per-tile CCL of a 3-D bool mask; labels are global flat indices.
 
     Shape must be divisible by ``tile`` (callers pad).  Foreground voxels get
     the minimum global flat index of their *within-tile* component;
     background gets ``BIG``.  Cross-tile merging is ``tile_ccl.py``'s job.
+    Voxels connect across the faces of ``axes`` only: ``(1, 2)`` labels
+    each z-plane of the tile on its own (4-connected).
     """
     z, y, x = mask.shape
     tz, ty, tx = tile
     assert z % tz == 0 and y % ty == 0 and x % tx == 0, (mask.shape, tile)
     return pl.pallas_call(
-        partial(_ccl_kernel, tile),
+        partial(_ccl_kernel, tile, tuple(axes)),
         out_shape=_out_struct((z, y, x), jnp.int32, mask),
         grid=(z // tz, y // ty, x // tx),
         in_specs=[

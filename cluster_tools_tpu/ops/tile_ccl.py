@@ -94,12 +94,13 @@ def _tile_for(shape: Tuple[int, int, int]) -> Tuple[int, int, int]:
 
 
 def tile_local_labels_xla(
-    mask: jnp.ndarray, tile: Tuple[int, int, int]
+    mask: jnp.ndarray, tile: Tuple[int, int, int],
+    axes: Tuple[int, ...] = (0, 1, 2),
 ) -> jnp.ndarray:
     """Per-tile CCL via the legacy kernel, vmapped — CPU/fallback path.
 
     Same contract as ``tile_ccl_pallas``: global flat indices, ``BIG``
-    background.
+    background; ``axes=(1, 2)`` labels each z-plane of a tile on its own.
     """
     z, y, x = mask.shape
     tz, ty, tx = tile
@@ -109,8 +110,15 @@ def tile_local_labels_xla(
         .transpose(0, 2, 4, 1, 3, 5)
         .reshape(gz * gy * gx, tz, ty, tx)
     )
-    local = jax.vmap(lambda m: label_components(m, connectivity=1))(tiles)
     nloc = tz * ty * tx
+    if tuple(axes) == (1, 2):
+        planes = jax.vmap(jax.vmap(lambda m: label_components(m, connectivity=1)))(
+            tiles
+        )
+        plane = jnp.arange(tz, dtype=jnp.int32).reshape(1, tz, 1, 1) * (ty * tx)
+        local = jnp.where(planes == ty * tx, nloc, planes + plane)
+    else:
+        local = jax.vmap(lambda m: label_components(m, connectivity=1))(tiles)
     # local rep -> global flat index, elementwise
     tid = jnp.arange(gz * gy * gx, dtype=jnp.int32).reshape(-1, 1, 1, 1)
     ti = tid // (gy * gx)
@@ -267,8 +275,10 @@ def merge_face_pairs(
     pair_cap: int = DEFAULT_PAIR_CAP,
     edge_cap: int = DEFAULT_EDGE_CAP,
     max_rounds: int = 64,
+    axes: Tuple[int, ...] = (0, 1, 2),
 ):
-    """Union-find closure over tile-face equivalences.
+    """Union-find closure over tile-face equivalences (the faces across
+    ``axes``: components within a plane join across no z face).
 
     ``labels``: per-tile global-flat-index labels (``BIG`` background).
     Returns ``(ea, eb, root_a, root_b, n_edges, overflow, counts)`` where
@@ -283,7 +293,7 @@ def merge_face_pairs(
     pair_lists = []
     most_pairs = _match_vma(jnp.zeros((), jnp.int32), labels)
     n_total = _match_vma(jnp.zeros((), jnp.int32), labels)
-    for axis in range(3):
+    for axis in axes:
         (pa, pb), kept = _face_pairs_axis(labels, tile, axis, pair_cap)
         pair_lists.append((pa, pb))
         most_pairs = jnp.maximum(most_pairs, kept)
@@ -457,7 +467,7 @@ def resolve_labels_gather(
     jax.jit,
     static_argnames=(
         "connectivity", "impl", "tile", "pair_cap", "edge_cap", "table_cap",
-        "interpret",
+        "interpret", "axes",
     ),
 )
 def label_components_tiled(
@@ -469,6 +479,7 @@ def label_components_tiled(
     edge_cap: Optional[int] = None,
     table_cap: int = DEFAULT_TABLE_CAP,
     interpret: bool = False,
+    axes: Tuple[int, ...] = (0, 1, 2),
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Two-level CCL of a 3-D bool mask: ``(labels, overflow, work)``.
 
@@ -486,6 +497,9 @@ def label_components_tiled(
     neighborhood use the legacy kernel.  Capacities default to volume-scaled
     values (static, shape-derived); pass explicit caps for workloads with
     unusually many fragments per tile face.
+
+    ``axes``: the axes voxels connect along; ``(1, 2)`` gives each z-plane's
+    own 4-connected components (the per-plane watershed's seed plateaus).
 
     ``work`` is the program's work record (:mod:`.work`, names ``ccl.*``):
     the merge's counts and, where the Mosaic remap kernel can run, the
@@ -521,14 +535,14 @@ def label_components_tiled(
         if padded:
             m = jnp.pad(m, ((0, zp - z), (0, yp - y), (0, xp - x)))
         if impl == "pallas":
-            labels = tile_ccl_pallas(m, tile=tile, interpret=interpret)
+            labels = tile_ccl_pallas(m, tile=tile, interpret=interpret, axes=axes)
         else:
-            labels = tile_local_labels_xla(m, tile)
+            labels = tile_local_labels_xla(m, tile, axes)
 
     # second level: equivalences across tile faces, solved and applied
     with jax.named_scope("ccl.merge"):
         ea, eb, root_a, root_b, n_edges, overflow, counts = merge_face_pairs(
-            labels, tile, pair_cap=pair_cap, edge_cap=edge_cap
+            labels, tile, pair_cap=pair_cap, edge_cap=edge_cap, axes=axes
         )
         counts[work.CAP_TABLE] = table_cap
 

@@ -43,6 +43,17 @@ restructures the resolution:
 When every basin is seeded (e.g. the oracle test's fully-seeded minima) the
 result is bit-identical to the legacy kernel; only unseeded-basin fill order
 differs.
+
+**Flood axes.**  Every stage takes a static set of axes the flood runs along:
+``VOLUME`` = (0, 1, 2), the 6-neighbourhood, or ``PLANES`` = (1, 2), one
+flood per z-plane (upstream's ``apply_dt_2d`` + ``apply_ws_2d``, the
+watershed task's ``two_d``).  Along an axis outside the set nothing is
+compared or joined: the EDT has no window there, maxima and seed plateaus are
+taken within a plane, no voxel drains across it, so no exit crosses it and
+its strips are not collected, and the fill harvests no face there
+(``fill.faces_z`` reads 0).  The shape stays 3-D: a block's planes are
+flooded side by side in the same tiles, and a label (the seed's least flat
+index + 1) names one plane.
 """
 
 from __future__ import annotations
@@ -82,6 +93,18 @@ DEFAULT_FILL_CAP = 1 << 21
 # unique unseeded-basin adjacencies (deduped (a, b) pairs), not face
 # voxels — object-scale, so orders of magnitude below FILL_CAP
 DEFAULT_ADJ_CAP = 1 << 18
+
+#: the flood's axes: the 6-neighbourhood, or one flood per z-plane
+VOLUME = (0, 1, 2)
+PLANES = (1, 2)
+
+
+def _flood_axes(axes) -> Tuple[int, ...]:
+    """``axes`` as one of the two sets the kernels know."""
+    axes = tuple(int(a) for a in axes)
+    if axes not in (VOLUME, PLANES):
+        raise ValueError(f"flood axes {axes}: expected {VOLUME} or {PLANES}")
+    return axes
 
 
 def _auto_fill_rounds(n_pad: int) -> int:
@@ -142,12 +165,14 @@ def descent_directions(
     height: jnp.ndarray,
     is_seed: jnp.ndarray,
     valid: jnp.ndarray,
+    axes: Tuple[int, ...] = VOLUME,
 ) -> jnp.ndarray:
     """Code 0..6 of each voxel's steepest-descent target (0 = self).
 
     Identical tiebreak to ``watershed._descent_pointers``: lexicographic min
-    of ``(height, flat_index)`` over the closed 6-neighborhood; seeds and
-    invalid voxels are terminals.  Dense shifts only.
+    of ``(height, flat_index)`` over the closed neighborhood along ``axes``
+    (6 neighbours, or 4 within a plane); seeds and invalid voxels are
+    terminals.  Dense shifts only.
     """
     shape = height.shape
     n = int(np.prod(shape))
@@ -159,6 +184,8 @@ def descent_directions(
     best_i = idx
     best_d = jnp.zeros(shape, jnp.int32)
     for code, off in enumerate(WS_OFFS, start=1):
+        if not any(off[a] for a in axes):
+            continue
         nh = h
         ni = idx
         for ax, s in enumerate(off):
@@ -345,20 +372,23 @@ def _strip_entries(values: jnp.ndarray, tile, axis: int, side: int):
 
 
 def collect_negative_values(
-    values: jnp.ndarray, tile: Tuple[int, int, int], cap: int
+    values: jnp.ndarray, tile: Tuple[int, int, int], cap: int,
+    axes: Tuple[int, ...] = VOLUME,
 ):
     """Deduped (value, tile_id) pairs for negative labels on tile boundaries.
 
     Every cross-tile fragment touches a boundary strip of each tile it
     occupies, so this covers all (tile, value) incidences needed for exits
-    and fill remaps.  Returns ``(vals, tids, overflow, n_kept, family_max)``:
-    the deduped pairs, whether they or one strip family passed ``cap``, how
-    many pairs there are and how many entries the fullest family held.
+    and fill remaps; a flood along ``axes`` crosses tile faces across those
+    axes only, so only their strips are read.  Returns ``(vals, tids,
+    overflow, n_kept, family_max)``: the deduped pairs, whether they or one
+    strip family passed ``cap``, how many pairs there are and how many
+    entries the fullest family held.
     """
     vs, ts = [], []
     overflow = _match_vma(jnp.zeros((), jnp.int32), values)
     n_total = family_max = overflow
-    for axis in range(3):
+    for axis in axes:
         for side in (0, 1):
             sl, tid = _strip_entries(values, tile, axis, side)
             # a family can never hold more entries than its strip has
@@ -573,6 +603,7 @@ def fill_unseeded_basins(
     fill_cap: int = DEFAULT_FILL_CAP,
     max_rounds: Optional[int] = None,
     adj_cap: Optional[int] = None,
+    axes: Tuple[int, ...] = VOLUME,
 ):
     """Merge unseeded basins across their lowest saddles (Boruvka rounds).
 
@@ -592,15 +623,16 @@ def fill_unseeded_basins(
     the common few-unseeded-basins case executes at 1/16 size.
     Overflowing ``adj_cap`` raises the overflow flag like every other
     capacity.  ``max_rounds=None`` resolves to the always-sufficient
-    volume-scaled bound (:func:`_auto_fill_rounds`).
+    volume-scaled bound (:func:`_auto_fill_rounds`).  Faces are taken
+    along ``axes`` only (a plane's flood has none across planes).
     """
     if max_rounds is None:
         max_rounds = _auto_fill_rounds(labels.size)
     h = height.astype(jnp.float32)
     evs_a, evs_b, evs_h = [], [], []
-    counts = {}
+    counts = {work.FILL_FACES[axis]: 0 for axis in VOLUME}
     n_total = _match_vma(jnp.zeros((), jnp.int32), labels)
-    for axis in range(3):
+    for axis in axes:
         na = labels.shape[axis]
         a = lax.slice_in_dim(labels, 0, na - 1, axis=axis)
         b = lax.slice_in_dim(labels, 1, na, axis=axis)
@@ -635,7 +667,7 @@ def fill_unseeded_basins(
     # flagged; adversarial regimes should raise adj_cap explicitly.
     if adj_cap is None:
         adj_cap = min(
-            3 * fill_cap, max(DEFAULT_ADJ_CAP, labels.size // 32)
+            len(axes) * fill_cap, max(DEFAULT_ADJ_CAP, labels.size // 32)
         )
 
     # Capacity tiering: a realistic seeded volume (few unseeded basins)
@@ -648,8 +680,8 @@ def fill_unseeded_basins(
     )
     counts.update({
         work.FILL_ADJACENCIES: n_adj,
-        work.OVER_FACE: reduce(
-            jnp.logical_or, [counts[f] > fill_cap for f in work.FILL_FACES]),
+        work.OVER_FACE: reduce(jnp.logical_or, [
+            counts[work.FILL_FACES[axis]] > fill_cap for axis in axes]),
         work.OVER_ADJ: adj_over, work.OVER_ROUNDS: unconverged,
         work.CAP_FACE: fill_cap, work.CAP_ADJ: adj_cap,
     })
@@ -661,6 +693,7 @@ def fill_unseeded_basins_dense(
     height: jnp.ndarray,
     max_rounds: Optional[int] = None,
     face_cap: Optional[int] = None,
+    axes: Tuple[int, ...] = VOLUME,
 ):
     """Sort-free unseeded-basin fill: scatter-min Boruvka rounds over a
     compact basin table and the live prefix of one face list.
@@ -731,7 +764,11 @@ def fill_unseeded_basins_dense(
     ``face_cap`` took 4.1 s; the four n-sized compaction scatters 1.3 s
     and their sorts 0.65 s) and the final resolve's volume-sized gather
     1.2 s.
-    Memory: one list of four ``3 * face_cap`` int32 arrays, four
+    Faces are harvested along ``axes`` only: a plane's flood has none
+    across planes, so its list holds two axes' faces and ``fill.faces_z``
+    reads 0.
+
+    Memory: one list of four ``len(axes) * face_cap`` int32 arrays, four
     ``basin_cap`` tables and two volume-sized int32 temporaries (the rank
     before the rounds, the code table after them); of the harvest only
     one axis's compacted face positions (``face_cap`` slots) live beside
@@ -812,28 +849,28 @@ def fill_unseeded_basins_dense(
         # voxel index is globally distinct and seen identically from both
         # sides, so the min-edge graph is a forest plus 2-cycles (the
         # classic distinct-weight Boruvka argument, as in _fill_core).
-        # The three axes' faces go into ONE list (va, vb, sad, eid) of 48
-        # chunks (>= 3 * face_cap slots) whose first n_live slots are the
-        # faces.  An axis's face positions are compacted (padding: n) and
+        # The flood axes' faces go into ONE list (va, vb, sad, eid) of 16
+        # chunks an axis (>= face_cap slots each) whose first n_live slots
+        # are the faces.  An axis's face positions are compacted (padding: n) and
         # walked in chunks up to their count, as the rounds walk the list:
         # a chunk's endpoints, saddles and ids land at the running count
         # plus the chunk's start, its tail past the count (0, 0, the pad's
         # saddle and eid) over slots that the next axis overwrites.  No
         # slot past n_live is read.
         lists = tuple(
-            _match_vma(jnp.zeros((48 * chunk,), jnp.int32), values)
+            _match_vma(jnp.zeros((16 * len(axes) * chunk,), jnp.int32), values)
             for _ in range(4)
         )
         n_live = harvest_trips = _match_vma(jnp.zeros((), jnp.int32), values)
-        faces = []
-        for axis in range(3):
+        faces = [0, 0, 0]
+        for axis in axes:
             nb = _shift(values, -1, axis, jnp.int32(0)).ravel()
             ok0 = (
                 (v != nb) & (v != 0) & (nb != 0)
                 & ((v <= -2) | (nb <= -2))
             )
             (idx_c,), n_faces = _compact(ok0, (flat_idx,), face_cap, n)
-            faces.append(n_faces)
+            faces[axis] = n_faces
             n_kept = jnp.minimum(n_faces, face_cap)
             n_trips = (n_kept + chunk - 1) // chunk
             # room for 16 whole chunks: the last one's slice never clamps
@@ -863,7 +900,9 @@ def fill_unseeded_basins_dense(
             )
             n_live = n_live + n_kept
             harvest_trips = harvest_trips + n_trips
-        faces_over = reduce(jnp.logical_or, [f > face_cap for f in faces])
+        faces_over = reduce(
+            jnp.logical_or, [faces[axis] > face_cap for axis in axes]
+        )
     me_idx = _match_vma(jnp.arange(basin_cap, dtype=jnp.int32), values)
     slot = jnp.arange(chunk, dtype=jnp.int32)
 
@@ -1141,6 +1180,7 @@ def seeded_watershed_tiled(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: Optional[str] = None,
+    axes: Tuple[int, ...] = VOLUME,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Seeded watershed with the two-level tile machinery: ``(labels,
     overflow, work)``, the last the program's work record (:mod:`.work`).
@@ -1162,12 +1202,14 @@ def seeded_watershed_tiled(
     default substrate-aware ``auto`` — see :func:`_resolve_fill_mode`),
     resolved HERE, outside jit, so flipping the variable mid-process
     retraces instead of reusing a stale cache entry.
+
+    ``axes``: the flood's axes (:data:`VOLUME` or :data:`PLANES`).
     """
     return _seeded_watershed_tiled_jit(
         height, seeds, mask, impl=impl, tile=tile, exit_cap=exit_cap,
         fill_cap=fill_cap, table_cap=table_cap, interpret=interpret,
         adj_cap=adj_cap, fill_rounds=fill_rounds,
-        fill_mode=_resolve_fill_mode(fill_mode),
+        fill_mode=_resolve_fill_mode(fill_mode), axes=_flood_axes(axes),
     )
 
 
@@ -1175,7 +1217,7 @@ def seeded_watershed_tiled(
     jax.jit,
     static_argnames=(
         "impl", "tile", "exit_cap", "fill_cap", "table_cap", "interpret",
-        "adj_cap", "fill_rounds", "fill_mode",
+        "adj_cap", "fill_rounds", "fill_mode", "axes",
     ),
 )
 def _seeded_watershed_tiled_jit(
@@ -1191,6 +1233,7 @@ def _seeded_watershed_tiled_jit(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: str = "capacity",
+    axes: Tuple[int, ...] = VOLUME,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``(labels, overflow, work)``: the program packs its parts' counts
     into the one work record (:mod:`.work`)."""
@@ -1199,12 +1242,13 @@ def _seeded_watershed_tiled_jit(
     # composing them here compiles the identical fused program.
     values, h, flow_overflow, flow_counts = _ws_flow_core(
         height, seeds, mask, impl=impl, tile=tile, exit_cap=exit_cap,
-        table_cap=table_cap, interpret=interpret,
+        table_cap=table_cap, interpret=interpret, axes=axes,
     )
     out, fill_overflow, fill_counts = _ws_fill_core(
         values, h, height.shape, impl=impl, tile=tile, exit_cap=exit_cap,
         fill_cap=fill_cap, table_cap=table_cap, interpret=interpret,
         adj_cap=adj_cap, fill_rounds=fill_rounds, fill_mode=fill_mode,
+        axes=axes,
     )
     record = work.pack(work.join(flow_counts, fill_counts))
     return out, flow_overflow | fill_overflow, record
@@ -1227,9 +1271,12 @@ def resolved_modes(impl: str = "auto") -> dict:
     }
 
 
-def _ws_static_plan(shape, tile, exit_cap, fill_cap):
+def _ws_static_plan(shape, tile, exit_cap, fill_cap, axes=VOLUME):
     """Tile/padded geometry + capacity defaults, shared by the fused program
-    and the split-phase programs so both compile identical caps."""
+    and the split-phase programs so both compile identical caps.  The exit
+    capacity's floor is the strips a flood along ``axes`` can exit through:
+    about n/4 of the voxels for the 6-neighbourhood's six families, n/7 for
+    a plane's four (no z strips)."""
     z, y, x = shape
     tile = _tile_for(shape) if tile is None else tile
     tz, ty, tx = tile
@@ -1241,7 +1288,8 @@ def _ws_static_plan(shape, tile, exit_cap, fill_cap):
     n_pad = zp * yp * xp
     if exit_cap is None:
         # n/3 >= the total strip voxel count for the default tile, so exits
-        # can never overflow below ~6M voxels.  ABOVE that the loads keep
+        # can never overflow below ~6M voxels (n/6 for a plane's four strip
+        # families, 2/16 + 2/128 of the voxels, below ~12M).  ABOVE that the loads keep
         # scaling with the volume (measured on bench-like box-filtered
         # noise, fractions size-constant 96³→160³ and smoothing-
         # insensitive: exit candidates ~8% of voxels SUMMED over the six
@@ -1252,9 +1300,10 @@ def _ws_static_plan(shape, tile, exit_cap, fill_cap):
         # per-family headroom up to the 2^24 ceiling (int32 buffers,
         # ~600MB transient at 512³).  The ~8% total only picks the
         # capacity TIER, never the flag.
-        exit_cap = min(
-            1 << 24, max(_auto_cap(n_pad, DEFAULT_EXIT_CAP, 3), n_pad // 12)
-        )
+        exit_cap = min(1 << 24, max(
+            _auto_cap(n_pad, DEFAULT_EXIT_CAP, 3 if axes == VOLUME else 6),
+            n_pad // 12,
+        ))
     if fill_cap is None:
         # fill edges can reach ~n/2 per axis in pure-noise/sparse-seed
         # regimes (overflow-flagged); the proportional floor covers the
@@ -1276,9 +1325,11 @@ def _ws_flow_core(
     exit_cap: Optional[int],
     table_cap: int,
     interpret: bool,
+    axes: Tuple[int, ...] = VOLUME,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, dict]:
     """Flow phase: tile-pad, descent directions, in-tile flow, exit chase +
-    remap.  Returns ``(values, h, overflow, counts)`` at TILE-PADDED shape:
+    remap, for a flood along ``axes`` (the in-tile flow and the chase follow
+    the directions, which never point off those axes).  Returns ``(values, h, overflow, counts)`` at TILE-PADDED shape:
     >0 seeded label, <= -2 unseeded terminal code, -1 masked/padded, plus the
     padded float32 heights the fill phase needs and the flow's part of the
     work record (:mod:`.work`, names ``flow.*``)."""
@@ -1287,7 +1338,7 @@ def _ws_flow_core(
     impl = resolve_impl(impl)
     z, y, x = height.shape
     tile, (zp, yp, xp), exit_cap, _ = _ws_static_plan(
-        height.shape, tile, exit_cap, 0
+        height.shape, tile, exit_cap, 0, axes
     )
     tz, ty, tx = tile
     padded = (zp != z) or (yp != y) or (xp != x)
@@ -1301,7 +1352,7 @@ def _ws_flow_core(
         valid = jnp.pad(valid, pads)
 
     with jax.named_scope("ws.flow.descent"):
-        dirs = descent_directions(h, s > 0, valid)
+        dirs = descent_directions(h, s > 0, valid, axes)
         sv = jnp.where(valid, s, -1)
 
     with jax.named_scope("ws.flow.propagate"):
@@ -1317,7 +1368,7 @@ def _ws_flow_core(
     # cross-tile exits: collect, chase, remap
     with jax.named_scope("ws.flow.exits"):
         codes, code_tiles, exit_over, n_exits, family_max = (
-            collect_negative_values(values, tile, exit_cap)
+            collect_negative_values(values, tile, exit_cap, axes)
         )
     with jax.named_scope("ws.flow.chase"):
         finals, chase_unconverged, counts = chase_exits(values, codes)
@@ -1384,6 +1435,7 @@ def _ws_fill_core(
     adj_cap: Optional[int],
     fill_rounds: Optional[int],
     fill_mode: str,
+    axes: Tuple[int, ...] = VOLUME,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
     """Fill phase: unseeded-basin fill across lowest saddles (fill_mode
     selects the machinery — see :func:`_resolve_fill_mode`), remap, squash
@@ -1393,7 +1445,7 @@ def _ws_fill_core(
     impl = resolve_impl(impl)
     z, y, x = orig_shape
     tile, (zp, yp, xp), exit_cap, fill_cap = _ws_static_plan(
-        orig_shape, tile, exit_cap, fill_cap
+        orig_shape, tile, exit_cap, fill_cap, axes
     )
     tz, ty, tx = tile
     padded = (zp != z) or (yp != y) or (xp != x)
@@ -1407,7 +1459,7 @@ def _ws_fill_core(
     if fill_mode == "dense":
         with jax.named_scope("ws.fill.dense"):
             values, fill_unconv, counts = fill_unseeded_basins_dense(
-                values, h, max_rounds=fill_rounds
+                values, h, max_rounds=fill_rounds, axes=axes
             )
         overflow = fill_unconv > 0
         out = jnp.where(values > 0, values, 0).astype(jnp.int32)
@@ -1417,7 +1469,7 @@ def _ws_fill_core(
     with jax.named_scope("ws.fill.capacity"):
         fill_vals, fill_finals, overflow, counts = fill_unseeded_basins(
             values, h, fill_cap=fill_cap, max_rounds=fill_rounds,
-            adj_cap=adj_cap,
+            adj_cap=adj_cap, axes=axes,
         )
     n_tiles = (zp // tz) * (yp // ty) * (xp // tx)
 
@@ -1426,7 +1478,7 @@ def _ws_fill_core(
 
         # tiles needing a basin's entry: strip incidences + the terminal's tile
         bvals, btiles, b_overflow, _, _ = collect_negative_values(
-            values, tile, exit_cap
+            values, tile, exit_cap, axes
         )
         overflow = overflow | b_overflow
         counts.update({work.OVER_EXIT: b_overflow, work.CAP_EXIT: exit_cap})
@@ -1488,13 +1540,16 @@ def _dt_seeds_core(
     edge_cap: Optional[int],
     table_cap: int,
     interpret: bool,
+    axes: Tuple[int, ...] = VOLUME,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Seed phase of the DT watershed: threshold -> (capped) EDT -> optional
     smoothing -> maxima plateaus -> seed CCL.  Returns ``(seeds, valid,
     overflow, work)`` at the input shape — the split execution mode
     (parallel/split_pipeline.py) jits this as its own program; the fused
     ``dt_watershed_tiled`` inlines it.  ``work`` is the seed CCL's work
-    record (:mod:`.work`) under the names ``seeds.*``."""
+    record (:mod:`.work`) under the names ``seeds.*``.  Along ``axes``
+    only: for :data:`PLANES` the EDT, the smoothing, the maxima and the
+    plateaus are each a plane's own (``sampling``'s z entry unused)."""
     from .edt import distance_transform_squared
     from .filters import gaussian_smooth
     from .watershed import local_maxima
@@ -1508,7 +1563,7 @@ def _dt_seeds_core(
         # forwarded)
         dist = distance_transform_squared(
             fg, sampling=sampling, max_distance=dt_max_distance,
-            impl="xla" if impl == "xla" else "auto",
+            impl="xla" if impl == "xla" else "auto", axes=axes,
         )
     else:
         # caller-supplied squared distances (e.g. the mesh-exact transform
@@ -1516,15 +1571,20 @@ def _dt_seeds_core(
         # so seed maxima stay inside basins
         dist = jnp.where(fg, dist.astype(jnp.float32), 0.0)
     if sigma_seeds > 0:
+        if axes != VOLUME:
+            # an infinite voxel size along the other axes: no blur there
+            samp = sampling or (1.0,) * boundaries.ndim
+            sampling = tuple(float(s) if a in axes else float("inf")
+                             for a, s in enumerate(samp))
         dist = gaussian_smooth(dist, sigma_seeds, sampling=sampling)
     maxima = (
-        local_maxima(dist, 1)
+        local_maxima(dist, 1, axes=axes)
         & fg
         & (dist >= min_seed_distance * min_seed_distance)
     )
     raw, seed_overflow, record = label_components_tiled(
         maxima, impl=impl, tile=tile, pair_cap=pair_cap, edge_cap=edge_cap,
-        table_cap=table_cap, interpret=interpret,
+        table_cap=table_cap, interpret=interpret, axes=axes,
     )
     n = int(np.prod(boundaries.shape))
     seeds = jnp.where(raw == n, 0, raw + 1).astype(jnp.int32)
@@ -1551,6 +1611,7 @@ def dt_watershed_tiled(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: Optional[str] = None,
+    axes: Tuple[int, ...] = VOLUME,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused distance-transform watershed on the two-level machinery.
 
@@ -1571,6 +1632,11 @@ def dt_watershed_tiled(
     ``fill_mode``: explicit machinery selection; ``None`` resolves
     ``CT_FILL_MODE`` here, OUTSIDE jit, so the env value is part of the
     compile key (see :func:`_resolve_fill_mode`).
+
+    ``axes``: :data:`VOLUME` (the 6-neighbourhood), or :data:`PLANES`, one
+    watershed per z-plane from the EDT on (upstream's ``apply_dt_2d`` +
+    ``apply_ws_2d``; the task's ``two_d``): the planes are flooded side by
+    side in the same program and a label names one plane.
     """
     return _dt_watershed_tiled_jit(
         boundaries, threshold=threshold, sigma_seeds=sigma_seeds,
@@ -1579,7 +1645,7 @@ def dt_watershed_tiled(
         pair_cap=pair_cap, edge_cap=edge_cap, exit_cap=exit_cap,
         fill_cap=fill_cap, table_cap=table_cap, interpret=interpret,
         adj_cap=adj_cap, fill_rounds=fill_rounds,
-        fill_mode=_resolve_fill_mode(fill_mode),
+        fill_mode=_resolve_fill_mode(fill_mode), axes=_flood_axes(axes),
     )
 
 
@@ -1589,7 +1655,7 @@ def dt_watershed_tiled(
         "threshold", "sigma_seeds", "min_seed_distance", "sampling",
         "dt_max_distance", "impl", "tile", "pair_cap", "edge_cap",
         "exit_cap", "fill_cap", "table_cap", "interpret", "adj_cap",
-        "fill_rounds", "fill_mode",
+        "fill_rounds", "fill_mode", "axes",
     ),
 )
 def _dt_watershed_tiled_jit(
@@ -1612,19 +1678,20 @@ def _dt_watershed_tiled_jit(
     adj_cap: Optional[int] = None,
     fill_rounds: Optional[int] = None,
     fill_mode: str = "capacity",
+    axes: Tuple[int, ...] = VOLUME,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     seeds, valid, seed_overflow, seed_record = _dt_seeds_core(
         boundaries, mask, dist, threshold=threshold, sigma_seeds=sigma_seeds,
         min_seed_distance=min_seed_distance, sampling=sampling,
         dt_max_distance=dt_max_distance, impl=impl, tile=tile,
         pair_cap=pair_cap, edge_cap=edge_cap, table_cap=table_cap,
-        interpret=interpret,
+        interpret=interpret, axes=axes,
     )
     labels, ws_overflow, record = _seeded_watershed_tiled_jit(
         boundaries, seeds, mask=valid, impl=impl, tile=tile,
         exit_cap=exit_cap, fill_cap=fill_cap, table_cap=table_cap,
         interpret=interpret, adj_cap=adj_cap, fill_rounds=fill_rounds,
-        fill_mode=fill_mode,
+        fill_mode=fill_mode, axes=axes,
     )
     return labels, seed_overflow | ws_overflow, work.merge(record, seed_record)
 

@@ -80,8 +80,10 @@ def seeded_watershed(
     on synthetic EM: ~35% fragment impurity vs ~6.5% for the saddle-ordered
     fill).  :func:`cluster_tools_tpu.ops.tile_ws.seeded_watershed_tiled`
     implements the height-ordered (minimum-spanning-forest) fill and is the
-    default task/pipeline kernel; this function remains for 2-D mode,
-    connectivity > 1, and as the fully-seeded oracle.
+    default task/pipeline kernel, in 3-D and per plane (the task's
+    ``two_d``, ``axes=PLANES``); this function remains for connectivity > 1
+    (``impl="legacy"`` too), for the size filter's regrowth, and as the
+    fully-seeded oracle.
     """
     shape = height.shape
     n = int(np.prod(shape))
@@ -126,14 +128,23 @@ def seeded_watershed(
     return lab.reshape(shape)
 
 
-@partial(jax.jit, static_argnames=("connectivity",))
-def local_maxima(x: jnp.ndarray, connectivity: int = 1) -> jnp.ndarray:
-    """Boolean mask of (plateau) local maxima: x >= all neighbors."""
+@partial(jax.jit, static_argnames=("connectivity", "axes"))
+def local_maxima(
+    x: jnp.ndarray, connectivity: int = 1, axes: Optional[Tuple[int, ...]] = None
+) -> jnp.ndarray:
+    """Boolean mask of (plateau) local maxima: x >= all neighbors.  With
+    ``axes`` (connectivity 1) the neighbours along those axes only: maxima
+    of each plane for ``axes=(1, 2)``."""
     shape = x.shape
     m = jnp.ones(shape, bool)
     neg_big = jnp.float32(-3e38)
     xf = x.astype(jnp.float32)
-    for off in _neighbor_offsets(len(shape), connectivity):
+    offsets = _neighbor_offsets(len(shape), connectivity)
+    if axes is not None:
+        if connectivity != 1:
+            raise ValueError("local_maxima: axes needs connectivity 1")
+        offsets = [o for o in offsets if any(o[a] for a in axes)]
+    for off in offsets:
         for o in (off, tuple(-x_ for x_ in off)):
             m &= xf >= _shift_nd(xf, o, neg_big)
     return m
@@ -170,9 +181,14 @@ def distance_transform_watershed(
 
     ``two_d=True`` runs the whole pipeline independently per z-slice (the
     reference's 2-D mode for anisotropic EM volumes), with per-slice label
-    offsets keeping labels unique across the block.  Labels are block-local
-    (min-voxel flat index based); callers globalize by block offset.  vmap
-    over a leading batch axis for mesh-wide execution.
+    offsets keeping labels unique across the block.  The watershed task
+    takes this path for ``two_d`` only with ``connectivity`` > 1 or
+    ``impl="legacy"``: at connectivity 1 it runs the tiled kernels' planar
+    form (``ops/tile_ws.py``, ``axes=PLANES``), whose fill is the exact
+    lowest-saddle flood and not the relaxation of :func:`seeded_watershed`.
+    Labels are block-local (min-voxel flat index based); callers globalize
+    by block offset.  vmap over a leading batch axis for mesh-wide
+    execution.
 
     ``dt_max_distance`` caps the EDT at that physical distance (values below
     the cap stay exact; the cascade cost drops from O(extent) to O(cap) per
@@ -225,13 +241,14 @@ def distance_transform_watershed(
     )
 
 
-@partial(jax.jit, static_argnames=("connectivity", "max_label"))
+@partial(jax.jit, static_argnames=("connectivity", "max_label", "planes"))
 def filter_small_segments(
     labels: jnp.ndarray,
     height: jnp.ndarray,
     min_size: jnp.ndarray,
     connectivity: int = 1,
     max_label: Optional[int] = None,
+    planes: bool = False,
 ) -> jnp.ndarray:
     """Remove segments below ``min_size`` voxels and grow survivors into the
     freed space (reference: vigra ``sizeFilterSegInplace`` inside
@@ -241,7 +258,9 @@ def filter_small_segments(
     ``max_label`` = block voxel count; pass ``2 * N`` for the two-pass
     external-id encoding of :func:`dt_watershed_seeded`); sizes are counted
     with a dense ``segment_sum`` over the block, small segments are cleared,
-    and the watershed fill relaxation re-grows the remaining labels.
+    and the watershed fill relaxation re-grows the remaining labels —
+    within each z-plane where ``planes`` (the per-plane watershed's labels,
+    none of which lies in two planes).
     """
     n = int(np.prod(labels.shape)) if max_label is None else int(max_label)
     flat = labels.ravel().astype(jnp.int32)
@@ -251,6 +270,10 @@ def filter_small_segments(
     small = (sizes[jnp.clip(flat, 0, n)] < min_size) & (flat > 0)
     kept = jnp.where(small, 0, flat).reshape(labels.shape)
     # regrow: freed voxels adopt the label of their lowest labeled neighbor
+    if planes:
+        return jax.vmap(
+            lambda h, k, m: seeded_watershed(h, k, mask=m, connectivity=connectivity)
+        )(height, kept, labels > 0)
     grown = seeded_watershed(
         height, kept, mask=labels > 0, connectivity=connectivity
     )

@@ -116,12 +116,14 @@ def _warn_overflow(logger, block, rec):
 
 
 def _pass_counters(summary, blocks, outer, use_tiled, overflow_blocks,
-                   records):
+                   records, two_d=False):
     """What one sweep of the watershed computed, for the task's manifest and
     its ``ws.pass`` span (docs/OBSERVABILITY.md "Two-pass watershed"): the
     blocks it ran, its dispatches, the lanes that carried no block, and the
     voxels computed (every lane at the kernel's tile-padded outer shape)
-    beside those of the outer blocks and of the inner blocks it stored; and
+    beside those of the outer blocks and of the inner blocks it stored;
+    ``two_d`` and ``planes``, the planes flooded one by one (every lane's
+    tile-padded planes, 0 for a 3-D flood); and
     ``work``, the real lanes' work records as one (``ops/work.py::total``;
     ``records``: block id -> the lane's record, which the span keeps); and
     ``step_cache``, where the sweep's program came from (process, store or
@@ -138,6 +140,8 @@ def _pass_counters(summary, blocks, outer, use_tiled, overflow_blocks,
         "n_blocks": n_blocks,
         "dispatches": int(summary.get("n_dispatches", 0)),
         "lanes_padded": lanes_padded,
+        "two_d": bool(two_d),
+        "planes": (n_blocks + lanes_padded) * int(padded[0]) if two_d else 0,
         "padded_voxels": (n_blocks + lanes_padded) * int(np.prod(padded)),
         "outer_voxels": n_blocks * int(np.prod(outer)),
         "inner_voxels": sum(int(np.prod(b.shape)) for b in blocks),
@@ -180,8 +184,10 @@ class _WsTaskBase(BaseTask):
             # measured 6.5% fragment impurity vs 35% for the legacy ring
             # fill, which can adopt labels THROUGH membranes), "legacy"
             # (round-2 dense fixpoint), or explicit "pallas"/"xla".  2-D
-            # mode and connectivity != 1 always use legacy.  Honored by both
-            # the single-pass and the two-pass (externally seeded) tasks.
+            # mode floods each z-plane on the tiled kernels too (their
+            # planar form); connectivity != 1 always uses legacy.  Honored
+            # by both the single-pass and the two-pass (externally seeded)
+            # tasks.
             "impl": "auto",
             # tiled-kernel capacity knobs (None = the ops-level defaults;
             # ignored by the legacy kernel).  Raise on overflow reports:
@@ -293,17 +299,20 @@ class _WsTaskBase(BaseTask):
         )
 
     def _sweep(self, executor, cfg, kernel, blocks, load, store, block_done,
-               done, out, parity, outer, use_tiled, overflow_blocks, records):
+               done, out, parity, outer, use_tiled, overflow_blocks, records,
+               two_d=False):
         """One sweep of the block grid through the executor, as both passes
         run it; returns the pass's counters (:func:`_pass_counters`).  The
         ``ws.pass`` span is the pass on the timeline: which parity, how many
-        blocks, and (once the sweep is through) the lanes its dispatches
-        carried, the voxels they computed and the real lanes' work records
-        (``work``: one dict a block, by block id; a padding lane's row never
-        reaches ``store``)."""
+        blocks, whether each plane is flooded alone (``two_d``), and (once
+        the sweep is through) the lanes its dispatches carried, the planes
+        and voxels they computed and the real lanes' work records (``work``:
+        one dict a block, by block id; a padding lane's row never reaches
+        ``store``)."""
         todo = [b for b in blocks if b.block_id not in done]
         with trace_mod.span(
-            "ws.pass", task=self.uid, parity=parity, n_blocks=len(todo)
+            "ws.pass", task=self.uid, parity=parity, n_blocks=len(todo),
+            two_d=bool(two_d),
         ) as pass_span:
             summary = executor.map_blocks(
                 kernel,
@@ -341,13 +350,14 @@ class _WsTaskBase(BaseTask):
                 inflight_byte_budget=cfg.get("inflight_byte_budget"),
             )
             counters = _pass_counters(
-                summary, todo, outer, use_tiled, overflow_blocks, records
+                summary, todo, outer, use_tiled, overflow_blocks, records,
+                two_d,
             )
             pass_span.note(
                 lanes=len(todo) + counters["lanes_padded"],
                 work=[dict(records[b], block=b) for b in sorted(records)],
                 **{k: counters[k] for k in (
-                    "dispatches", "padded_voxels", "outer_voxels",
+                    "dispatches", "planes", "padded_voxels", "outer_voxels",
                     "inner_voxels",
                 )},
             )
@@ -429,7 +439,6 @@ class WatershedBase(_WsTaskBase):
         impl = str(cfg.get("impl", "auto"))
         use_tiled = (
             impl != "legacy"
-            and not two_d
             and int(kp.get("connectivity", 1)) == 1
             and len(outer) == 3
         )
@@ -450,6 +459,25 @@ class WatershedBase(_WsTaskBase):
                     lab, b, jnp.int32(size_filter), connectivity=kp["connectivity"]
                 )
             return lab, ovf, rec
+
+        def ws_block_planar(b, m):
+            """``two_d`` on the tiled kernels: one flood per z-plane
+            (upstream's ``apply_dt_2d`` + ``apply_ws_2d``).  A function of
+            its own, so that its sweep program carries its name
+            (``jit_sharded_ws_block_planar``)."""
+            from ..ops.tile_ws import PLANES, dt_watershed_tiled
+
+            lab, ovf, rec = dt_watershed_tiled(
+                b, mask=m, impl=impl, axes=PLANES, **tk
+            )
+            if size_filter > 0:
+                # a freed voxel regrows from its own plane
+                lab = filter_small_segments(
+                    lab, b, jnp.int32(size_filter), planes=True
+                )
+            return lab, ovf, rec
+
+        kernel = ws_block_planar if use_tiled and two_d else ws_block
 
         overflow_blocks = set()
         records = {}  # block id -> the lane's work record
@@ -532,8 +560,9 @@ class WatershedBase(_WsTaskBase):
             )
             self._log_execution(executor, impl, use_tiled)
             counters = self._sweep(
-                executor, cfg, ws_block, blocks_all, load, store, block_done,
+                executor, cfg, kernel, blocks_all, load, store, block_done,
                 done, out, parity, outer, use_tiled, overflow_blocks, records,
+                two_d,
             )
             device_memory = device_peak_bytes(executor.devices)
         return {
@@ -769,9 +798,9 @@ class WatershedWorkflow(WorkflowBase):
 
     #: what a pass's manifest says of its sweep (:func:`_pass_counters`)
     _PASS_KEYS = (
-        "n_blocks", "dispatches", "lanes_padded", "padded_voxels",
-        "outer_voxels", "inner_voxels", "n_ext_labels", "overflow_blocks",
-        "work", "step_cache",
+        "n_blocks", "dispatches", "lanes_padded", "two_d", "planes",
+        "padded_voxels", "outer_voxels", "inner_voxels", "n_ext_labels",
+        "overflow_blocks", "work", "step_cache",
     )
 
     def run_impl(self):

@@ -66,6 +66,11 @@ KERNELS = {
     "tile_ccl": (
         pk.tile_ccl_pallas, [(SHAPE, jnp.bool_)], dict(tile=TILE),
     ),
+    # the per-section watershed's seed plateaus: each z-plane of a tile on
+    # its own (ops/tile_ws.py PLANES)
+    "tile_ccl_planar": (
+        pk.tile_ccl_pallas, [(SHAPE, jnp.bool_)], dict(tile=TILE, axes=(1, 2)),
+    ),
     "tile_ws_propagate": (
         pk.tile_ws_propagate_pallas,
         [(SHAPE, jnp.int32), (SHAPE, jnp.int32)],

@@ -393,9 +393,11 @@ def test_harvest_metric_is_declared_for_every_cell_with_the_fused_step():
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert mine[key] == meta[key]
     # the three cells of the fused step, then (PR 37) the two-pass cell,
-    # whose blockwise programs run the same harvest under a vmap
+    # whose blockwise programs run the same harvest under a vmap, and (PR 41)
+    # the per-section cell, whose planar program harvests two axes
     assert mine["workloads"] == ["fused384.volumes", "fused4x384.volumes.sp4",
-                                 "multicut384.volumes", "twopass125.volumes"]
+                                 "multicut384.volumes", "twopass125.volumes",
+                                 "ws2d125.volumes"]
     assert (mine["unit"], mine["better"], mine["source"]) == ("s", "lower", "device_trace")
     assert (mine["layer"], mine["moves"]) == ("kernels", "voxels_per_s")
     assert meta["stages"] == ["ws.fill.harvest"]
@@ -488,10 +490,11 @@ def test_chase_metric_is_declared_for_every_cell():
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert mine[key] == meta[key]
     # every watershed runs the chase: the fused step's three cells and the
-    # two-pass cell's blockwise programs, there under a vmap over lanes
-    assert mine["workloads"] == [w["name"] for w in bench["workloads"]][:4] == [
+    # two-pass and per-section cells' blockwise programs, there under a vmap
+    # over lanes
+    assert mine["workloads"] == [w["name"] for w in bench["workloads"]] == [
         "fused384.volumes", "fused4x384.volumes.sp4", "multicut384.volumes",
-        "twopass125.volumes"]
+        "twopass125.volumes", "ws2d125.volumes"]
     assert (mine["unit"], mine["better"], mine["source"]) == ("s", "lower", "device_trace")
     assert (mine["layer"], mine["moves"]) == ("kernels", "voxels_per_s")
     assert meta["stages"] == ["ws.flow.chase"]
@@ -505,7 +508,7 @@ LOADS = [
     ("step_load_s", "fused.step_load", "entry and workflow",
      ["fused384.volumes", "fused4x384.volumes.sp4", "multicut384.volumes"]),
     ("executor_program_load_s", "executor.program_load", "executor",
-     ["twopass125.volumes"]),
+     ["twopass125.volumes", "ws2d125.volumes"]),
 ]
 
 
