@@ -174,6 +174,61 @@ def _scatter_set_lanes(axis_size, in_batched, buf, idx, vals):
     return out.reshape(lanes, m), True
 
 
+def lane_cond(pred, true_fn, false_fn, operands):
+    """``lax.cond(pred, true_fn, false_fn, operands)`` that stays a branch
+    under ``vmap``; returns ``(out, ran)``, ``ran`` the pair (true side
+    ran, false side ran) of scalar bools.
+
+    Alone it is that cond and compiles to the same program, with ``ran``
+    ``(pred, ~pred)``.  Under ``vmap`` (the blockwise executor's lanes)
+    ``lax.cond`` with a batched predicate becomes a select, and the device
+    computes both sides for every lane; the rule below branches on the
+    lanes together instead: ``vmap(true_fn)`` runs where any lane's
+    predicate holds, ``vmap(false_fn)`` where any lane's does not, and the
+    select takes each lane's side, so both run only where the lanes
+    disagree.  Every lane gets the side its own predicate picks, so the
+    results are those of the select; only a side no lane asked for is left
+    out.  ``ran`` then says what the device ran for the batch (unbatched:
+    one value for all lanes).
+
+    The branches may not close over a value that is batched (the rule
+    cannot see it): pass such values in ``operands``."""
+
+    @jax.custom_batching.custom_vmap
+    def cond(pred, operands):
+        return lax.cond(pred, true_fn, false_fn, operands), (pred, ~pred)
+
+    @cond.def_vmap
+    def _lanes(axis_size, in_batched, pred, operands):
+        pred_batched, ops_batched = in_batched
+        in_axes = (jax.tree.map(lambda b: 0 if b else None, ops_batched),)
+        on_lanes, off_lanes = (
+            jax.vmap(fn, in_axes=in_axes, axis_size=axis_size)
+            for fn in (true_fn, false_fn)
+        )
+        if not pred_batched:
+            out = lax.cond(pred, on_lanes, off_lanes, operands)
+            ran = (pred, ~pred)
+        else:
+            # each side once in the program, run where a lane asks for it;
+            # the side nobody asked for leaves zeros the select never picks
+            shapes = jax.eval_shape(on_lanes, operands)
+
+            def nothing(_):
+                return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+
+            def select(a, b):
+                return jnp.where(pred.reshape(pred.shape + (1,) * (a.ndim - 1)), a, b)
+
+            ran = (jnp.any(pred), ~jnp.all(pred))
+            out = jax.tree.map(
+                select, lax.cond(ran[0], on_lanes, nothing, operands),
+                lax.cond(ran[1], off_lanes, nothing, operands))
+        return (out, ran), (jax.tree.map(lambda _: True, out), (False, False))
+
+    return cond(pred, operands)
+
+
 def _compact(
     flags: jnp.ndarray, values: Tuple[jnp.ndarray, ...], cap: int, fill: int
 ):
@@ -200,7 +255,8 @@ def _compact(
 def run_capacity_tiered(arrays, n_total, big_cap, core, n_padded,
                         max_rounds, vma_like):
     """Run ``core(*arrays, cap, max_rounds, vma_like)`` at 1/16 capacity
-    when the runtime entry count allows.
+    when the runtime entry count allows; returns ``(outputs, counts)``,
+    the last the tier's branch in the work record (:func:`work.branches`).
 
     Every sort inside a merge core runs at its STATIC buffer size, so a
     typical volume (real entries ≪ capacity) would sort ~all padding.
@@ -222,7 +278,8 @@ def run_capacity_tiered(arrays, n_total, big_cap, core, n_padded,
     small_n = min(big_cap, max(3 * 16384, arrays[0].shape[0] // 16))
 
     def _small(args):
-        compacted, _ = _compact(args[0] < BIG, args, small_n, BIG)
+        arrays, vma_like = args
+        compacted, _ = _compact(arrays[0] < BIG, arrays, small_n, BIG)
         out = core(*compacted, small_n, max_rounds, vma_like)
         padded = tuple(
             jnp.pad(
@@ -234,11 +291,14 @@ def run_capacity_tiered(arrays, n_total, big_cap, core, n_padded,
         return padded + out[n_padded:]
 
     def _big(args):
-        return core(*args, big_cap, max_rounds, vma_like)
+        arrays, vma_like = args
+        return core(*arrays, big_cap, max_rounds, vma_like)
 
+    args = (tuple(arrays), vma_like)
     if small_n >= big_cap:
-        return _big(tuple(arrays))
-    return lax.cond(n_total <= small_n, _small, _big, tuple(arrays))
+        return _big(args), {}
+    out, ran = lane_cond(n_total <= small_n, _small, _big, args)
+    return out, work.branches(ran)
 
 
 def _face_pairs_axis(
@@ -287,8 +347,8 @@ def merge_face_pairs(
     when a capacity was exceeded or the union-find hit ``max_rounds``
     unconverged (labels would be under-merged — callers re-run with bigger
     caps or fall back); ``counts`` is the merge's part of the work record
-    (:mod:`.work`): the fullest axis's pairs, the edges, and the flag split
-    by what tripped.
+    (:mod:`.work`): the fullest axis's pairs, the edges, the flag split by
+    what tripped and the capacity tier's branch.
     """
     pair_lists = []
     most_pairs = _match_vma(jnp.zeros((), jnp.int32), labels)
@@ -304,17 +364,17 @@ def merge_face_pairs(
     a = _match_vma(jnp.concatenate([p[0] for p in pair_lists]), labels)
     b = _match_vma(jnp.concatenate([p[1] for p in pair_lists]), labels)
 
-    ea, eb, root_a, root_b, n_edges, edge_over, unconverged = (
+    (ea, eb, root_a, root_b, n_edges, edge_over, unconverged), tier = (
         run_capacity_tiered(
             (a, b), n_total, edge_cap, _merge_core, 4, max_rounds, labels
         )
     )
-    counts = {
+    counts = work.join(tier, {
         work.CCL_PAIRS: most_pairs, work.CCL_EDGES: n_edges,
         work.OVER_EDGE: (most_pairs > pair_cap) | edge_over,
         work.OVER_ROUNDS: unconverged,
         work.CAP_PAIR: pair_cap, work.CAP_EDGE: edge_cap,
-    }
+    })
     return ea, eb, root_a, root_b, n_edges, work.any_over(counts), counts
 
 
@@ -395,10 +455,11 @@ def build_remap_tables(
 
     ``tile_ids``: which tile each entry belongs to (``BIG`` = drop the
     entry); duplicates of (tile, old) collapse to one slot.  Returns
-    ``(old_tbl, new_tbl, overflow, tile_max)`` with tables shaped
+    ``(old_tbl, new_tbl, overflow, tile_max, counts)`` with tables shaped
     ``(n_tiles, table_cap)``, unused slots holding -1; ``tile_max`` is the
     number of entries of the fullest tile, and ``overflow`` says that it
-    passed ``table_cap`` (the caller then resolves by a gather instead).
+    passed ``table_cap`` (the caller then resolves by a gather instead);
+    ``counts`` is the tier's branch in the work record.
 
     The sort runs at the static input size; table shapes don't depend on
     it, so the usual 1/16 capacity tier applies with no scatter-back —
@@ -406,6 +467,7 @@ def build_remap_tables(
     """
     n_in = tile_ids.shape[0]
     small_n = max(16384, n_in // 16)
+    args = (tile_ids, old_vals, new_vals)
     if small_n < n_in:
         n_live = (tile_ids < BIG).sum()
 
@@ -416,10 +478,9 @@ def build_remap_tables(
         def _big(args):
             return _remap_tables_core(*args, n_tiles, table_cap)
 
-        return lax.cond(
-            n_live <= small_n, _small, _big, (tile_ids, old_vals, new_vals)
-        )
-    return _remap_tables_core(tile_ids, old_vals, new_vals, n_tiles, table_cap)
+        out, ran = lane_cond(n_live <= small_n, _small, _big, args)
+        return (*out, work.branches(ran))
+    return (*_remap_tables_core(*args, n_tiles, table_cap), {})
 
 
 def _remap_tables_core(tile_ids, old_vals, new_vals, n_tiles, table_cap):
@@ -554,24 +615,29 @@ def label_components_tiled(
             tids = jnp.where(
                 changed, _tile_id_of(v, (zp, yp, xp), tile), jnp.int32(BIG)
             )
-            old_tbl, new_tbl, tbl_overflow, tile_max = build_remap_tables(
+            old_tbl, new_tbl, tbl_overflow, tile_max, tier = build_remap_tables(
                 tids, v, r, n_tiles, table_cap=table_cap
             )
-            counts[work.CCL_REMAP_TILE_MAX] = tile_max
-            counts[work.CCL_REMAP_FALLBACK] = tbl_overflow
 
             def fast(args):
-                labels, old_tbl, new_tbl = args
+                labels, (old_tbl, new_tbl), _ = args
                 return apply_remap_pallas(
                     labels, old_tbl, new_tbl, tile=tile, cap=table_cap,
                     interpret=interpret,
                 )
 
             def slow(args):
-                labels, _, _ = args
-                return resolve_labels_gather(labels, ea, eb, root_a, root_b)
+                labels, _, edges = args
+                return resolve_labels_gather(labels, *edges)
 
-            resolved = lax.cond(tbl_overflow, slow, fast, (labels, old_tbl, new_tbl))
+            resolved, ran = lane_cond(
+                tbl_overflow, slow, fast,
+                (labels, (old_tbl, new_tbl), (ea, eb, root_a, root_b)),
+            )
+            counts = work.join(counts, tier, work.branches(ran, fallback=True), {
+                work.CCL_REMAP_TILE_MAX: tile_max,
+                work.CCL_REMAP_FALLBACK: tbl_overflow,
+            })
         else:
             resolved = resolve_labels_gather(labels, ea, eb, root_a, root_b)
 

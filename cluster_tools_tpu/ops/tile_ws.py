@@ -69,7 +69,7 @@ from jax import lax
 
 from . import work
 from .ccl import _match_vma, _shift, _true_like
-from .pallas_kernels import WS_OFFS
+from .pallas_kernels import WS_OFFS, apply_remap_pallas
 from .tile_ccl import (
     BIG,
     DEFAULT_TABLE_CAP,
@@ -82,6 +82,7 @@ from .tile_ccl import (
     _tile_id_of,
     build_remap_tables,
     label_components_tiled,
+    lane_cond,
     resolve_impl,
     run_capacity_tiered,
 )
@@ -381,9 +382,10 @@ def collect_negative_values(
     occupies, so this covers all (tile, value) incidences needed for exits
     and fill remaps; a flood along ``axes`` crosses tile faces across those
     axes only, so only their strips are read.  Returns ``(vals, tids,
-    overflow, n_kept, family_max)``: the deduped pairs, whether they or one
-    strip family passed ``cap``, how many pairs there are and how many
-    entries the fullest family held.
+    overflow, n_kept, family_max, counts)``: the deduped pairs, whether they
+    or one strip family passed ``cap``, how many pairs there are, how many
+    entries the fullest family held and the dedup tier's branch in the work
+    record.
     """
     vs, ts = [], []
     overflow = _match_vma(jnp.zeros((), jnp.int32), values)
@@ -416,11 +418,11 @@ def collect_negative_values(
     # size (≤ 6*cap; ~half of it at 512³ thanks to the strip-size bounds
     # above) — tier it like the merge cores (shared rationale in
     # run_capacity_tiered)
-    cv, ct, n_kept = run_capacity_tiered(
+    (cv, ct, n_kept), tier = run_capacity_tiered(
         (v, t_), n_total, cap, _collect_core, 2, 0, values
     )
     overflow = jnp.maximum(overflow, (n_kept > cap).astype(jnp.int32))
-    return cv, ct, overflow > 0, n_kept, family_max
+    return cv, ct, overflow > 0, n_kept, family_max, tier
 
 
 def _collect_core(v, t_, cap, _max_rounds, _vma_like):
@@ -434,8 +436,9 @@ def _collect_core(v, t_, cap, _max_rounds, _vma_like):
 
 def value_join(
     query_vals: jnp.ndarray, table_vals: jnp.ndarray, table_finals: jnp.ndarray
-) -> jnp.ndarray:
-    """For each query value, the table's final (or the query itself if absent).
+) -> Tuple[jnp.ndarray, dict]:
+    """For each query value, the table's final (or the query itself if
+    absent), and the tier's branch in the work record.
 
     Sort-based join — ``searchsorted`` lowers to a binary-search gather chain
     that measured ~50x slower than a sort at these sizes on TPU.
@@ -466,11 +469,12 @@ def value_join(
         def _big(args):
             return _value_join_core(*args)
 
-        return lax.cond(
+        out, ran = lane_cond(
             (n_q <= small_q) & (n_t <= small_t), _small, _big,
             (query_vals, table_vals, table_finals),
         )
-    return _value_join_core(query_vals, table_vals, table_finals)
+        return out, work.branches(ran)
+    return _value_join_core(query_vals, table_vals, table_finals), {}
 
 
 def _value_join_core(query_vals, table_vals, table_finals):
@@ -675,10 +679,12 @@ def fill_unseeded_basins(
     # common case runs the whole dedup+Boruvka machine at 1/16 size
     # (rationale + the shared threshold live in
     # tile_ccl.run_capacity_tiered).
-    edge_vals, edge_finals, n_adj, adj_over, unconverged = run_capacity_tiered(
-        (a, b, hk), n_total, adj_cap, _fill_core, 2, max_rounds, labels
+    (edge_vals, edge_finals, n_adj, adj_over, unconverged), tier = (
+        run_capacity_tiered(
+            (a, b, hk), n_total, adj_cap, _fill_core, 2, max_rounds, labels
+        )
     )
-    counts.update({
+    counts = work.join(counts, tier, {
         work.FILL_ADJACENCIES: n_adj,
         work.OVER_FACE: reduce(jnp.logical_or, [
             counts[work.FILL_FACES[axis]] > fill_cap for axis in axes]),
@@ -1367,7 +1373,7 @@ def _ws_flow_core(
 
     # cross-tile exits: collect, chase, remap
     with jax.named_scope("ws.flow.exits"):
-        codes, code_tiles, exit_over, n_exits, family_max = (
+        codes, code_tiles, exit_over, n_exits, family_max, tier = (
             collect_negative_values(values, tile, exit_cap, axes)
         )
     with jax.named_scope("ws.flow.chase"):
@@ -1375,8 +1381,7 @@ def _ws_flow_core(
     values, remap_counts = _remap_exits(
         values, codes, code_tiles, finals, impl, tile, table_cap, interpret
     )
-    counts.update(remap_counts)
-    counts.update({
+    counts = work.join(counts, tier, remap_counts, {
         work.FLOW_EXITS: n_exits, work.FLOW_EXIT_FAMILY_MAX: family_max,
         work.OVER_EXIT: exit_over, work.OVER_HOPS: chase_unconverged,
         work.CAP_EXIT: exit_cap, work.CAP_TABLE: table_cap,
@@ -1395,29 +1400,39 @@ def _remap_exits(values, codes, code_tiles, finals, impl, tile, table_cap,
     n_tiles = (zp // tz) * (yp // ty) * (xp // tx)
 
     if impl == "pallas":
-        from .pallas_kernels import apply_remap_pallas
-
         changed = (codes <= -2) & (finals != codes)
         tids = jnp.where(changed, code_tiles, jnp.int32(BIG))
-        old_tbl, new_tbl, tbl_overflow, tile_max = build_remap_tables(
+        old_tbl, new_tbl, tbl_overflow, tile_max, tier = build_remap_tables(
             tids, codes, finals, n_tiles, table_cap=table_cap
         )
-
-        def fast(args):
-            v, o, nw = args
-            return apply_remap_pallas(
-                v, o, nw, tile=tile, cap=table_cap, interpret=interpret
-            )
-
-        def slow(args):
-            v, _, _ = args
-            return _resolve_codes_gather(v, codes, finals)
-
-        return lax.cond(tbl_overflow, slow, fast, (values, old_tbl, new_tbl)), {
+        values, ran = lane_cond(
+            tbl_overflow, _gather_codes, _apply_tables(tile, table_cap, interpret),
+            (values, (old_tbl, new_tbl), (codes, finals)),
+        )
+        return values, work.join(tier, work.branches(ran, fallback=True), {
             work.FLOW_REMAP_TILE_MAX: tile_max,
             work.FLOW_REMAP_FALLBACK: tbl_overflow,
-        }
+        })
     return _resolve_codes_gather(values, codes, finals), {}
+
+
+def _gather_codes(args):
+    """The remap fallback's side of ``lane_cond``: the volume-sized gather."""
+    values, _, (codes, finals) = args
+    return _resolve_codes_gather(values, codes, finals)
+
+
+def _apply_tables(tile, table_cap, interpret):
+    """The remap's Mosaic side of ``lane_cond``: the per-tile tables."""
+
+    def fast(args):
+        values, (old_tbl, new_tbl), _ = args
+        return apply_remap_pallas(
+            values, old_tbl, new_tbl, tile=tile, cap=table_cap,
+            interpret=interpret,
+        )
+
+    return fast
 
 
 @jax.named_scope("ws.fill")
@@ -1474,16 +1489,13 @@ def _ws_fill_core(
     n_tiles = (zp // tz) * (yp // ty) * (xp // tx)
 
     if impl == "pallas":
-        from .pallas_kernels import apply_remap_pallas
-
         # tiles needing a basin's entry: strip incidences + the terminal's tile
-        bvals, btiles, b_overflow, _, _ = collect_negative_values(
+        bvals, btiles, b_overflow, _, _, collect_tier = collect_negative_values(
             values, tile, exit_cap, axes
         )
         overflow = overflow | b_overflow
-        counts.update({work.OVER_EXIT: b_overflow, work.CAP_EXIT: exit_cap})
         # map each (value, tile) incidence to its fill final
-        bfin = value_join(bvals, fill_vals, fill_finals)
+        bfin, join_tier = value_join(bvals, fill_vals, fill_finals)
         # terminal-tile incidences for interior basins
         tvals = fill_vals
         t_of = _tile_id_of(jnp.where(tvals <= -2, -tvals - 2, 0), (zp, yp, xp), tile)
@@ -1494,25 +1506,22 @@ def _ws_fill_core(
             [jnp.where((bvals <= -2) & (bfin != bvals), btiles, jnp.int32(BIG)),
              jnp.where((tvals <= -2) & (fill_finals != tvals), ttiles, jnp.int32(BIG))]
         )
-        old2, new2, tbl_overflow2, tile_max2 = build_remap_tables(
+        old2, new2, tbl_overflow2, tile_max2, table_tier = build_remap_tables(
             all_tiles, all_vals, all_fin, n_tiles, table_cap=table_cap
         )
-        counts.update({
-            work.FILL_REMAP_TILE_MAX: tile_max2,
-            work.FILL_REMAP_FALLBACK: tbl_overflow2,
-        })
-
-        def fast2(args):
-            v, o, nw = args
-            return apply_remap_pallas(
-                v, o, nw, tile=tile, cap=table_cap, interpret=interpret
-            )
-
-        def slow2(args):
-            v, _, _ = args
-            return _resolve_codes_gather(v, fill_vals, fill_finals)
-
-        values = lax.cond(tbl_overflow2, slow2, fast2, (values, old2, new2))
+        values, ran = lane_cond(
+            tbl_overflow2, _gather_codes,
+            _apply_tables(tile, table_cap, interpret),
+            (values, (old2, new2), (fill_vals, fill_finals)),
+        )
+        counts = work.join(
+            counts, collect_tier, join_tier, table_tier,
+            work.branches(ran, fallback=True), {
+                work.OVER_EXIT: b_overflow, work.CAP_EXIT: exit_cap,
+                work.FILL_REMAP_TILE_MAX: tile_max2,
+                work.FILL_REMAP_FALLBACK: tbl_overflow2,
+            },
+        )
     else:
         values = _resolve_codes_gather(values, fill_vals, fill_finals)
 

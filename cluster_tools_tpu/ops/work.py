@@ -1,13 +1,14 @@
 """The work record: what a watershed / CCL device program counted while it ran.
 
 The tiled kernels decide their own cost from the data: loops run a number of
-trips read from live counts, ``lax.cond`` branches pick a volume-sized
-fallback when a per-tile table overflows, and every list is a static
-capacity that a count either fits or trips.  The counts exist as loop state,
-as predicate operands and as what ``_compact`` returns; this module is the
-one table of their names, so that a program can hand them out beside its
-labels as one small ``int32`` vector and the host can read them back by name
-(docs/OBSERVABILITY.md "The work record").
+trips read from live counts, branches (``tile_ccl.lane_cond``) pick a
+volume-sized fallback when a per-tile table overflows or a capacity tier by
+the live count, and every list is a static capacity that a count either fits
+or trips.  The counts exist as loop state, as predicate operands, as what
+``_compact`` returns and as what ``lane_cond`` says the device ran; this
+module is the one table of their names, so that a program can hand them out
+beside its labels as one small ``int32`` vector and the host can read them
+back by name (docs/OBSERVABILITY.md "The work record").
 
 Inside a program a part of it collects ``{name: int32 scalar}`` under the
 constants below and the program's edge packs the whole dict once
@@ -88,6 +89,15 @@ SEEDS_REMAP_FALLBACK = "seeds.remap_fallback"
 STEP_FRAGMENTS = "step.fragments"
 STEP_COMPONENTS = "step.components"
 
+# -- branches: ops/tile_ccl.py::lane_cond, at every cond of the kernels -------
+#: volume-sized fallback branches (the ``*.remap_fallback`` conds) the device
+#: ran, and conds that ran both sides because the lanes of a ``vmap``
+#: disagreed; facts of the batch, not of a lane: every lane's record carries
+#: the batch's (:func:`branches`)
+BRANCH_FALLBACKS_RUN = "branch.fallbacks_run"
+BRANCH_BOTH_SIDES = "branch.both_sides"
+BRANCH = (BRANCH_FALLBACKS_RUN, BRANCH_BOTH_SIDES)
+
 # -- the overflow flag, by what tripped -------------------------------------
 OVER_EXIT = "over.exit"
 OVER_FACE = "over.face"
@@ -122,7 +132,7 @@ NAMES = (
     FILL_ADJACENCIES, FILL_REMAP_TILE_MAX, FILL_REMAP_FALLBACK,
     CCL_PAIRS, CCL_EDGES, CCL_REMAP_TILE_MAX, CCL_REMAP_FALLBACK,
     SEEDS_PAIRS, SEEDS_EDGES, SEEDS_REMAP_TILE_MAX, SEEDS_REMAP_FALLBACK,
-    STEP_FRAGMENTS, STEP_COMPONENTS,
+    STEP_FRAGMENTS, STEP_COMPONENTS, *BRANCH,
     *OVER,
     CAP_EXIT, CAP_FACE, CAP_BASIN, CAP_ADJ, CAP_PAIR, CAP_EDGE, CAP_SEED_PAIR,
     CAP_SEED_EDGE, CAP_TABLE, CAP_LABELS,
@@ -130,10 +140,11 @@ NAMES = (
 
 #: count -> the capacity it is read against, where it has one.  A hard
 #: capacity raises the overflow flag when passed; the table capacity is soft:
-#: passing it selects a ``*_fallback`` branch (1 where the volume-sized gather
-#: ran; under ``vmap`` a ``lax.cond`` is a select and both sides run, so the
-#: record reports the predicate; -1 where the program has no such branch,
-#: ``impl="xla"``) and the labels stay exact.
+#: passing it selects a ``*_fallback`` branch (1 where the lane's predicate
+#: asks for the volume-sized gather; under ``vmap`` the device runs it for
+#: the batch where any lane asks, which ``branch.fallbacks_run`` counts; -1
+#: where the program has no such branch, ``impl="xla"``) and the labels stay
+#: exact.
 CAPACITY = {
     FLOW_EXITS: CAP_EXIT, FLOW_EXIT_FAMILY_MAX: CAP_EXIT,
     FILL_FACES_Z: CAP_FACE, FILL_FACES_Y: CAP_FACE, FILL_FACES_X: CAP_FACE,
@@ -184,7 +195,7 @@ NOT_THE_CAPACITY = {
 #: combined over lanes by the largest, not the sum
 _BY_MAX = frozenset(
     n for n in NAMES if n.startswith("cap.") or n.endswith("_tile_max")
-    or n == FLOW_EXIT_FAMILY_MAX
+    or n == FLOW_EXIT_FAMILY_MAX or n in BRANCH
 )
 
 
@@ -204,8 +215,9 @@ def pack(counts: Mapping[str, object]):
 
 def join(*parts: Mapping[str, object]) -> Dict[str, object]:
     """The parts of one program's record as one dict, before :func:`pack`:
-    an ``over.*`` bit that several parts raise is their OR, every other
-    name is counted by one part (a capacity given twice is the same)."""
+    an ``over.*`` bit that several parts raise is their OR, the ``branch.*``
+    counts of several parts are added, every other name is counted by one
+    part (a capacity given twice is the same)."""
     out: Dict[str, object] = {}
     for part in parts:
         for name, v in part.items():
@@ -213,16 +225,39 @@ def join(*parts: Mapping[str, object]) -> Dict[str, object]:
                 v = v > 0
                 if name in out:
                     v = out[name] | v
+            elif name in BRANCH and name in out:
+                v = out[name] + v
             out[name] = v
     return out
 
 
-def merge(*records):
-    """Records of programs run one after another over the same data: a name
-    is counted by one of them, the others hold ``UNSET`` there."""
+def branches(ran, fallback: bool = False) -> Dict[str, object]:
+    """One cond's part of the record from what ``lane_cond`` says the device
+    ran, ``(true side, false side)``: whether it ran both, and, for a
+    ``fallback`` cond (its true side the volume-sized gather), whether it
+    ran that."""
     import jax.numpy as jnp
 
-    return reduce(jnp.maximum, records)
+    took, left = ran
+    counts = {BRANCH_BOTH_SIDES: (took & left).astype(jnp.int32)}
+    if fallback:
+        counts[BRANCH_FALLBACKS_RUN] = took.astype(jnp.int32)
+    return counts
+
+
+def merge(*records):
+    """Records of programs run one after another over the same data: a name
+    is counted by one of them, the others hold ``UNSET`` there; the
+    ``branch.*`` counts of those that made them are added."""
+    import jax.numpy as jnp
+
+    added = np.array([name in BRANCH for name in NAMES])
+
+    def two(a, b):
+        both = added & (a != UNSET) & (b != UNSET)
+        return jnp.where(both, a + b, jnp.maximum(a, b))
+
+    return reduce(two, records)
 
 
 def as_seed_ccl(record):

@@ -330,7 +330,7 @@ def test_value_join_small_tier_matches_core(rng, n_t, n_q):
     import jax.numpy as jnp
 
     got = np.asarray(value_join(
-        jnp.asarray(queries), jnp.asarray(table), jnp.asarray(finals)))
+        jnp.asarray(queries), jnp.asarray(table), jnp.asarray(finals))[0])
     want = np.asarray(_value_join_core(
         jnp.asarray(queries), jnp.asarray(table), jnp.asarray(finals)))
     np.testing.assert_array_equal(got, want)
@@ -363,7 +363,8 @@ def test_collect_negative_values_tier_matches_oracle(rng, p, fits):
     small_n = max(3 * 16384, (4 * 32768 + 2 * 4096) // 16)
     assert small_n < cap and (n_total <= small_n) == fits
 
-    cv, ct, overflow, _, _ = collect_negative_values(jnp.asarray(values), tile, cap)
+    cv, ct, overflow, _, _, _ = collect_negative_values(
+        jnp.asarray(values), tile, cap)
     cv, ct = np.asarray(cv), np.asarray(ct)
     assert not bool(overflow)
     tid = (idx[0] // 16 * (shape[1] // 16) + idx[1] // 16) * (shape[2] // 128) \

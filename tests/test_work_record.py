@@ -87,7 +87,8 @@ def test_no_module_but_the_table_spells_a_counters_name():
                     if f'"{n}"' in text or f"'{n}'" in text]
     assert spelled == []
     # and what the benchmark's readers are told to read is in the table
-    for metric in ("capacity_fallbacks", "capacity_peak_fill", "live_slot_share"):
+    for metric in ("capacity_fallbacks", "capacity_peak_fill", "live_slot_share",
+                   "fallback_branches_run"):
         with open(os.path.join(ROOT, "benchmark", "metrics", metric + ".json")) as f:
             meta = json.load(f)
         named = set(meta.get("fallbacks", []))
@@ -96,6 +97,7 @@ def test_no_module_but_the_table_spells_a_counters_name():
             named |= {walk["capacity"], walk["trips"], *walk["live"],
                       *walk["live_up_to_capacity"]}
         named |= set(meta.get("tails", []))
+        named |= {meta[key] for key in ("ran", "both_sides") if key in meta}
         assert named and named <= set(work.NAMES), metric
 
 
@@ -470,32 +472,41 @@ ONE = _rec(flow__exits=1000, flow__exit_family_max=400, flow__chase_hops=3,
            fill__harvest_trips=5, fill__basins=40, fill__round_trips=5,
            fill__live_faces=896, seeds__remap_tile_max=12, seeds__remap_fallback=0,
            ccl__remap_fallback=0, cap__exit=1600, cap__face=3200, cap__basin=4096,
-           cap__table=64)
+           cap__table=64, branch__fallbacks_run=1, branch__both_sides=0)
 #: a second shard: emptier lists, but its seed CCL's table overflows too
-TWO = dict(ONE, **{work.FLOW_EXITS: 160, work.SEEDS_REMAP_FALLBACK: 1})
+TWO = dict(ONE, **{work.FLOW_EXITS: 160, work.SEEDS_REMAP_FALLBACK: 1,
+                   work.BRANCH_FALLBACKS_RUN: 2})
+#: the batch of ONE's and TWO's lanes runs both fallbacks, and the seed
+#: CCL's cond on both sides: every lane's record says so
+BATCH = {work.BRANCH_FALLBACKS_RUN: 2, work.BRANCH_BOTH_SIDES: 1}
 ONE_LIVE = 100.0 * (1168 + 896 + 800) / (13 * 100 + (5 + 5) * 200)
 
 READINGS = {
     # one program execution, one shard
     "one": ([("fused.wait", [ONE])],
             dict(capacity_fallbacks=1, capacity_peak_fill=62.5,
-                 live_slot_share=ONE_LIVE)),
+                 live_slot_share=ONE_LIVE, fallback_branches_run=1)),
     # a branch counts once an execution, however many lanes took it (the
     # exits' in both lanes of the first pass, the seed CCL's in one); two
     # executions (the two passes of a sweep) count apart.  TWO walks what
     # ONE walks, so three equal walks give ONE's share
-    "two": ([("ws.pass", [ONE, TWO]), ("ws.pass", [TWO])],
+    "two": ([("ws.pass", [dict(ONE, **BATCH), dict(TWO, **BATCH)]),
+             ("ws.pass", [TWO])],
             dict(capacity_fallbacks=4, capacity_peak_fill=62.5,
-                 live_slot_share=ONE_LIVE)),
+                 live_slot_share=ONE_LIVE, fallback_branches_run=4)),
     # a program from before the record: spans without the argument
     "none": ([("fused.wait", None), ("task.run", None)],
              dict(capacity_fallbacks=None, capacity_peak_fill=None,
-                  live_slot_share=None)),
+                  live_slot_share=None, fallback_branches_run=None)),
+    # a record from before the branch counts: the new reader has nothing
+    "before": ([("ws.pass", [dict(ONE, **{n: work.UNSET for n in work.BRANCH})])],
+               dict(capacity_fallbacks=1, capacity_peak_fill=62.5,
+                    live_slot_share=ONE_LIVE, fallback_branches_run=None)),
 }
 
 
 @pytest.mark.parametrize("metric", ["capacity_fallbacks", "capacity_peak_fill",
-                                    "live_slot_share"])
+                                    "live_slot_share", "fallback_branches_run"])
 @pytest.mark.parametrize("case", sorted(READINGS))
 def test_readers_on_hand_made_records(case, metric):
     spans, want = READINGS[case]
@@ -509,6 +520,23 @@ def test_readers_on_hand_made_records(case, metric):
         assert got is None
     else:
         assert got == pytest.approx(want[metric])
+
+
+def test_fallback_branches_run_says_each_execution(capfd):
+    traced = dict(job={"t0": 0.0, "t1": 100.0}, runtime_spans=[
+        {"ph": "X", "name": "ws.pass", "ts": 1.0 + i, "dur": 0.5,
+         "args": {"work": records}}
+        for i, records in enumerate([[dict(ONE, **BATCH)] * 2, [TWO]])])
+    meta = bench_run.load_json(
+        ROOT, "benchmark", "metrics", "fallback_branches_run.json")
+    assert bench_run.load_reader("fallback_branches_run").read(traced, meta) == 4
+    lines = [ln for ln in capfd.readouterr().err.splitlines()
+             if ln.startswith("[fallback_branches_run]")]
+    assert lines == [
+        "[fallback_branches_run] execution 0 ws.pass: "
+        "branch.fallbacks_run=2 branch.both_sides=1",
+        "[fallback_branches_run] execution 1 ws.pass: "
+        "branch.fallbacks_run=2 branch.both_sides=0"]
 
 
 def test_live_slot_share_says_each_walk_and_the_tails(capfd):
