@@ -22,6 +22,13 @@ one-for-one onto the flattened flax leaves, with layout rules per kind:
 
 A shape/kind mismatch raises with the full remaining-leaf diff rather than
 producing silently-wrong weights.
+
+pytorch-3dunet checkpoints (``pytorch3dunet.unet3d.model.UNet3D``, the
+flax twin :class:`~.unet_gcr.UNet3DGCR`) are matched by name instead
+(:func:`pytorch_3dunet_to_flax`): ``encoders.{l}.basic_module.SingleConv{k}
+.{groupnorm,conv}.*`` -> ``enc{l}/SingleConv{k}/...``, ``decoders.{i}...``
+-> ``dec{L-2-i}/...`` (the source lists its decoders deepest first), and
+``final_conv.*``.
 """
 
 from __future__ import annotations
@@ -235,16 +242,67 @@ def torch_state_dict_to_flax(
     return tu.unflatten_dict(flat)
 
 
+def pytorch_3dunet_to_flax(state_dict: Mapping[str, Any], model) -> Dict:
+    """A pytorch-3dunet ``UNet3D`` ``state_dict`` -> ``model``'s flax
+    variables (``model`` a :class:`~.unet_gcr.UNet3DGCR` of the same
+    widths), matched by name.  A tensor the model does not hold, one it
+    holds that the checkpoint lacks, or a shape that does not convert is
+    refused, naming the tensor."""
+    import flax.traverse_util as tu
+
+    sample = (1,) + tuple(model.input_multiple()) + (1,)
+    template = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros(sample, jnp.float32)
+    )
+    levels = int(model.num_levels)
+
+    def torch_key(path):
+        head, *inner, leaf = path
+        if head.startswith("dec"):   # the source lists its decoders deepest first
+            head = f"decoders.{levels - 2 - int(head[3:])}.basic_module"
+        elif head.startswith("enc"):
+            head = f"encoders.{int(head[3:])}.basic_module"
+        leaf = {"kernel": "weight", "scale": "weight", "bias": "bias"}[leaf]
+        return ".".join([head, *inner, leaf])
+
+    leaves = tu.flatten_dict(template["params"])
+    wanted = {torch_key(p): p for p in leaves}
+    given = {k: v for k, v in state_dict.items() if "num_batches_tracked" not in k}
+    extra = sorted(set(given) - set(wanted))
+    missing = sorted(set(wanted) - set(given))
+    if extra or missing:
+        raise ValueError(
+            "not a pytorch-3dunet UNet3D of these widths: "
+            + (f"tensor {extra[0]!r} has no place in the model" if extra
+               else f"tensor {missing[0]!r} is missing from the checkpoint")
+            + f" ({len(extra)} extra, {len(missing)} missing)"
+        )
+    flat = {}
+    for key, tensor in given.items():   # the checkpoint's order: first misfit named
+        path = wanted[key]
+        flat[("params",) + path] = jnp.asarray(
+            _convert_leaf(path, leaves[path], key, _to_array(tensor)),
+            dtype=jnp.float32,
+        )
+    return tu.unflatten_dict(flat)
+
+
 def load_torch_checkpoint(path: str, model, sample_shape) -> Dict:
     """Load a ``.pt``/``.pth`` torch checkpoint file into flax variables.
 
     Accepts a raw ``state_dict`` or the common wrapper dicts
-    (``{"state_dict": ...}`` / ``{"model_state_dict": ...}``).
+    (``{"state_dict": ...}`` / ``{"model_state_dict": ...}``); a
+    :class:`~.unet_gcr.UNet3DGCR` is matched by name
+    (:func:`pytorch_3dunet_to_flax`), every other model by position.
     """
     import torch
 
+    from .unet_gcr import UNet3DGCR
+
     obj = torch.load(path, map_location="cpu", weights_only=True)
     obj = _unwrap_state_dict(obj, path)
+    if isinstance(model, UNet3DGCR):
+        return pytorch_3dunet_to_flax(obj, model)
     return torch_state_dict_to_flax(obj, model, sample_shape)
 
 

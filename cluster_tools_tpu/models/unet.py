@@ -21,6 +21,8 @@ from typing import Any, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .unet_gcr import UNet3DGCR
+
 
 class ConvBlock(nn.Module):
     features: int
@@ -84,7 +86,7 @@ class UNet3D(nn.Module):
         return x
 
 
-_MODELS = {"unet3d": UNet3D}
+_MODELS = {"unet3d": UNet3D, "unet3d_gcr": UNet3DGCR}
 
 
 def get_model(name: str, **kwargs) -> nn.Module:

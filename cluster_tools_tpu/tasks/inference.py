@@ -11,9 +11,19 @@ double-buffered host IO.
 Params: ``input_path/input_key`` (raw), ``output_path/output_key``
 (multi-channel float32, shape ``(C,) + volume``), ``checkpoint_path``
 (flax msgpack or flat npz of params; None -> randomly initialized weights,
-for pipeline smoke tests), ``model`` config dict (``name`` + kwargs for
-:func:`..models.get_model`), ``halo``, ``normalize_percentile`` or fixed
-``normalize_range``, ``activation`` ('sigmoid'/'softmax'/None).
+recorded as ``<tmp_folder>/<task uid>.weights.npz``), ``model`` config dict
+(``name`` + kwargs for :func:`..models.get_model`), ``halo``,
+``normalize_percentile`` or fixed ``normalize_range``, ``activation``
+('sigmoid'/'softmax'/None).
+
+The outer block (block + 2 halo) is rounded up, per axis, to what the
+model's input extents must be multiples of (``input_multiple()``, else
+``2**depth``).  Spans (docs/OBSERVABILITY.md "Inference"):
+``inference.weights`` (made or read, ``params``, ``nbytes``) and
+``inference.pass`` around the sweep (``lanes``, ``dispatches``,
+``outer_shape``, ``outer_voxels``, ``padded_voxels``, ``inner_voxels``,
+``flops_per_lane``, ``flops``); the same counts go into the manifest and ``io_metrics.json``
+(there the FLOPs as the total only).
 """
 
 from __future__ import annotations
@@ -25,6 +35,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..runtime import trace as trace_mod
 from ..runtime.executor import (
     BlockwiseExecutor,
     is_sub_block,
@@ -44,9 +55,6 @@ def load_checkpoint(path: str, model, sample_shape):
         from ..models.torch_import import load_torch_checkpoint
 
         return load_torch_checkpoint(path, model, sample_shape)
-    template = model.init(
-        jax.random.PRNGKey(0), jnp.zeros(sample_shape, jnp.float32)
-    )
     if path.endswith(".npz"):
         import flax.traverse_util as tu
 
@@ -56,7 +64,18 @@ def load_checkpoint(path: str, model, sample_shape):
             flat = {("params",) + k: v for k, v in flat.items()}
         return tu.unflatten_dict(flat)
     with open(path, "rb") as f:
-        return flax.serialization.from_bytes(template, f.read())
+        return flax.serialization.from_bytes(
+            init_params(model, sample_shape), f.read()
+        )
+
+
+def init_params(model, sample_shape):
+    """Random weights from ``PRNGKey(0)``, made under ``jit`` from an input
+    of ``sample_shape`` (the params' shapes do not depend on its extent, so
+    callers pass the smallest the model accepts)."""
+    return jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros(sample_shape, jnp.float32)
+    )
 
 
 def save_checkpoint(path: str, params) -> None:
@@ -71,10 +90,23 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def input_multiple(model, ndim: int) -> tuple:
+    """What the model's input extents must be multiples of, per axis."""
+    if hasattr(model, "input_multiple"):
+        return tuple(int(m) for m in model.input_multiple())
+    return (2 ** int(getattr(model, "depth", 0)),) * ndim
+
+
 class InferenceBase(BaseTask):
     """Blockwise model prediction (reference: ``InferenceBase``)."""
 
     task_name = "inference"
+    #: the sweep's counts, in io_metrics.json too (they add up over a
+    #: resumed run's passes, so the FLOPs go there as a total)
+    io_metrics_keys = (
+        "n_blocks", "lanes", "dispatches", "outer_voxels", "padded_voxels",
+        "inner_voxels", "flops",
+    )
 
     @staticmethod
     def default_task_config():
@@ -119,23 +151,32 @@ class InferenceBase(BaseTask):
         else:
             model = get_model(model_name, **model_cfg)
         out_channels = getattr(model, "out_channels", 1)
-        depth = getattr(model, "depth", 0)
-        mult = 2 ** int(depth)
+        mult = input_multiple(model, len(shape))
 
-        # static kernel shape: outer block rounded up to the U-Net multiple
+        # static kernel shape: outer block rounded up to the model's multiple
         outer = tuple(
-            _round_up(b + 2 * h, mult) for b, h in zip(block_shape, halo)
+            _round_up(b + 2 * h, m) for b, h, m in zip(block_shape, halo, mult)
         )
-        sample = (1,) + outer + (1,)
-        if variables is not None:
-            pass  # imported together with the model above
-        elif ckpt:
-            variables = load_checkpoint(ckpt, model, sample)
-        else:
-            self.logger.info("no checkpoint_path: using random init (smoke mode)")
-            variables = model.init(
-                jax.random.PRNGKey(0), jnp.zeros(sample, jnp.float32)
-            )
+        smallest = (1,) + mult + (1,)
+        weights_path = None
+        with trace_mod.span("inference.weights", task=self.uid) as weights_span:
+            if variables is not None:
+                source = "torch"  # imported together with the model above
+            elif ckpt:
+                source = "checkpoint"
+                variables = load_checkpoint(ckpt, model, smallest)
+            else:
+                source = "init"
+                self.logger.info("no checkpoint_path: random weights, recorded")
+                variables = init_params(model, smallest)
+                # recorded as upstream leaves its problem, for inspection
+                weights_path = os.path.join(self.tmp_folder, f"{self.uid}.weights.npz")
+                save_checkpoint(weights_path, variables)
+            leaves = jax.tree_util.tree_leaves(variables)
+            n_params = sum(int(np.prod(a.shape)) for a in leaves)
+            nbytes = sum(int(a.nbytes) for a in leaves)
+            weights_span.note(source=source, params=n_params, nbytes=nbytes,
+                              path=weights_path)
 
         # MemoryTarget output (docs/PERFORMANCE.md "Task-graph fusion"):
         # the probability map stays in RAM for a downstream watershed /
@@ -183,12 +224,12 @@ class InferenceBase(BaseTask):
                 # the smaller allocation is the point of the split (it
                 # never enters a stacked batch, so the static shape does
                 # not apply)
-                target = tuple(_round_up(s, mult) for s in data.shape)
+                target = tuple(_round_up(s, m) for s, m in zip(data.shape, mult))
             else:
                 target = outer
             return (pad_block_to(data, target)[..., None],)
 
-        def kernel(x):
+        def predict_block(x):
             logits = model.apply(variables, x[None])[0]
             if activation == "sigmoid":
                 y = jax.nn.sigmoid(logits)
@@ -211,39 +252,60 @@ class InferenceBase(BaseTask):
         )
         # float probability outputs: the executor's built-in NaN/inf check
         # quarantines any block a bad kernel or checkpoint corrupts
-        executor.map_blocks(
-            kernel,
-            blocks_all,
-            load,
-            store,
-            on_block_done=lambda b: self.log_block_success(b.block_id),
-            done_block_ids=done,
-            failures_path=self.failures_path,
-            task_name=self.uid,
-            block_deadline_s=cfg.get("block_deadline_s"),
-            watchdog_period_s=cfg.get("watchdog_period_s"),
-            store_verify_fn=region_verifier(
-                out, bb_of=lambda b: (slice(None),) + b.bb
-            ),
-            schedule=str(cfg.get("block_schedule") or "morton"),
-            sweep_mode=str(cfg.get("sweep_mode") or "auto"),
-            sharded_batch=cfg.get("sharded_batch"),
-            device_pool=str(cfg.get("device_pool") or "auto"),
-            device_pool_bytes=cfg.get("device_pool_bytes"),
-            # opt-in OOM split (config allow_block_split): the conv kernel
-            # is shape-local, so sub-block outputs tile the parent's region
-            # exactly when halo covers the receptive field and the
-            # normalization range is fixed (enforced above)
-            splittable=allow_split,
-            split_halo=halo,
-            min_block_shape=cfg.get("min_block_shape"),
-            degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
-            inflight_byte_budget=cfg.get("inflight_byte_budget"),
-        )
+        with trace_mod.span(
+            "inference.pass", task=self.uid, n_blocks=len(todo),
+            outer_shape=list(outer),
+        ) as pass_span:
+            summary = executor.map_blocks(
+                predict_block,
+                blocks_all,
+                load,
+                store,
+                on_block_done=lambda b: self.log_block_success(b.block_id),
+                done_block_ids=done,
+                failures_path=self.failures_path,
+                task_name=self.uid,
+                block_deadline_s=cfg.get("block_deadline_s"),
+                watchdog_period_s=cfg.get("watchdog_period_s"),
+                store_verify_fn=region_verifier(
+                    out, bb_of=lambda b: (slice(None),) + b.bb
+                ),
+                schedule=str(cfg.get("block_schedule") or "morton"),
+                sweep_mode=str(cfg.get("sweep_mode") or "auto"),
+                sharded_batch=cfg.get("sharded_batch"),
+                device_pool=str(cfg.get("device_pool") or "auto"),
+                device_pool_bytes=cfg.get("device_pool_bytes"),
+                # opt-in OOM split (config allow_block_split): the conv kernel
+                # is shape-local, so sub-block outputs tile the parent's region
+                # exactly when halo covers the receptive field and the
+                # normalization range is fixed (enforced above)
+                splittable=allow_split,
+                split_halo=halo,
+                min_block_shape=cfg.get("min_block_shape"),
+                degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
+                inflight_byte_budget=cfg.get("inflight_byte_budget"),
+            )
+            # every lane computes the whole padded outer block, padding
+            # lanes included (padded_voxels, as the watershed's ws.pass)
+            lanes = len(todo) + int(summary.get("n_lanes_padded", 0))
+            counts = {
+                "lanes": lanes,
+                "dispatches": int(summary.get("n_dispatches", 0)),
+                "outer_voxels": len(todo) * int(np.prod(outer)),
+                "padded_voxels": lanes * int(np.prod(outer)),
+                "inner_voxels": sum(int(np.prod(b.shape)) for b in todo),
+            }
+            if hasattr(model, "forward_flops"):
+                counts["flops_per_lane"] = int(model.forward_flops(outer))
+                counts["flops"] = counts["lanes"] * counts["flops_per_lane"]
+            pass_span.note(**counts)
         return {
             "n_blocks": len(todo),
             "out_channels": int(out_channels),
             "model": model_name,
+            "outer_shape": list(outer),
+            "weights": {"source": source, "params": n_params, "path": weights_path},
+            **counts,
         }
 
 

@@ -56,8 +56,17 @@ def jobs(tmp_path_factory):
     """Two jobs of the workflow in one process, on two seeds' volumes, the
     exact fill on and the tracer on: ``{seed: dict(vol, ws, seg, tmp, rec,
     manifest)}``, the second job's compile counters, and the ring's spans."""
-    from cluster_tools_tpu.parallel import mesh
+    from jax.experimental.compilation_cache import compilation_cache
 
+    from cluster_tools_tpu.parallel import mesh, step_cache
+
+    # the first job builds its programs (or reads them back), whatever an
+    # earlier test file of this process left in memory; and JAX looks at its
+    # persistent cache anew (it decides once per process whether the cache is
+    # on: a first compile under programs_built_here leaves it off)
+    step_cache.forget()
+    jax.clear_caches()
+    compilation_cache.reset_cache()
     root = str(tmp_path_factory.mktemp("mc_resident"))
     one_device = mesh.backend_devices
     out = {}

@@ -491,8 +491,9 @@ def test_chase_metric_is_declared_for_every_cell():
         assert mine[key] == meta[key]
     # every watershed runs the chase: the fused step's three cells and the
     # two-pass and per-section cells' blockwise programs, there under a vmap
-    # over lanes
-    assert mine["workloads"] == [w["name"] for w in bench["workloads"]] == [
+    # over lanes; the inference cell runs no watershed
+    watersheds = [w["name"] for w in bench["workloads"] if w["config"] != "unet3d_cremi_125"]
+    assert mine["workloads"] == watersheds == [
         "fused384.volumes", "fused4x384.volumes.sp4", "multicut384.volumes",
         "twopass125.volumes", "ws2d125.volumes"]
     assert (mine["unit"], mine["better"], mine["source"]) == ("s", "lower", "device_trace")
