@@ -109,6 +109,7 @@ def batched_shard_map(
     batch: int,
     axis_name: str = "blocks",
     check_vma: bool = False,
+    replicated: bool = False,
 ):
     """One compiled dispatch for a stacked batch of blocks, sharded over
     ``mesh``.
@@ -127,6 +128,14 @@ def batched_shard_map(
     kernels carrying ``while_loop``/pallas bodies trip the static
     replication checker; only the advisory check is off, the collectives (none here unless the kernel
     adds them) are unaffected.
+
+    ``replicated=True``: the returned callable takes, first, an operand that
+    is the same for every lane (a pytree of arrays, e.g. a network's
+    weights), replicated to every device (``P()``) and passed to each
+    lane's ``kernel`` call as its first argument, unbatched (``in_axes``
+    None); the stacked batch follows.  The program then reads the operand
+    as an argument, not as constants, so one compiled program serves every
+    value of it.
     """
     n = mesh_n_devices(mesh)
     batch = int(batch)
@@ -135,25 +144,25 @@ def batched_shard_map(
             f"sharded batch {batch} is not divisible by the {n}-device mesh"
         )
 
+    spec = P(axis_name)
+    lead = int(bool(replicated))
+
     def _sharded_batch_body(*args):
-        return jax.vmap(kernel)(*args)
+        in_axes = (None,) * lead + (0,) * (len(args) - lead)
+        return jax.vmap(kernel, in_axes=in_axes)(*args)
+
+    def _program(*args):
+        in_specs = (P(),) * lead + (spec,) * (len(args) - lead)
+        return shard_map(_sharded_batch_body, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec, check_vma=check_vma)(*args)
 
     # the compiled program carries its kernel's name (``jit_sharded_<name>``
     # on a trace's "XLA Modules" line), so that two sweeps of one job can be
     # told apart there
-    _sharded_batch_body.__name__ = "sharded_" + getattr(
+    _program.__name__ = _sharded_batch_body.__name__ = "sharded_" + getattr(
         kernel, "__name__", "kernel"
     )
-    spec = P(axis_name)
-    return jax.jit(
-        shard_map(
-            _sharded_batch_body,
-            mesh=mesh,
-            in_specs=spec,
-            out_specs=spec,
-            check_vma=check_vma,
-        )
-    )
+    return jax.jit(_program)
 
 
 def ragged_shard_map(
@@ -163,6 +172,7 @@ def ragged_shard_map(
     specs: Sequence,
     axis_name: str = "blocks",
     check_vma: bool = False,
+    replicated: bool = False,
 ):
     """One compiled dispatch for a *ragged* (mixed-shape) batch of blocks,
     driven by the paged block pool's descriptors (:mod:`.block_pool`,
@@ -187,7 +197,9 @@ def ragged_shard_map(
     of the batch width, which is what keeps the ragged path bit-identical
     to per-block execution on the lanes' stored regions
     (tests/test_ragged.py).  ``check_vma=False`` for the same reason as
-    :func:`batched_shard_map`.
+    :func:`batched_shard_map`.  ``replicated=True``: a replicated operand
+    leads the arguments and reaches every lane's ``kernel`` call first, as
+    in :func:`batched_shard_map`.
     """
     n = mesh_n_devices(mesh)
     batch = int(batch)
@@ -217,6 +229,8 @@ def ragged_shard_map(
         return jnp.where(mask, x, fill)
 
     def _sharded_body(*flat):
+        lead = flat[:1] if replicated else ()
+        flat = flat[len(lead):]
         pools = flat[: len(specs)]
         lanes = flat[len(specs):]  # (table, valid) per arg
 
@@ -225,13 +239,15 @@ def ragged_shard_map(
             for i, spec in enumerate(specs):
                 table, valid = lane_flat[2 * i], lane_flat[2 * i + 1]
                 args.append(_reassemble(pools[i], table, valid, spec))
-            return kernel(*args)
+            return kernel(*lead, *args)
 
-        # pools are closed over (vmap broadcasts them across lanes)
+        # pools (and the operand) are closed over (vmap broadcasts them
+        # across lanes)
         return jax.vmap(_lane)(*lanes)
 
     spec_in = (
-        tuple(P() for _ in specs)
+        (P(),) * bool(replicated)
+        + tuple(P() for _ in specs)
         + tuple(P(axis_name) for _ in specs for _ in range(2))
     )
     return jax.jit(

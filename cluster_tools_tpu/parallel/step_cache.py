@@ -26,22 +26,28 @@ A sweep program comes here only where its kernel's identity freezes
 plain), and its key holds a digest of that identity: two kernels that read
 the same values share a program, a kernel that reads another threshold or
 capacity does not, and a kernel that captures an array or a dataset stays
-in its executor's own cache.  What the process keeps is the compiled
-program, which holds nothing a task owns.
+in its executor's own cache.  Arrays a sweep hands the executor as its
+replicated operand (the inference task's weights) are the program's
+arguments: they enter the key by shape, dtype and sharding, so a kernel of
+the same architecture with other weights shares the program.  What the
+process keeps is the compiled program, which holds nothing a task owns.
 
 The store exists where the process has a persistent compile cache directory
-and nowhere else; nothing switches it.  It keeps :data:`STORE_STEPS` = 4
-entries: one tree's one-chip cells write three (the fused step, shared by
-``fused384.volumes`` and ``multicut384.volumes``, and the two sweep
-programs of ``twopass125.volumes``).  Every way out of it is the build: a
+and nowhere else; nothing switches it.  It keeps its most recently used
+entries up to :data:`STORE_BYTES` in all, and always the newest.  Every way
+out of it is the build: a
 missing, truncated, foreign or mismatching entry, a ``serialize`` or a load
 that raises all end in a built program and an overwritten entry.  A wrong
 hit would be silently wrong labels, so the key document holds everything
 that reaches the lowering (:func:`key_document`) and is compared field by
-field on a hit, not only by its hash.  The key sees *files* and captured
-values: code patched in memory (a test's ``monkeypatch`` of ``ops/*``) is
-invisible to it, so such a test runs its jobs with no store and calls
-:func:`forget` around them (``tests/helpers.py::programs_built_here``).
+field on a hit, not only by its hash.  The key sees *files*, captured
+values, and the package's top-level functions that are not in memory what
+their files define (:func:`replaced_functions`: a control or a test that
+swaps one, as ``benchmark/control_float8.py`` swaps the U-Net's
+``_operand``, gets a program of its own).  Other code patched in memory (a
+class's method, a module's constant) is invisible to it, so a test that
+patches such runs its jobs with no store and calls :func:`forget` around
+them (``tests/helpers.py::programs_built_here``).
 Clearing the store by hand is deleting ``<compile cache dir>/steps/``.
 """
 
@@ -51,9 +57,12 @@ import contextlib
 import functools
 import glob
 import hashlib
+import inspect
 import json
 import os
 import pickle
+import re
+import sys
 import threading
 import time
 import zlib
@@ -69,9 +78,10 @@ PACKAGE_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: ready programs a process keeps: a loaded program's temporaries stay
 #: reserved on the chip (4.8 GB for the 384^3 step)
 PROCESS_STEPS = 2
-#: entries the store keeps (80-100 MiB each at 384^3); JAX's own size
-#: bound does not see these files
-STORE_STEPS = 4
+#: bytes the store keeps, most recently used first (an entry is 5-100 MiB:
+#: the fused step at 384^3 is the largest); JAX's own size bound does not
+#: see these files
+STORE_BYTES = 1 << 30
 #: a writer's temp file older than this was left by a killed process
 _STALE_TEMP_S = 600.0
 
@@ -91,6 +101,54 @@ def package_digest(root: str = PACKAGE_ROOT) -> str:
     return h.hexdigest()
 
 
+#: a top-level ``def`` of a source file
+_TOP_DEF = re.compile(r"^(?:async[ \t]+)?def[ \t]+(\w+)", re.M)
+
+
+@functools.lru_cache(maxsize=None)
+def _defined_functions(path: str) -> Tuple[str, ...]:
+    with open(path, encoding="utf-8") as f:
+        return tuple(_TOP_DEF.findall(f.read()))
+
+
+def replaced_functions(package_root: str = PACKAGE_ROOT) -> list:
+    """``[module, name, replacement]`` for each top-level function of a
+    loaded module under ``package_root`` that is not, in memory, the one
+    its file defines (a ``def`` at column 0 whose name now holds something
+    else, seen through ``__wrapped__``), sorted.  ``replacement`` is the
+    new value's module and qualified name and the digest of its
+    :func:`~cluster_tools_tpu.runtime.executor.kernel_identity` (its
+    ``id`` where that does not freeze), so two different replacements
+    differ.  Empty unless something was swapped; a few milliseconds."""
+    from ..runtime.executor import identity_digest, kernel_identity
+
+    prefix = os.path.join(package_root, "")
+    replaced = []
+    for module_name, module in list(sys.modules.items()):
+        path = getattr(module, "__file__", None)
+        if not path or not path.endswith(".py") or \
+                not os.path.abspath(path).startswith(prefix):
+            continue
+        namespace = vars(module)
+        for name in _defined_functions(path):
+            value = namespace.get(name)
+            try:
+                fn = inspect.unwrap(value)
+            except ValueError:   # a loop of ``__wrapped__``
+                fn = value
+            if value is None or inspect.isfunction(fn) and \
+                    (fn.__module__, fn.__qualname__) == (module_name, name):
+                continue
+            identity = kernel_identity(value)
+            replaced.append([module_name, name, {
+                "module": getattr(value, "__module__", type(value).__module__),
+                "qualname": getattr(value, "__qualname__", type(value).__qualname__),
+                "identity": (identity_digest(identity) if identity is not None
+                             else f"id:{id(value)}"),
+            }])
+    return sorted(replaced, key=lambda r: r[:2])
+
+
 def _describe(x) -> dict:
     return {"shape": list(x.shape), "dtype": str(np.dtype(x.dtype)),
             "spec": list(x.sharding.spec)}
@@ -100,9 +158,11 @@ def key_document(mesh, x, execution: str, build_args: dict,
                  package_root: str = PACKAGE_ROOT) -> dict:
     """Everything that can change the program compiled for input ``x`` (an
     array or a ``jax.ShapeDtypeStruct`` with its ``NamedSharding``; a tuple
-    of them for a program of several inputs), as plain JSON: the package's
-    sources, JAX and the backend's build, the mesh (axis names, shape,
-    device ids in order), the inputs (shape, dtype, partition spec),
+    of them for a program of several inputs, where an input may be a pytree
+    of them, as the executor's replicated operand is), as plain JSON: the
+    package's sources, JAX and the backend's build, the mesh (axis names,
+    shape, device ids in order), the inputs (shape, dtype, partition spec
+    of each leaf, and the tree where an input is not a single array),
     ``execution`` and every argument of the program's builder, what the
     kernel switches resolved to (``ops/tile_ws.py::resolved_modes`` of the
     builder's ``impl``, which carries ``CT_FILL_MODE``; a builder without
@@ -115,8 +175,13 @@ def key_document(mesh, x, execution: str, build_args: dict,
 
     devices = list(mesh.devices.flat)
     client = devices[0].client
-    inputs = ({"inputs": [_describe(a) for a in x]} if isinstance(x, tuple)
-              else {"input": _describe(x)})
+    if isinstance(x, tuple):
+        leaves, tree = jax.tree_util.tree_flatten(x)
+        inputs = {"inputs": [_describe(a) for a in leaves]}
+        if len(leaves) != len(x):
+            inputs["in_tree"] = str(tree)
+    else:
+        inputs = {"input": _describe(x)}
     doc = {
         "sources": package_digest(package_root),
         "jax": jax.__version__,
@@ -139,6 +204,9 @@ def key_document(mesh, x, execution: str, build_args: dict,
             "LIBTPU_INIT_ARGS": os.environ.get("LIBTPU_INIT_ARGS", ""),
         },
     }
+    replaced = replaced_functions(package_root)
+    if replaced:
+        doc["replaced"] = replaced
     # as it reads back from an entry: tuples are lists, keys are strings
     return json.loads(json.dumps(doc, sort_keys=True))
 
@@ -215,12 +283,12 @@ def load(directory: str, key: str, document: dict, x, note=lambda **kw: None
     except Exception as e:
         raise Unusable(f"damaged:{type(e).__name__}")
     t2 = time.monotonic()
-    xs = x if isinstance(x, tuple) else (x,)
+    xs = jax.tree_util.tree_leaves(x)
     devices = list(xs[0].sharding.mesh.devices.flat)
     try:
         step = deserialize_and_load(payload, *trees, backend=devices[0].client,
                                     execution_devices=devices)
-        expects, _ = step.input_shardings
+        expects = jax.tree_util.tree_leaves(step.input_shardings[0])
     except Exception as e:   # whatever the backend refuses: build instead
         raise Unusable(f"load:{type(e).__name__}")
     if len(expects) != len(xs) or not all(
@@ -237,7 +305,8 @@ def load(directory: str, key: str, document: dict, x, note=lambda **kw: None
 def save(directory: str, key: str, document: dict, compiled) -> int:
     """Write ``compiled`` as entry ``key`` (temp + ``os.replace``: a reader
     sees the old entry, the new one, or none) and prune the store to its
-    :data:`STORE_STEPS` most recently used entries.  Returns the bytes."""
+    most recently used entries within :data:`STORE_BYTES`.  Returns the
+    bytes."""
     from jax.experimental.serialize_executable import serialize
 
     payload, in_tree, out_tree = serialize(compiled)
@@ -267,14 +336,17 @@ def _prune(directory: str) -> None:
     for name in os.listdir(directory):
         path = os.path.join(directory, name)
         with contextlib.suppress(OSError):   # another process prunes too
-            mtime = os.stat(path).st_mtime
+            stat = os.stat(path)
             if ".tmp." not in name:
-                entries.append((mtime, path))
-            elif now - mtime > _STALE_TEMP_S:
+                entries.append((stat.st_mtime, stat.st_size, path))
+            elif now - stat.st_mtime > _STALE_TEMP_S:
                 os.unlink(path)
-    for _, path in sorted(entries)[:-STORE_STEPS]:
-        with contextlib.suppress(OSError):
-            os.unlink(path)
+    kept = 0
+    for i, (_, size, path) in enumerate(sorted(entries, reverse=True)):
+        kept += size
+        if i and kept > STORE_BYTES:
+            with contextlib.suppress(OSError):
+                os.unlink(path)
 
 
 def _process_level():
@@ -290,8 +362,9 @@ def _process_level():
 
 def forget() -> None:
     """Drop every ready program of the process: for tests that patch the
-    program in memory, which the key cannot see (the store they switch off
-    by running without a persistent compile cache)."""
+    program in memory where the key cannot see it (a class's method, a
+    module's constant; a swapped top-level function it sees), and switch
+    the store off by running without a persistent compile cache."""
     global _process
     with _lock:
         _process = None
@@ -315,7 +388,8 @@ def step_for(mesh, x, execution: str, builder: Callable, build_args: dict
 def program_for(mesh, xs: tuple, kernel_digest: str, batch: int,
                 builder: Callable) -> Tuple[Callable, Dict[str, object]]:
     """The executor's sharded sweep program ``builder()`` (a jitted
-    function of the stacked batch ``xs``) compiled for ``xs``: from the
+    function of the stacked batch ``xs``, led by the sweep's replicated
+    operand where it has one) compiled for ``xs``: from the
     process, else from the store, else built; ``kernel_digest`` is
     :func:`~cluster_tools_tpu.runtime.executor.identity_digest` of the
     kernel it maps.  Returns ``(program, info)`` as :func:`step_for` does;

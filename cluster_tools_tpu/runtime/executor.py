@@ -329,22 +329,29 @@ class _KeptSweepProgram:
     and only then built (``batched_shard_map(...).lower(...).compile()``).
     What the process keeps is the compiled program alone: the kernel's
     captured values are plain (that is what freezing them proved), so
-    nothing a task owns outlives it.  ``info`` is the last look-up's
+    nothing a task owns outlives it.  With ``replicated``, the sweep's
+    replicated operand leads the arguments; its leaves enter the signature
+    and the key by shape, dtype and sharding, never by value, so another
+    value of it runs the same program.  ``info`` is the last look-up's
     ``{from, key, load_s, store_bytes, fallback}``."""
 
-    def __init__(self, kernel: Callable, identity: tuple, mesh: Mesh, batch: int):
+    def __init__(self, kernel: Callable, identity: tuple, mesh: Mesh, batch: int,
+                 replicated: bool = False):
         self._kernel, self._mesh, self._batch = kernel, mesh, int(batch)
+        self._replicated = bool(replicated)
         self._digest = identity_digest(identity)
         self._ready: Dict[tuple, Callable] = {}
         self.info: Optional[Dict[str, Any]] = None
 
     def __call__(self, *args):
-        signature = tuple((a.shape, a.dtype, a.sharding) for a in args)
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        signature = (tree,) + tuple((a.shape, a.dtype, a.sharding) for a in leaves)
         program = self._ready.get(signature)
         if program is None:
             program, self.info = step_cache.program_for(
                 self._mesh, args, self._digest, self._batch,
-                lambda: batched_shard_map(self._kernel, self._mesh, self._batch),
+                lambda: batched_shard_map(self._kernel, self._mesh, self._batch,
+                                          replicated=self._replicated),
             )
             self._ready[signature] = program
         return program(*args)
@@ -795,6 +802,7 @@ class BlockwiseExecutor:
         page_shape: Optional[Sequence[int]] = None,
         device_pool: str = "auto",
         device_pool_bytes: Optional[int] = None,
+        replicated_operand: Any = None,
     ) -> Dict[str, int]:
         """Execute ``kernel`` over ``blocks``; see class docstring.
 
@@ -904,6 +912,17 @@ class BlockwiseExecutor:
         on the timeline (``executor.h2d`` spans — absent on the
         resident-pool happy path).
 
+        ``replicated_operand`` — a pytree of arrays that is the same for
+        every block (a network's weights): placed on the mesh once per
+        sweep, replicated, and passed to ``kernel`` as its FIRST argument
+        on every route (sharded, ragged, per-block, split sub-blocks), so
+        the kernel is called ``kernel(operand, *load_fn(block))``.  The
+        programs take it as an argument instead of capturing it as
+        constants: a kernel that captures only plain values then keeps its
+        sharded program across jobs (:class:`_KeptSweepProgram`) whatever
+        the operand's values.  None (default): ``kernel(*load_fn(block))``
+        and the programs of a sweep without one.
+
         Raises RuntimeError naming every block that stays failed after the
         end-of-run quarantine pass, and
         :class:`~cluster_tools_tpu.runtime.supervision.DrainInterrupt`
@@ -969,11 +988,27 @@ class BlockwiseExecutor:
         # lane gathers from the whole pool); tables/valid shard over blocks
         replicated = NamedSharding(self.mesh, P())
         dev_key = tuple(d.id for d in self.devices)
+        # the replicated operand, on the mesh once per sweep: ``lead`` goes
+        # in front of every program call's block arguments, and the
+        # programs built for it are cached apart (``operand_tag``)
+        has_operand = replicated_operand is not None
+        lead = (
+            (jax.device_put(replicated_operand, replicated),)
+            if has_operand else ()
+        )
+        operand_tag = ("replicated",) if has_operand else ()
 
         def _vmap_program():
-            return jax.jit(
-                jax.vmap(kernel), in_shardings=sharding, out_shardings=sharding
-            )
+            def vmapped(*args):
+                in_axes = (None,) * len(lead) + (0,) * (len(args) - len(lead))
+                return jax.vmap(kernel, in_axes=in_axes)(*args)
+
+            # named as ``jax.vmap(kernel)`` is; with an operand, it arrives
+            # committed to ``replicated`` and the stacked inputs to
+            # ``sharding``, and jit follows them
+            vmapped.__name__ = jax.vmap(kernel).__name__
+            placed = {} if lead else {"in_shardings": sharding}
+            return jax.jit(vmapped, out_shardings=sharding, **placed)
 
         # the cache route (shared identity-keyed under a resident server,
         # else this executor's instance cache) is resolved once per sweep:
@@ -984,18 +1019,22 @@ class BlockwiseExecutor:
         if use_sharded and shared_program_cache() is None:
             identity = kernel_identity(kernel)
             if identity is not None:
-                kept = _KeptSweepProgram(kernel, identity, self.mesh, bs)
+                kept = _KeptSweepProgram(kernel, identity, self.mesh, bs,
+                                         replicated=has_operand)
         if kept is not None:
             batched_kernel = kept
         elif use_sharded:
             batched_kernel = cached_program(
-                ("sharded", bs, dev_key),
-                lambda: batched_shard_map(kernel, self.mesh, bs),
+                ("sharded", bs, dev_key) + operand_tag,
+                lambda: batched_shard_map(kernel, self.mesh, bs,
+                                          replicated=has_operand),
             )
         else:
             # width is carried by the input shapes, not the wrapper: one
             # cached jit(vmap) serves every batch width of this kernel
-            batched_kernel = cached_program(("vmap", dev_key), _vmap_program)
+            batched_kernel = cached_program(
+                ("vmap", dev_key) + operand_tag, _vmap_program
+            )
         # the sweep span doubles as the sweep_s clock (docs/OBSERVABILITY.md):
         # trace spans are the one timing source in runtime/ (CT008), and a
         # begin/end pair still measures with the tracer off so the
@@ -1122,7 +1161,9 @@ class BlockwiseExecutor:
                 return batched_kernel, bs
             kern = fallback_state.get("kernel")
             if kern is None:
-                kern = cached_program(("vmap", dev_key), _vmap_program)
+                kern = cached_program(
+                    ("vmap", dev_key) + operand_tag, _vmap_program
+                )
                 fallback_state["kernel"] = kern
             return kern, bs0
 
@@ -1139,7 +1180,7 @@ class BlockwiseExecutor:
             with dispatch_lock:
                 with trace_mod.span("executor.dispatch", n_blocks=1,
                                     task=task_name, grain="per_block"):
-                    out = kern(*stacked)
+                    out = kern(*lead, *stacked)
             _note_dispatch(1)
             out_np = jax.tree_util.tree_map(lambda a: np.asarray(a)[0], out)
             device_pool_mod.record_d2h(sum(
@@ -1195,13 +1236,14 @@ class BlockwiseExecutor:
                     device_pool_mod.record_h2d(
                         sum(int(a.nbytes) for a in shd)
                     )
-                    dev_inputs = tuple(rep) + tuple(
+                    dev_inputs = lead + tuple(rep) + tuple(
                         jax.device_put(a, sharding) for a in shd
                     )
                     prog = cached_program(
-                        ("ragged", dev_key) + sb.key(),
+                        ("ragged", dev_key) + sb.key() + operand_tag,
                         lambda sb=sb: ragged_shard_map(
-                            kernel, self.mesh, sb.width, sb.specs
+                            kernel, self.mesh, sb.width, sb.specs,
+                            replicated=has_operand,
                         ),
                     )
                     return dev_inputs, prog
@@ -1213,16 +1255,17 @@ class BlockwiseExecutor:
                 "executor.h2d", task=task_name, nbytes=int(rb.nbytes),
                 grain="ragged",
             ):
-                dev_inputs = tuple(
+                dev_inputs = lead + tuple(
                     jax.device_put(a, replicated) for a in rep
                 ) + tuple(
                     jax.device_put(a, sharding) for a in shd
                 )
             device_pool_mod.record_h2d(rb.nbytes)
             prog = cached_program(
-                ("ragged", dev_key) + rb.key(),
+                ("ragged", dev_key) + rb.key() + operand_tag,
                 lambda rb=rb: ragged_shard_map(
-                    kernel, self.mesh, rb.width, rb.specs
+                    kernel, self.mesh, rb.width, rb.specs,
+                    replicated=has_operand,
                 ),
             )
             return dev_inputs, prog
@@ -1863,7 +1906,7 @@ class BlockwiseExecutor:
                             "executor.h2d", task=task_name,
                             nbytes=int(batch_bytes), grain="dense",
                         ):
-                            dev_inputs = tuple(
+                            dev_inputs = lead + tuple(
                                 jax.device_put(a, sharding) for a in arrays
                             )
                         device_pool_mod.record_h2d(batch_bytes)
@@ -2036,7 +2079,7 @@ class BlockwiseExecutor:
                         sum(int(np.asarray(x).nbytes) for x in val)
                     )
                     with dispatch_lock:
-                        out = sub_jit(*val)
+                        out = sub_jit(*lead, *val)
                     _note_dispatch(1)
                     out_np = jax.tree_util.tree_map(np.asarray, out)
                     device_pool_mod.record_d2h(sum(

@@ -24,10 +24,21 @@ model's input extents must be multiples of (``input_multiple()``, else
 ``outer_shape``, ``outer_voxels``, ``padded_voxels``, ``inner_voxels``,
 ``flops_per_lane``, ``flops``); the same counts go into the manifest and ``io_metrics.json``
 (there the FLOPs as the total only).
+
+The weights are the sweep's replicated operand
+(``BlockwiseExecutor.map_blocks(..., replicated_operand=...)``): the kernel
+receives them as its first argument and captures only plain values (the
+model's name and config, the activation), so its sharded program is kept
+across jobs by ``parallel/step_cache.py`` and serves every checkpoint of
+the same architecture; the manifest's ``step_cache`` says where the job's
+program came from (process, store or built).  A ``model: "auto"`` kernel
+captures its imported module, which does not freeze, and keeps its
+executor's own cache.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Any, Dict
 
@@ -229,8 +240,19 @@ class InferenceBase(BaseTask):
                 target = outer
             return (pad_block_to(data, target)[..., None],)
 
-        def predict_block(x):
-            logits = model.apply(variables, x[None])[0]
+        if model_name == "auto":
+            # the module inferred from the checkpoint is captured: it does
+            # not freeze, so the program stays in the executor's own cache
+            def make_model():
+                return model
+        else:
+            # plain values only: the kernel's identity freezes, so its
+            # sharded program is kept across jobs; building the module
+            # under trace costs nothing on the device
+            make_model = functools.partial(get_model, model_name, **model_cfg)
+
+        def predict_block(params, x):
+            logits = make_model().apply(params, x[None])[0]
             if activation == "sigmoid":
                 y = jax.nn.sigmoid(logits)
             elif activation == "softmax":
@@ -284,6 +306,7 @@ class InferenceBase(BaseTask):
                 min_block_shape=cfg.get("min_block_shape"),
                 degrade_wait_s=float(cfg.get("degrade_wait_s", 5.0)),
                 inflight_byte_budget=cfg.get("inflight_byte_budget"),
+                replicated_operand=variables,
             )
             # every lane computes the whole padded outer block, padding
             # lanes included (padded_voxels, as the watershed's ws.pass)
@@ -305,6 +328,10 @@ class InferenceBase(BaseTask):
             "model": model_name,
             "outer_shape": list(outer),
             "weights": {"source": source, "params": n_params, "path": weights_path},
+            # where the sweep's program came from (process, store or built;
+            # parallel/step_cache.py), None where the executor kept it in
+            # its own cache
+            "step_cache": summary.get("program"),
             **counts,
         }
 

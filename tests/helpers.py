@@ -10,10 +10,12 @@ def programs_built_here():
     """Jobs inside build their programs from the code as it is in memory
     now: the process holds no ready program (the fused step, the executor's
     sweep programs) and there is no step store.  ``parallel/step_cache.py``
-    keys a compiled program on source *files* and on the values its kernel
-    captures, not on the module code the kernel calls, so a test that
-    patches the program (``ops/*``) in memory, or wants to see the build,
-    says so; the patched program does not outlive the block either."""
+    keys a compiled program on source *files*, on the values its kernel
+    captures and on the package's top-level functions swapped in memory,
+    not on other module code the kernel calls (a method, a constant), so a
+    test that patches the program (``ops/*``) in memory, or wants to see
+    the build, says so; the patched program does not outlive the block
+    either."""
     import jax
 
     from cluster_tools_tpu.parallel import step_cache

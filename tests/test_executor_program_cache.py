@@ -291,3 +291,181 @@ def test_identity_digest_orders_a_frozen_set():
     b = ("fn", frozenset({"z", ("y", 2), ("x", 1)}))
     assert identity_digest(a) == identity_digest(b)
     assert identity_digest(a) != identity_digest(("fn", frozenset({"z"})))
+
+
+# -- a replicated operand (a network's weights), on every route ---------------
+
+
+def with_operand(op, b):
+    return b * op["scale"] + op["shift"][1]
+
+
+def _operand(scale=3.0):
+    return {"scale": jnp.float32(scale), "shift": jnp.arange(4, dtype=jnp.float32)}
+
+
+#: route -> (map_blocks arguments, blocks swept, faults injected)
+OPERAND_ROUTES = {
+    "sharded": (dict(sweep_mode="sharded"), 8, None),
+    "vmap": (dict(sweep_mode="per_block"), 8, None),
+    "ragged": (dict(sweep_mode="sharded"), 5, None),
+    "per_block_fallback": (dict(sweep_mode="sharded"), 8, {
+        "site": "dispatch", "kind": "oom", "blocks": "first", "fail_attempts": 1}),
+    "split_sub_blocks": (dict(sweep_mode="per_block"), 8, {
+        "site": "load", "kind": "oom", "min_voxels": 1000, "fail_attempts": 10**6}),
+    "split_ragged": (dict(sweep_mode="sharded"), 8, {
+        "site": "load", "kind": "oom", "min_voxels": 1000, "fail_attempts": 10**6}),
+}
+
+
+def _operand_sweep(kernel, operand, route, inject=None):
+    """``kernel(operand, block)`` over the 8-block grid (or its first
+    blocks) along ``route``: the summary, the executor, what was stored and
+    what was swept."""
+    kw, n_blocks, fault = OPERAND_ROUTES[route]
+    blocks = executor_mod.morton_order(_blocks())[:n_blocks]
+    if fault is not None:
+        fault = dict(fault)
+        if fault.get("blocks") == "first":
+            fault["blocks"] = [int(blocks[0].block_id)]
+        inject({"seed": 3, "faults": [fault]})
+    vol = np.random.default_rng(3).random((32, 32, 32), np.float32)
+    out = np.full_like(vol, np.nan)
+
+    def store(b, raw):
+        out[b.bb] = np.asarray(raw)[b.inner_in_outer_bb]
+
+    ex = BlockwiseExecutor(target="local", backoff_base=1e-4)
+    summary = ex.map_blocks(
+        kernel, blocks, lambda b: (vol[b.outer_bb],), store, sharded_batch=8,
+        task_name="operand", splittable=True, split_halo=(0, 0, 0),
+        min_block_shape=(4, 4, 4), degrade_wait_s=0.01,
+        replicated_operand=operand, **kw)
+    swept = np.zeros(vol.shape, bool)
+    for b in blocks:
+        swept[b.bb] = True
+    return summary, ex, out, vol, swept
+
+
+@pytest.mark.parametrize("route", list(OPERAND_ROUTES))
+def test_a_replicated_operand_reaches_the_kernel_on_every_route(cache_dir, inject, route):
+    summary, _, out, vol, swept = _operand_sweep(with_operand, _operand(), route, inject)
+    assert summary["n_failed"] == 0
+    np.testing.assert_array_equal(out[swept], vol[swept] * np.float32(3.0) + np.float32(1.0))
+    assert np.isnan(out[~swept]).all()
+    shown = {
+        "sharded": summary.get("program") is not None,
+        "vmap": summary["sweep_mode"] == "per_block",
+        "ragged": summary.get("n_ragged_batches") == 1,
+        "per_block_fallback": summary.get("n_unsharded") == 8,
+        "split_sub_blocks": summary.get("n_split") == 8,
+        "split_ragged": summary.get("n_split") == 8 and summary.get("n_ragged_batches"),
+    }[route]
+    assert shown, summary
+
+
+def test_another_operand_value_runs_the_same_kept_program(cache_dir):
+    """The operand is the program's argument: a sweep with other values
+    finds the program the first one left in the process, and computes with
+    its own values."""
+    step_cache.forget()
+    first, *_ = _operand_sweep(with_operand, _operand(3.0), "sharded")
+    second, _, out, vol, _ = _operand_sweep(with_operand, _operand(-2.0), "sharded")
+    assert first["program"]["from"] in ("built", "store")
+    assert second["program"]["from"] == "process"
+    assert second["program"]["key"] == first["program"]["key"]
+    np.testing.assert_array_equal(out, vol * np.float32(-2.0) + np.float32(1.0))
+
+
+def test_an_operand_kernel_that_captures_an_array_stays_in_its_executor(cache_dir):
+    table = jnp.arange(4, dtype=jnp.float32)
+
+    def kernel(op, b):
+        return b * op["scale"] + table[1]
+
+    assert kernel_identity(kernel) is None
+    before = step_cache.totals()
+    summary, ex, out, vol, _ = _operand_sweep(kernel, _operand(), "sharded")
+    assert "program" not in summary and not step_cache.delta(before)
+    in_executor = {key for _, key in ex._program_cache._entries}
+    dev_key = tuple(d.id for d in ex.devices)
+    assert ("sharded", 8, dev_key, "replicated") in in_executor
+    np.testing.assert_array_equal(out, vol * np.float32(3.0) + np.float32(1.0))
+
+
+def test_the_key_holds_the_operand_by_shape_never_by_value(cache_dir):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices("cpu")[:1]), ("blocks",))
+    stacked = jax.device_put(np.zeros((8, 4, 4, 4), np.float32),
+                             NamedSharding(mesh, P("blocks")))
+
+    def document(operand):
+        placed = jax.device_put(operand, NamedSharding(mesh, P()))
+        return step_cache.key_document(mesh, (placed, stacked), "sharded",
+                                       {"kernel": "k", "batch": 8})
+
+    assert document(_operand(3.0)) == document(_operand(-2.0))
+    assert document(_operand()) != document({"scale": jnp.float32(3.0)})
+    assert len(document(_operand())["inputs"]) == 3
+    assert "in_tree" in document(_operand())
+    assert "in_tree" not in step_cache.key_document(
+        mesh, (stacked,), "sharded", {"kernel": "k", "batch": 8})
+
+
+def test_a_sweep_without_an_operand_lowers_the_program_it_lowered_before(cache_dir):
+    """A kernel given no operand gets the sharded program it got before the
+    operand existed: ``jit(shard_map(vmap(kernel)))``, named for the
+    kernel."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from cluster_tools_tpu.compat import shard_map
+    from cluster_tools_tpu.parallel.batch_shard import batched_shard_map
+
+    mesh = Mesh(np.array(jax.devices("cpu")[:1]), ("blocks",))
+    x = jax.ShapeDtypeStruct((8, 16, 16, 16), jnp.float32,
+                             sharding=NamedSharding(mesh, P("blocks")))
+
+    def sharded_plain(*args):
+        return jax.vmap(plain)(*args)
+
+    before = jax.jit(shard_map(sharded_plain, mesh=mesh, in_specs=P("blocks"),
+                               out_specs=P("blocks"), check_vma=False))
+    assert batched_shard_map(plain, mesh, 8).lower(x).as_text() == \
+        before.lower(x).as_text()
+
+
+def test_a_package_function_swapped_in_memory_enters_the_key(cache_dir, monkeypatch):
+    """A control or a test that swaps one of the package's top-level
+    functions (the U-Net's ``_operand`` at float8) gets a key of its own,
+    one per replacement, and the sound key back once it is undone."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from cluster_tools_tpu.models import unet_gcr
+
+    mesh = Mesh(np.array(jax.devices("cpu")[:1]), ("blocks",))
+    stacked = jax.device_put(np.zeros((8, 4, 4, 4), np.float32),
+                             NamedSharding(mesh, P("blocks")))
+
+    def document():
+        return step_cache.key_document(mesh, (stacked,), "sharded",
+                                       {"kernel": "k", "batch": 8})
+
+    def swapped(doc):
+        return [r for r in doc.get("replaced", []) if r not in sound.get("replaced", [])]
+
+    sound = document()
+    monkeypatch.setattr(unet_gcr, "_operand",
+                        lambda a: a.astype(jnp.float8_e4m3fn).astype(jnp.bfloat16))
+    float8 = document()
+    monkeypatch.setattr(unet_gcr, "_operand",
+                        lambda a: a.astype(jnp.float16).astype(jnp.bfloat16))
+    float16 = document()
+    monkeypatch.undo()
+    assert [r[:2] for r in swapped(float8)] == [["cluster_tools_tpu.models.unet_gcr",
+                                                 "_operand"]]
+    assert swapped(float8)[0][2]["qualname"].endswith("<lambda>")
+    assert step_cache.digest(float8) != step_cache.digest(float16) != step_cache.digest(sound)
+    assert document() == sound
+    # a decorated function is seen through ``__wrapped__``: not a swap
+    assert not any(r[1] == "package_digest" for r in sound.get("replaced", []))

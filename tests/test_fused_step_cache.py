@@ -567,22 +567,35 @@ def small_compiled():
     return jax.jit(lambda v: v + 1).lower(x).compile()
 
 
-def test_store_keeps_its_most_recently_used_entries(tmp_path, small_compiled):
+def test_store_keeps_its_most_recently_used_entries(tmp_path, small_compiled, monkeypatch):
     directory = str(tmp_path / "steps")
+    size = step_cache.save(str(tmp_path / "one"), "key", {"n": 0}, small_compiled)
+    # room for four entries of this size, not five
+    monkeypatch.setattr(step_cache, "STORE_BYTES", 4 * size + size // 2)
     now = time.time()
-    n = step_cache.STORE_STEPS + 3
+    n = 4 + 3
     for i in range(n):
         step_cache.save(directory, f"key{i}", {"n": i}, small_compiled)
         os.utime(os.path.join(directory, f"key{i}"), (now - 100 + i, now - 100 + i))
     # the oldest that is left is used again, then one more entry arrives
     kept = sorted(os.listdir(directory))
-    assert len(kept) <= step_cache.STORE_STEPS + 1
+    assert len(kept) <= 4 + 1
     oldest = kept[0]
     os.utime(os.path.join(directory, oldest), (now, now))
     step_cache.save(directory, "newest", {"n": -1}, small_compiled)
     left = set(os.listdir(directory))
-    assert len(left) == step_cache.STORE_STEPS
+    assert len(left) == 4
     assert {"newest", oldest, f"key{n - 1}"} <= left and "key0" not in left
+
+
+def test_the_newest_entry_stays_whatever_its_size(tmp_path, small_compiled, monkeypatch):
+    directory = str(tmp_path / "steps")
+    monkeypatch.setattr(step_cache, "STORE_BYTES", 1)
+    step_cache.save(directory, "old", {"n": 0}, small_compiled)
+    past = time.time() - 100
+    os.utime(os.path.join(directory, "old"), (past, past))
+    step_cache.save(directory, "new", {"n": 1}, small_compiled)
+    assert os.listdir(directory) == ["new"]
 
 
 def test_a_killed_writers_temp_file_goes_and_a_live_one_stays(tmp_path, small_compiled):

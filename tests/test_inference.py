@@ -2,6 +2,7 @@
 round-trip, blockwise == single-shot (halo large enough), classifier
 accuracy on a synthetic two-class volume."""
 
+import contextlib
 import json
 import os
 
@@ -713,3 +714,189 @@ def test_inference_task_auto_model_from_torch_checkpoint(workspace, rng):
     pred = file_reader(path, "r")["pred"][...]
     assert pred.shape == (2,) + shape
     assert np.isfinite(pred).all()
+
+
+# -- the sweep program, kept across jobs --------------------------------------
+
+KEPT_MODEL = {"name": "unet3d", "out_channels": 2, "base_features": 4, "depth": 1,
+              "norm": None}
+
+
+def _inference_job(root, path, tag, model=KEPT_MODEL, **params):
+    """One inference job under a fresh output key: its map, the manifest's
+    ``step_cache``, its ``io_metrics.json`` counters and its span names."""
+    from cluster_tools_tpu.runtime import trace
+    from cluster_tools_tpu.tasks.inference import InferenceWorkflow
+
+    tmp = os.path.join(root, f"tmp_{tag}")
+    os.makedirs(tmp)
+    with open(os.path.join(tmp, "global.config"), "w") as f:
+        json.dump({"block_shape": [16, 16, 16]}, f)
+    trace.configure(enabled=True)
+    try:
+        wf = InferenceWorkflow(
+            tmp_folder=tmp, config_dir=tmp, max_jobs=2, target="local",
+            input_path=path, input_key="raw", output_path=path,
+            output_key=f"pred_{tag}", model=dict(model), halo=[8, 8, 8],
+            normalize_range=[0.0, 1.0], block_shape=[16, 16, 16],
+            sharded_batch=1, **params)
+        assert build([wf]), f"job {tag} failed (see logs)"
+        spans = [e["name"] for e in trace._get().snapshot_events() if e["ph"] == "X"]
+    finally:
+        trace.reset()
+    (task,) = wf.requires()
+    with open(os.path.join(tmp, f"{task.uid}.success.json")) as f:
+        manifest = json.load(f)
+    with open(os.path.join(tmp, "io_metrics.json")) as f:
+        metrics = json.load(f)["tasks"][task.uid]
+    return dict(pred=file_reader(path, "r")[f"pred_{tag}"][...], tmp=tmp,
+                step_cache=manifest["step_cache"], metrics=metrics, spans=spans)
+
+
+@contextlib.contextmanager
+def _step_store(root):
+    """A step store under ``root`` on one device, the process level empty
+    on the way in and out, and JAX's config put back."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from cluster_tools_tpu.parallel import step_cache
+    from cluster_tools_tpu.runtime import executor as executor_mod
+
+    names = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs")
+    config = {n: getattr(jax.config, n) for n in names}
+    jax.config.update("jax_compilation_cache_dir", os.path.join(root, "jax_cache"))
+    jax.config.update("jax_enable_compilation_cache", True)
+    # JAX itself keeps nothing, so the build compiles and can be stored
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    cc.reset_cache()
+    step_cache.forget()
+    try:
+        with pytest.MonkeyPatch.context() as patch:
+            one = jax.devices("cpu")[:1]
+            patch.setattr(executor_mod, "get_devices", lambda *a, **k: one)
+            yield
+    finally:
+        for n, value in config.items():
+            jax.config.update(n, value)
+        cc.reset_cache()
+        step_cache.forget()
+
+
+@pytest.fixture(scope="module")
+def kept_jobs(tmp_path_factory):
+    """Three jobs of one architecture in one process, on one device with a
+    step store: random weights (the program is built), a checkpoint's
+    other weights (found in the process), and the checkpoint again with
+    the process level dropped (read from the store)."""
+    from cluster_tools_tpu.models import get_model
+    from cluster_tools_tpu.parallel import step_cache
+    from cluster_tools_tpu.tasks.inference import save_checkpoint
+
+    root = str(tmp_path_factory.mktemp("kept_inference"))
+    path = os.path.join(root, "data.zarr")
+    raw = np.random.default_rng(5).random((32, 32, 32)).astype(np.float32)
+    file_reader(path).require_dataset(
+        "raw", shape=raw.shape, chunks=(16, 16, 16), dtype="float32")[...] = raw
+    cfg = {k: v for k, v in KEPT_MODEL.items() if k != "name"}
+    model = get_model(KEPT_MODEL["name"], **cfg)
+    other = model.init(jax.random.PRNGKey(7), jnp.zeros((1, 16, 16, 16, 1)))
+    # the head's bias away from 0, so that the two weights' maps differ by
+    # far more than blockwise against single-shot may
+    other["params"]["Conv_1"]["bias"] = jnp.asarray([1.5, -1.5], jnp.float32)
+    ckpt = os.path.join(root, "other.npz")
+    save_checkpoint(ckpt, other)
+
+    with _step_store(root):
+        built = _inference_job(root, path, "built")
+        process = _inference_job(root, path, "process", checkpoint_path=ckpt)
+        step_cache.forget()
+        store = _inference_job(root, path, "store", checkpoint_path=ckpt)
+    return dict(raw=raw, model=model, other=other, built=built, process=process,
+                store=store)
+
+
+def _single_shot(model, variables, raw):
+    full = model.apply(variables, jnp.asarray(raw)[None, ..., None])[0]
+    return np.moveaxis(np.asarray(jax.nn.sigmoid(full)), -1, 0)
+
+
+def test_a_job_with_other_weights_runs_the_program_the_first_kept(kept_jobs):
+    built, process = kept_jobs["built"], kept_jobs["process"]
+    assert built["step_cache"]["from"] == "built"
+    assert built["step_cache"]["fallback"] is None
+    assert built["metrics"]["step_cache"] == {
+        "process_hits": 0, "store_hits": 0, "builds": 1, "fallbacks": 0}
+    assert process["step_cache"] == {
+        "from": "process", "key": built["step_cache"]["key"], "load_s": 0.0,
+        "store_bytes": 0, "fallback": None}
+    # one program for both jobs: the second looked it up once a dispatch
+    # signature, found it, and built, traced and read back nothing of it
+    assert process["metrics"]["step_cache"] == {
+        "process_hits": 1, "store_hits": 0, "builds": 0, "fallbacks": 0}
+    assert "executor.program_build" in built["spans"]
+    assert not {s for s in process["spans"] if s.startswith("executor.program_")}
+
+
+def test_each_job_predicts_with_its_own_weights(kept_jobs):
+    """The kept program reads the weights as its argument: each job's map is
+    the single-shot forward of its own weights, never the first job's."""
+    from cluster_tools_tpu.tasks.inference import load_checkpoint
+
+    raw, model = kept_jobs["raw"], kept_jobs["model"]
+    built, process = kept_jobs["built"], kept_jobs["process"]
+    (recorded,) = [f for f in os.listdir(built["tmp"]) if f.endswith(".weights.npz")]
+    own = load_checkpoint(os.path.join(built["tmp"], recorded), model, None)
+    inner = (slice(None), slice(8, 24), slice(8, 24), slice(8, 24))
+    want_built = _single_shot(model, own, raw)[inner]
+    want_process = _single_shot(model, kept_jobs["other"], raw)[inner]
+    np.testing.assert_allclose(built["pred"][inner], want_built, atol=2e-2)
+    np.testing.assert_allclose(process["pred"][inner], want_process, atol=2e-2)
+    # the two weights' maps lie far further apart than the tolerance
+    assert np.abs(want_built - want_process).max() > 0.1
+
+
+def test_a_new_process_level_reads_the_inference_program_from_the_store(kept_jobs):
+    built, process, store = kept_jobs["built"], kept_jobs["process"], kept_jobs["store"]
+    assert store["step_cache"]["from"] == "store"
+    assert store["step_cache"]["fallback"] is None
+    assert store["step_cache"]["key"] == built["step_cache"]["key"]
+    assert store["step_cache"]["store_bytes"] > 0
+    assert store["metrics"]["step_cache"]["store_hits"] == 1
+    assert "executor.program_load" in store["spans"]
+    assert "executor.program_build" not in store["spans"]
+    np.testing.assert_array_equal(store["pred"], process["pred"])
+
+
+GCR_MODEL = {"name": "unet3d_gcr", "out_channels": 1, "f_maps": 8, "num_levels": 2}
+
+
+def test_a_job_with_a_swapped_network_function_builds_a_program_of_its_own(
+        tmp_path, monkeypatch):
+    """The coarser-precision control swaps the U-Net's ``_operand`` for a
+    float8 rounding in memory, after a sound job in the same process has
+    kept its program.  The swapped job must not run the sound program: it
+    builds its own under another key, and with the same random weights its
+    map differs from the sound one.  Undone, the sound program is found
+    again in the process."""
+    from cluster_tools_tpu.models import unet_gcr
+
+    path = os.path.join(str(tmp_path), "data.zarr")
+    raw = np.random.default_rng(6).random((32, 32, 32)).astype(np.float32)
+    file_reader(path).require_dataset(
+        "raw", shape=raw.shape, chunks=(16, 16, 16), dtype="float32")[...] = raw
+    root = str(tmp_path)
+    with _step_store(root):
+        sound = _inference_job(root, path, "sound", model=GCR_MODEL)
+        monkeypatch.setattr(unet_gcr, "_operand",
+                            lambda a: a.astype(jnp.float8_e4m3fn).astype(jnp.bfloat16))
+        swapped = _inference_job(root, path, "swapped", model=GCR_MODEL)
+        monkeypatch.undo()
+        again = _inference_job(root, path, "again", model=GCR_MODEL)
+    assert sound["step_cache"]["from"] == "built"
+    assert swapped["step_cache"]["from"] == "built"
+    assert swapped["step_cache"]["key"] != sound["step_cache"]["key"]
+    assert not np.array_equal(swapped["pred"], sound["pred"])
+    assert again["step_cache"]["from"] == "process"
+    assert again["step_cache"]["key"] == sound["step_cache"]["key"]
+    np.testing.assert_array_equal(again["pred"], sound["pred"])
